@@ -92,8 +92,8 @@ def run(out_dir: str = "benchmarks/results") -> List[Record]:
     B, MP, PS, HQ, HKV, D = 8, 16, 16, 4, 2, 64  # 256 tokens/slot
     rng = np.random.default_rng(2)
     num_pages = 1 + B * MP
-    kp = jnp.asarray(rng.normal(size=(num_pages, PS, HKV, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(num_pages, PS, HKV, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(num_pages, HKV, PS, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(num_pages, HKV, PS, D)), jnp.float32)
     table = jnp.asarray(
         1 + rng.permutation(B * MP).reshape(B, MP).astype(np.int32)
     )
@@ -110,7 +110,8 @@ def run(out_dir: str = "benchmarks/results") -> List[Record]:
     out = paged_ops.paged_flash_decode(pq, kp, vp, table, pos)
     ref_out = paged_ref.paged_attention_ref(pq, kp, vp, table, pos)
     err = float(jnp.abs(out - ref_out).max())
-    # VMEM per grid step: q/o (G, D) + one KV page pair + f32 accumulators
+    # VMEM per grid step: q/o (G, D) + one (PS, D) tile each of K and V
+    # (pages are (P, HKV, PS, D)) + f32 accumulators
     vmem_kb = ((HQ // HKV) * D * 2 + PS * D * 2 + (HQ // HKV) * (D + 2)) * 4 / 1024
     records.append(Record(
         "paged_decode_kernel_interpret_max_err", err, "max_abs_err",

@@ -80,12 +80,25 @@ def main(argv: Optional[List[str]] = None) -> None:
         raise SystemExit(f"--out-root {out_root} exists and is not a directory")
     out_root.mkdir(parents=True, exist_ok=True)
     roofline_report.ALLOW_MISSING = roofline_report.ALLOW_MISSING or args.allow_missing
+    # a module whose work needs its own process (serve's 2-device interference
+    # child) starts it here, before this process initialises a JAX backend:
+    # a chip belongs to one process at a time
+    early_errors = {}
+    for name in names:
+        hook = getattr(MODULES[name], "before_backend", None)
+        if hook is not None:
+            try:
+                hook()
+            except Exception as e:  # noqa: BLE001 — reported with the module
+                early_errors[name] = e
     env = _env.fingerprint()
     print(_schema.CSV_HEADER)
     failures = []
     for name in names:
         t0 = time.time()
         try:
+            if name in early_errors:
+                raise early_errors[name]
             records = _schema.as_records(MODULES[name].run())
             for rec in records:
                 print(rec.csv_row(), flush=True)

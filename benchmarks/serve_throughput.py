@@ -32,11 +32,15 @@ interleaved engine includes the prompt chunk its tick ran first) with and
 without disaggregation, and ``serve_disagg_tok_per_s``. Because the CI box's
 wall-clock speed drifts by more than the effect under test, the two engines
 are timed in alternating passes and each reports the median across passes
-(see :func:`_interfere_child`). This scenario runs in a
-subprocess with ``xla_force_host_platform_device_count=2`` so the two
-workers really occupy disjoint devices and the page stream crosses a real
-``device_put`` seam — the parent process stays pinned to the one-device env
-of :mod:`benchmarks._env`.
+(see :func:`_interfere_child`). This scenario runs in a subprocess with
+``xla_force_host_platform_device_count=2`` so the two workers really occupy
+disjoint devices and the page stream crosses a real ``device_put`` seam —
+the parent process stays pinned to the one-device env of
+:mod:`benchmarks._env`. The child runs to its end before the parent
+initialises a JAX backend (:func:`before_backend`): a chip belongs to one
+process at a time. On an accelerator the child gets the devices present;
+with fewer than two, both workers share one and the records say so
+(``devices`` and ``note`` in their context).
 
 Compilation is excluded from both timings via a warmup pass that visits
 every decode shape; the continuous engine's per-stage compile cache is kept
@@ -53,7 +57,7 @@ import os
 import subprocess
 import sys
 import time
-from typing import List
+from typing import List, Optional
 
 import jax
 import numpy as np
@@ -294,10 +298,24 @@ def _interfere_child() -> dict:
     return out
 
 
-def _bench_interference() -> dict:
+_interference: Optional[dict] = None  # the child's measurements
+
+
+def before_backend() -> None:
     """Run the interference scenario in a subprocess whose host platform is
-    forced to TWO devices (the parent env pins one). The child prints one
-    JSON object on the last stdout line."""
+    forced to TWO devices (the parent env pins one), and keep its result for
+    :func:`run`. Call it before this process initialises a JAX backend
+    (``benchmarks.run`` does, before any module runs): on a chip the parent
+    would hold the device the child needs. The child prints one JSON object
+    on the last stdout line."""
+    global _interference
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized():
+        raise RuntimeError(
+            "the interference child must run before this process initialises "
+            "a JAX backend (a chip belongs to one process)"
+        )
     env = dict(os.environ)
     flags = [
         f for f in env.get("XLA_FLAGS", "").split()
@@ -315,10 +333,12 @@ def _bench_interference() -> dict:
         raise RuntimeError(
             f"interference child failed ({proc.returncode}):\n{proc.stderr[-2000:]}"
         )
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    _interference = json.loads(proc.stdout.strip().splitlines()[-1])
 
 
 def run(out_dir: str = "benchmarks/results") -> List[Record]:
+    if _interference is None:
+        before_backend()
     cfg = get_config(ARCH, "smoke")
     model = build_model(cfg)
     params, _ = model.init(jax.random.key(0))
@@ -371,16 +391,18 @@ def run(out_dir: str = "benchmarks/results") -> List[Record]:
                 f"serve_{name}_load{load}_latency_p99", p99, "s",
                 direction="lower", context=ctx,
             ))
-    interfere = _bench_interference()
+    interfere = _interference
     details["interference"] = interfere
     ictx = {
         "arch": ARCH, "slots": I_SLOTS, "short_requests": I_SHORT,
         "long_requests": I_LONG, "long_prompt_len": I_LONG_LEN,
         "new_tokens": I_NEW, "chunks_interleaved": list(I_CHUNK_INTERLEAVED),
-        "chunks_disagg": list(I_CHUNK_DISAGG), "devices": 2,
+        "chunks_disagg": list(I_CHUNK_DISAGG), "devices": interfere["num_devices"],
         "percentile_method": PERCENTILE_METHOD, "timed_reps": I_REPS,
         "prefix_cache": False,
     }
+    if interfere["num_devices"] < 2:
+        ictx["note"] = "one device: the prefill and decode workers share it"
     for name, key in (("paged", "paged"), ("disagg", "disagg")):
         m = interfere[key]
         records.append(Record(

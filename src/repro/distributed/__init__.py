@@ -23,7 +23,12 @@ Resharding invariants (enforced by tests/test_distributed.py):
    (distributed/step.py); replicas compute subtrees and the all-gathered
    combine finishes the same tree. Hence losses, stage transitions and
    final params are bit-identical across any planner-legal width, and
-   across elastic width changes at stage boundaries.
+   across elastic width changes at stage boundaries, where each replica's
+   per-microbatch program rounds as the width-1 program does: on the CPU
+   host at f32 compute (what the tests pin). At bf16 compute, and on a TPU
+   at either dtype, the programs of different widths round differently and
+   the runs agree closely in loss (1e-4 to 1e-2 apart), not bit for bit
+   (``chip_smoke.py --chips 4`` measures it on four chips).
 3. **Checkpoints are width-agnostic.** Only the collapsed single-copy
    state is ever serialized (local-SGD saves snap to averaging points), so
    a checkpoint written at width W restores at any width W′ — elastic

@@ -101,7 +101,9 @@ def build_elastic_train_step(
     with axis 0 sharded over the mesh's "data" axis. The only collective is
     one all-gather of the per-replica partial sums per optimizer update.
     Losses, grads and therefore the whole trajectory are bit-identical for
-    every width satisfying the planner's divisibility rule.
+    every width satisfying the planner's divisibility rule, wherever the
+    compiler rounds each microbatch's forward/backward alike in every
+    width's program (f32 compute on the CPU host; not bf16, not a TPU).
 
     Compile-cost note: the canonical tree unrolls one forward/backward per
     local microbatch (a lax.scan would impose serial summation order and

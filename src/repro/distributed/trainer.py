@@ -9,7 +9,8 @@ Guarantees (exact mode, see tests/test_distributed.py):
 
 - width equivalence: losses, stage transitions, GNS trajectory and final
   params are bit-identical at every device budget, including across an
-  elastic width change at a stage boundary;
+  elastic width change at a stage boundary (at f32 compute on the CPU
+  host; see invariant 2 in ``repro.distributed`` for where it fails);
 - elastic kill-equivalence: a run killed at any update under budget W and
   resumed under budget W′ reproduces the uninterrupted run bit-for-bit
   (checkpoints always hold the collapsed, width-agnostic state; the

@@ -31,7 +31,7 @@ def _is_tpu() -> bool:
 def build_paged_flash_decode():
     def decode(
         q: jnp.ndarray,           # (B, Hq, D) — one query token per slot
-        k_pages: jnp.ndarray,     # (P, ps, Hkv, D)
+        k_pages: jnp.ndarray,     # (P, Hkv, ps, D)
         v_pages: jnp.ndarray,
         page_table: jnp.ndarray,  # (B, max_pages)
         positions: jnp.ndarray,   # (B,) — per-slot decode write position
@@ -41,7 +41,7 @@ def build_paged_flash_decode():
         interpret: Optional[bool] = None,
     ) -> jnp.ndarray:
         b, hq, d = q.shape
-        hkv = k_pages.shape[2]
+        hkv = k_pages.shape[1]
         assert hq % hkv == 0, f"q heads {hq} % kv heads {hkv} != 0"
         interp = (not _is_tpu()) if interpret is None else interpret
         out = paged_flash_decode_grouped(
@@ -64,7 +64,7 @@ def build_paged_flash_decode():
 def build_paged_chunk_prefill():
     def prefill(
         q: jnp.ndarray,           # (B, C, Hq, D) — contiguous chunk of queries
-        k_pages: jnp.ndarray,     # (P, ps, Hkv, D)
+        k_pages: jnp.ndarray,     # (P, Hkv, ps, D)
         v_pages: jnp.ndarray,
         page_table: jnp.ndarray,  # (B, max_pages)
         pos_start: jnp.ndarray,   # (B,) — position of each chunk's first query
@@ -74,7 +74,7 @@ def build_paged_chunk_prefill():
         interpret: Optional[bool] = None,
     ) -> jnp.ndarray:
         b, c, hq, d = q.shape
-        hkv = k_pages.shape[2]
+        hkv = k_pages.shape[1]
         assert hq % hkv == 0, f"q heads {hq} % kv heads {hkv} != 0"
         interp = (not _is_tpu()) if interpret is None else interpret
         qg = q.transpose(0, 2, 1, 3).reshape(b, hkv, hq // hkv, c, d)

@@ -2,22 +2,54 @@
 # exact masking/scaling/softcap semantics of the serving attention path
 # (models/layers/attention.py) but materializes the table-gathered KV view —
 # the thing the Pallas kernels exist to avoid. The property harness in
-# tests/test_paged_decode_kernel.py asserts kernel == ref in interpret mode.
+# tests/test_paged_decode_kernel.py asserts kernel == ref in interpret mode;
+# chip_smoke.py does the same on the chip, both on random_paged_pool's data.
 from __future__ import annotations
 
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NEG_INF = -2.0e38  # matches attention.py's mask fill
 
 
+def random_paged_pool(seed, *, slots, ps, mp, hkv, d, share=False, dtype=np.float32):
+    """Random page pool (P, hkv, ps, d) + per-slot tables for comparing a
+    kernel with its oracle. Returns (k_pages, v_pages, table, positions) with
+    positions[b] = the slot's current decode write position (ragged). With
+    ``share`` every odd slot aliases slot 0's first page (a published COW
+    prefix page)."""
+    rng = np.random.default_rng(seed)
+    num_pages = 1 + slots * mp
+    k_pages = rng.normal(size=(num_pages, hkv, ps, d)).astype(np.float32)
+    v_pages = rng.normal(size=(num_pages, hkv, ps, d)).astype(np.float32)
+    lengths = rng.integers(1, mp * ps + 1, size=slots)
+    table = np.zeros((slots, mp), np.int32)
+    nxt = 1
+    for b in range(slots):
+        n = -(-int(lengths[b]) // ps)
+        table[b, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+    if share and slots > 1:
+        for b in range(1, slots, 2):
+            table[b, 0] = table[0, 0]
+    positions = (lengths - 1).astype(np.int32)
+    return (
+        jnp.asarray(k_pages.astype(dtype)),
+        jnp.asarray(v_pages.astype(dtype)),
+        jnp.asarray(table),
+        jnp.asarray(positions),
+    )
+
+
 def _gather(leaf, page_table):
-    """(P, ps, hkv, hd), (B, MP) -> slot-major dense (B, MP*ps, hkv, hd)."""
+    """(P, hkv, ps, hd), (B, MP) -> slot-major dense (B, MP*ps, hkv, hd)."""
     b, mp = page_table.shape
-    out = leaf[page_table.reshape(-1)]
-    return out.reshape((b, mp * leaf.shape[1]) + leaf.shape[2:])
+    _, hkv, ps, hd = leaf.shape
+    out = leaf[page_table.reshape(-1)].swapaxes(1, 2)
+    return out.reshape(b, mp * ps, hkv, hd)
 
 
 def paged_attention_ref(
@@ -32,13 +64,13 @@ def paged_attention_ref(
 ):
     """Single-token paged decode attention, gather-then-attend.
 
-    q: (B, Hq, D); k_pages/v_pages: (P, ps, Hkv, D); page_table: (B, MP)
+    q: (B, Hq, D); k_pages/v_pages: (P, Hkv, ps, D); page_table: (B, MP)
     int32; positions: (B,) int32 — the write position of the current token
     (so KV at logical positions <= positions[b] is attended). Returns
     (B, Hq, D) in q.dtype; math in float32.
     """
     b, hq, d = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[1]
     kg = _gather(k_pages, page_table).astype(jnp.float32)
     vg = _gather(v_pages, page_table).astype(jnp.float32)
     if hkv != hq:
@@ -72,7 +104,7 @@ def paged_prefill_ref(
     q: (B, C, Hq, D); pos_start: (B,) int32. Returns (B, C, Hq, D).
     """
     b, c, hq, d = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[1]
     kg = _gather(k_pages, page_table).astype(jnp.float32)
     vg = _gather(v_pages, page_table).astype(jnp.float32)
     if hkv != hq:

@@ -10,7 +10,13 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape, axes):
+    # jax.make_mesh defaults to Explicit axes; constrain() and the
+    # shard_map manual regions of the train steps need Auto ones
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,7 +25,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     pure data parallelism (one gradient all-reduce per update crosses it)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: Optional[int] = None):
@@ -31,8 +37,8 @@ def make_host_mesh(data: int = 1, model: int = 1, pod: Optional[int] = None):
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``. ``pod=None``
     (default) keeps the historical 2-axis ("data", "model") mesh."""
     if pod is None:
-        return jax.make_mesh((data, model), ("data", "model"))
-    return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
+        return _auto_mesh((data, model), ("data", "model"))
+    return _auto_mesh((pod, data, model), ("pod", "data", "model"))
 
 
 def make_disagg_submeshes(

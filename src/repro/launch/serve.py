@@ -15,15 +15,25 @@
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     PYTHONPATH=src python -m repro.launch.serve --engine disagg \
         --requests 12 --slots 4 --prefill-devices 4 --decode-devices 4
+
+    # qwen2.5-3b at published widths on one TPU chip (weights in bf16)
+    PYTHONPATH=src python -m repro.launch.serve --variant full --engine paged \
+        --param-dtype bfloat16 --prompt-len 256 --new-tokens 32 --cache-len 288 \
+        --slots 8 --chunk 128 --kernel pallas
+
+``main(argv)`` can also be called in-process (``chip_smoke.py`` does): it
+returns the engine, the prompts, the request ids and the results.
 """
 from __future__ import annotations
 
 import argparse
+from typing import Any, Dict, List, Optional
 
 import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_disagg_submeshes
 from repro.models import build_model
 from repro.obs import MetricsRegistry, Tracer
@@ -38,10 +48,12 @@ from repro.utils.log import get_logger
 log = get_logger("serve")
 
 
-def main() -> None:
+def main(argv: Optional[List[str]] = None) -> Dict[str, Any]:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b")
-    ap.add_argument("--variant", default="smoke")
+    ap.add_argument("--variant", default="smoke", choices=["smoke", "full"])
+    ap.add_argument("--param-dtype", default=None, choices=["float32", "bfloat16"],
+                    help="weight dtype (default: the config's own)")
     ap.add_argument("--engine", choices=["static", "continuous", "paged", "disagg"],
                     default="static")
     ap.add_argument("--batch", type=int, default=4, help="static: batch size")
@@ -88,7 +100,7 @@ def main() -> None:
     ap.add_argument("--metrics", default=None, metavar="PATH",
                     help="dump the metrics registry snapshot (counters, "
                          "gauges, histogram percentiles) as JSON")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     for flag, value, low in (
         ("--batch", args.batch, 1),
@@ -157,7 +169,10 @@ def main() -> None:
                 f"got {args.prefill_pages})"
             )
 
+    enable_compile_cache()
     cfg = get_config(args.arch, args.variant)
+    if args.param_dtype is not None:
+        cfg = cfg.replace(param_dtype=args.param_dtype)
     model = build_model(cfg)
     params, _ = model.init(jax.random.key(0))
 
@@ -174,7 +189,7 @@ def main() -> None:
         for i, row in enumerate(out):
             log.info("req %d: %s -> %s", i, row[: args.prompt_len].tolist(),
                      row[args.prompt_len:].tolist())
-        return
+        return {"engine": engine, "prompts": prompts, "outputs": out}
 
     if args.engine == "disagg":
         prefill_mesh, decode_mesh = make_disagg_submeshes(
@@ -265,6 +280,7 @@ def main() -> None:
     if metrics is not None:
         metrics.dump(args.metrics)
         log.info("metrics snapshot (%d series) written to %s", len(metrics), args.metrics)
+    return {"engine": engine, "prompts": prompts, "ids": ids, "results": results}
 
 
 if __name__ == "__main__":

@@ -4,9 +4,17 @@
         --variant smoke --schedule sebs --rho 4 --stages 3 --b1 8 \
         --c1 256 --seq 64 --steps-log 5
 
-Smoke/CPU-sized by default; the full configs are exercised via
-launch/dryrun.py (this host has one device). On a real TPU slice the same
-entry point runs the production mesh (``--mesh single|multi``).
+Smoke-sized by default, which is what the CPU tests and CI run. On a TPU
+``--variant full`` trains the published widths; ``--layers N`` cuts the
+depth to what one chip's memory holds, e.g. qwen2.5-3b with psgd in f32:
+
+    PYTHONPATH=src python -m repro.launch.train --variant full --layers 4 \
+        --b1 2 --c1 6 --rho 2 --stages 2 --seq 512
+
+On a TPU slice the same entry point runs the production mesh
+(``--mesh single|multi``). ``main(argv)`` can also be called in-process
+(``chip_smoke.py`` does): it returns the trainer, the final state and the
+train log.
 
 Fault tolerance: ``--ckpt-dir`` + ``--ckpt-every N`` snapshot the FULL run
 state (params, optimizer state, step, host RNG, pipeline position,
@@ -18,19 +26,24 @@ losses and final params are bit-identical to an uninterrupted run.
 Elastic data parallelism: ``--dp-elastic`` hands the run to
 :class:`repro.distributed.ElasticTrainer` — the replica count follows the
 SEBS stage ladder up to ``--device-budget``, with ``--sync-mode exact``
-(bit-identical across widths) or ``--sync-mode local`` (local SGD,
-averaging cadence ``--local-interval``/``--local-growth``).
+(bit-identical across widths at ``--compute-dtype float32`` on the CPU
+host; on a TPU, or at bf16, widths agree closely, not bit for bit) or
+``--sync-mode local`` (local SGD, averaging cadence
+``--local-interval``/``--local-growth``).
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
+from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core import SEBS, AdaptiveSEBS, ClassicalStagewise, SEBSTrainer
 from repro.obs import MetricsRegistry, Tracer
 from repro.data import DataPipeline, TokenDataset
@@ -42,10 +55,16 @@ from repro.utils.log import get_logger
 log = get_logger("train")
 
 
-def main() -> None:
+def main(argv: Optional[List[str]] = None) -> Dict[str, Any]:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b")
     ap.add_argument("--variant", default="smoke", choices=["smoke", "full"])
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to N layers (single-segment configs; "
+                         "widths unchanged)")
+    ap.add_argument("--compute-dtype", default=None, choices=["bfloat16", "float32"],
+                    help="override the config's compute dtype; elastic exact sync "
+                         "is bit-identical across widths at float32 on the CPU host only")
     ap.add_argument("--schedule", default="sebs", choices=["sebs", "classical", "adaptive"])
     ap.add_argument("--optimizer", default="psgd")
     ap.add_argument("--gamma", type=float, default=1e4)
@@ -66,8 +85,8 @@ def main() -> None:
                          "--mesh) and implies accumulate/deferred execution "
                          "(--mode/--accum-mode do not apply)")
     ap.add_argument("--sync-mode", default="exact", choices=["exact", "local"],
-                    help="exact: one gradient collective per update, bit-identical "
-                         "across widths; local: local SGD with stage-keyed averaging")
+                    help="exact: one gradient collective per update, width-invariant "
+                         "reduction order; local: local SGD with stage-keyed averaging")
     ap.add_argument("--device-budget", type=int, default=None,
                     help="max data-parallel width (default: all visible devices)")
     ap.add_argument("--local-interval", type=int, default=4,
@@ -96,7 +115,7 @@ def main() -> None:
                     help="dump the metrics registry snapshot (per-stage "
                          "update-time histograms, comm gauges) as JSON")
     ap.add_argument("--steps-log", type=int, default=5)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.dp_elastic and args.mesh != "none":
         ap.error("--dp-elastic builds its own per-stage data submeshes; drop --mesh")
@@ -136,13 +155,24 @@ def main() -> None:
             if getattr(args, dest) != default:
                 ap.error(f"--{dest.replace('_', '-')} requires --dp-elastic")
 
+    cfg = get_config(args.arch, args.variant)
+    if args.layers is not None:
+        if len(cfg.segments) != 1 or args.layers < 1 or args.layers % len(cfg.segments[0].body):
+            ap.error(f"--layers {args.layers} cannot cut {args.arch}: it needs one "
+                     "segment and a positive multiple of its block period")
+        seg = cfg.segments[0]
+        cfg = cfg.replace(segments=(
+            dataclasses.replace(seg, repeat=args.layers // len(seg.body)),))
+    if args.compute_dtype is not None:
+        cfg = cfg.replace(compute_dtype=args.compute_dtype)
+
+    enable_compile_cache()
     mesh = None
     if args.mesh != "none":
         from repro.launch.mesh import make_production_mesh
 
         mesh = make_production_mesh(multi_pod=args.mesh == "multi")
 
-    cfg = get_config(args.arch, args.variant)
     model = build_model(cfg)
     opt_kwargs = {"gamma": args.gamma} if args.optimizer == "psgd" else {}
     optimizer = make_optimizer(args.optimizer, **opt_kwargs)
@@ -217,6 +247,7 @@ def main() -> None:
     if metrics is not None:
         metrics.dump(args.metrics)
         log.info("metrics snapshot (%d series) written to %s", len(metrics), args.metrics)
+    return {"trainer": trainer, "state": state, "log": tlog}
 
 
 if __name__ == "__main__":

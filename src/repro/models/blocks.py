@@ -89,7 +89,7 @@ def init_block_cache_paged(
     state_batch: int, dtype,
 ):
     """Paged layout: attention KV lives in the shared page pool
-    ((num_pages, page_size, ...) leaves, one page id spanning every layer);
+    ((num_pages, hkv, page_size, hd) leaves, one page id spanning every layer);
     O(1) recurrent state (SSM/conv/RWKV) stays per-slot dense at
     ``state_batch`` rows."""
     cache = {}

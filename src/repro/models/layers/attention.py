@@ -72,40 +72,45 @@ CACHE_AXES = {
 
 
 def init_paged_cache(cfg, num_pages: int, page_size: int, dtype):
-    """Paged KV store: ``(num_pages, page_size, hkv, hd)`` per leaf. Page ids
-    are global across layers (one logical page = a slab through every
-    attention leaf); slots map logical→physical pages via a page table."""
+    """Paged KV store: ``(num_pages, hkv, page_size, hd)`` per leaf, so one
+    page of one kv head is a contiguous (page_size, hd) tile — the block the
+    Pallas kernels stream. Page ids are global across layers (one logical
+    page = a slab through every attention leaf); slots map logical→physical
+    pages via a page table."""
     hkv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
     return {
-        "k": jnp.zeros((num_pages, page_size, hkv, hd), dtype),
-        "v": jnp.zeros((num_pages, page_size, hkv, hd), dtype),
+        "k": jnp.zeros((num_pages, hkv, page_size, hd), dtype),
+        "v": jnp.zeros((num_pages, hkv, page_size, hd), dtype),
     }
 
 
 PAGED_CACHE_AXES = {
-    "k": (None, None, "kv_heads", "head_dim"),
-    "v": (None, None, "kv_heads", "head_dim"),
+    "k": (None, "kv_heads", None, "head_dim"),
+    "v": (None, "kv_heads", None, "head_dim"),
 }
 
 
 def _paged_write(leaf, val, page_table, positions):
     """Scatter ``val`` (B, S, hkv, hd) into the paged ``leaf``
-    (P, ps, hkv, hd) at logical ``positions`` (B, S) through ``page_table``
+    (P, hkv, ps, hd) at logical ``positions`` (B, S) through ``page_table``
     (B, max_pages). Rows whose table entry is 0 land in the scratch page."""
-    ps = leaf.shape[1]
+    ps = leaf.shape[2]
     rows = jnp.arange(page_table.shape[0])[:, None]
     phys = page_table[rows, positions // ps].reshape(-1)
     off = (positions % ps).reshape(-1)
     flat = val.reshape((-1,) + val.shape[2:]).astype(leaf.dtype)
-    return leaf.at[phys, off].set(flat, mode="drop")
+    # advanced indices split by a slice: the (N,) index dim leads, so the
+    # update is (N, hkv, hd) — exactly ``flat``
+    return leaf.at[phys, :, off].set(flat, mode="drop")
 
 
 def _paged_gather(leaf, page_table):
     """Gather a slot-major dense view (B, max_pages * ps, hkv, hd) of the
     paged ``leaf`` in logical-position order."""
     b, mp = page_table.shape
-    out = leaf[page_table.reshape(-1)]  # (B*mp, ps, hkv, hd)
-    return out.reshape((b, mp * leaf.shape[1]) + leaf.shape[2:])
+    _, hkv, ps, hd = leaf.shape
+    out = leaf[page_table.reshape(-1)].swapaxes(1, 2)  # (B*mp, ps, hkv, hd)
+    return out.reshape(b, mp * ps, hkv, hd)
 
 
 def _project_qkv(params, x, memory, cfg):
@@ -211,7 +216,7 @@ def apply(
     ``memory`` (B, T, d) switches to cross-attention (no cache, no causal).
 
     ``page_table`` (B, max_pages) int32 switches the cache to the paged
-    layout (leaves (num_pages, page_size, hkv, hd)): decode scatters the new
+    layout (leaves (num_pages, hkv, page_size, hd)): decode scatters the new
     KV at ``page_table[b, pos // ps]`` and attends over the table-gathered
     view; with s > 1 it is a *chunked prefill* continuation — the chunk's KV
     is written at its absolute ``positions`` and queries attend to every

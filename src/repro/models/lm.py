@@ -134,7 +134,7 @@ class LanguageModel:
 
     # -- paged KV cache (continuous batching v2) ------------------------------
     # One merged tree: attention leaves live in a shared page pool
-    # ((layers, num_pages, page_size, hkv, hd) — a page id indexes axis 1 of
+    # ((layers, num_pages, hkv, page_size, hd) — a page id indexes axis 1 of
     # every attention leaf at once), while O(1) recurrent state (SSM, conv,
     # RWKV shift) stays per-slot dense ((layers, state_batch, ...)). The
     # helpers below walk the tree and dispatch on which side of that split a
@@ -229,7 +229,7 @@ class LanguageModel:
         attention pages ``page_ids`` ((K,) int32, scratch-0 padded past the
         prompt) stacked along the page axis, plus the slot's recurrent state
         row. The result has the cache's tree structure with pool-size-free
-        shapes — ``(layers, K, page_size, ...)`` KV and ``(layers, 1, ...)``
+        shapes — ``(layers, K, hkv, page_size, hd)`` KV and ``(layers, 1, ...)``
         state — so it can be device_put to another submesh and scattered
         into a pool of any size there."""
         return self._map_paged(

@@ -104,8 +104,8 @@ class Tracer:
 
     ``jax_profiler=True`` additionally brackets each synchronous span in
     a ``jax.profiler.TraceAnnotation`` so host spans line up with device
-    timelines in on-TPU profiles; the import is lazy and failure-tolerant
-    (a CPU-only or stripped environment degrades to host-only tracing).
+    timelines in on-TPU profiles; jax is imported only when it is asked
+    for, so a tracer without it (and tools/trace_view.py) needs no jax.
     """
 
     def __init__(
@@ -125,12 +125,9 @@ class Tracer:
         self._open_requests: Dict[Any, float] = {}  # rid -> begin ts
         self._annotation = None
         if jax_profiler:
-            try:
-                from jax.profiler import TraceAnnotation
+            from jax.profiler import TraceAnnotation
 
-                self._annotation = TraceAnnotation
-            except Exception:
-                self._annotation = None
+            self._annotation = TraceAnnotation
 
     # -- recording -----------------------------------------------------------
     def _emit(self, ev: Dict[str, Any]) -> None:

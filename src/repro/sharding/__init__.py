@@ -7,7 +7,6 @@ from repro.sharding.partitioning import (
     shard_tree,
     constrain,
     batch_spec,
-    legacy_manual_axes,
 )
 
 __all__ = [
@@ -19,5 +18,4 @@ __all__ = [
     "shard_tree",
     "constrain",
     "batch_spec",
-    "legacy_manual_axes",
 ]

@@ -10,12 +10,11 @@ This keeps one rule table valid across all ten assigned architectures.
 """
 from __future__ import annotations
 
-from contextlib import contextmanager
 from typing import Any, Mapping, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # logical axis -> preferred mesh axis (or tuple of axes), in priority order.
 # ``batch``-like axes shard over the data-parallel axes; ``model``-ish axes
@@ -72,40 +71,15 @@ def mesh_data_axes(mesh) -> Tuple[str, ...]:
     return tuple(a for a in DATA_AXES if a in mesh.axis_names)
 
 
-# Pre-axis_types jax cannot see shard_map manual axes on the mesh object;
-# the legacy _shard_map wrapper (train/step.py) declares them here instead.
-_LEGACY_MANUAL_AXES: set = set()
-
-
-@contextmanager
-def legacy_manual_axes(axes: Sequence[str]):
-    """Declare mesh axes as shard_map-Manual for constrain() on jax versions
-    whose Mesh carries no axis_types."""
-    saved = set(_LEGACY_MANUAL_AXES)
-    _LEGACY_MANUAL_AXES.update(axes)
-    try:
-        yield
-    finally:
-        _LEGACY_MANUAL_AXES.clear()
-        _LEGACY_MANUAL_AXES.update(saved)
-
-
 def _mesh_axis_sizes(mesh) -> Mapping[str, int]:
     # works for both Mesh and AbstractMesh: .shape is a name→size mapping.
     # Axes in Manual mode (inside shard_map) are excluded: constraints may
     # only reference Auto axes — the manual axes are the caller's business.
-    sizes = dict(mesh.shape)
-    try:
-        from jax.sharding import AxisType
-
-        for name, t in zip(mesh.axis_names, mesh.axis_types):
-            if t == AxisType.Manual:
-                sizes.pop(name, None)
-    except Exception:  # pragma: no cover - older mesh objects
-        pass
-    for name in _LEGACY_MANUAL_AXES:
-        sizes.pop(name, None)
-    return sizes
+    return {
+        name: size
+        for (name, size), t in zip(mesh.shape.items(), mesh.axis_types)
+        if t != AxisType.Manual
+    }
 
 
 def logical_to_mesh_spec(
@@ -181,33 +155,13 @@ def constrain(x: jax.Array, logical_axes: Sequence[Optional[str]], mesh=None):
     without the replication anchors produces more resharding, not less;
     hypothesis refuted, see EXPERIMENTS.md §Perf.)
 
-    Works under both mesh-context APIs: ``jax.set_mesh`` (abstract mesh,
-    preferred) and the legacy ``with mesh:`` (thread resources)."""
-    mesh = mesh or _current_mesh()
+    The mesh is the one given, else the abstract mesh in context
+    (``jax.set_mesh``, or the manual region of a ``shard_map``)."""
+    mesh = mesh or jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty or not mesh.axis_names:
         return x
     spec = logical_to_mesh_spec(logical_axes, mesh, x.shape)
-    try:
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-    except ValueError:
-        try:
-            # AbstractMesh (from jax.set_mesh): pass the PartitionSpec directly
-            return jax.lax.with_sharding_constraint(x, spec)
-        except ValueError:
-            # legacy shard_map manual region (pre-axis_types jax: Mesh does
-            # not expose Manual axes, so the spec may reference one) —
-            # constraints are hints; skip rather than crash the trace. Only
-            # when the spec actually touches a declared manual axis: any
-            # other ValueError is a real spec bug and must surface.
-            spec_axes = {
-                a
-                for entry in spec
-                if entry is not None
-                for a in ((entry,) if isinstance(entry, str) else entry)
-            }
-            if spec_axes & _LEGACY_MANUAL_AXES:
-                return x
-            raise
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
 def batch_spec(mesh: Mesh, extra_dims: int = 1, batch_size: Optional[int] = None) -> P:
@@ -226,18 +180,3 @@ def batch_spec(mesh: Mesh, extra_dims: int = 1, batch_size: Optional[int] = None
             prod *= sizes[a]
         axes = keep
     return P(tuple(axes) if len(axes) > 1 else (axes[0] if axes else None), *([None] * extra_dims))
-
-
-def _current_mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and m.axis_names:
-            return m
-    except Exception:  # pragma: no cover
-        pass
-    try:
-        from jax._src import mesh as mesh_lib
-
-        return mesh_lib.thread_resources.env.physical_mesh
-    except Exception:  # pragma: no cover
-        return None

@@ -32,34 +32,15 @@ from repro.utils.tree import tree_add, tree_scale
 
 
 def shard_map_manual(f, mesh, in_specs, out_specs, manual_axes):
-    """Version-portable shard_map: manual over ``manual_axes`` only (the
-    model axis stays automatic), no replication/VMA checking.
+    """shard_map manual over ``manual_axes`` only (the model axis stays
+    automatic), no replication/VMA checking.
 
     Shared by the deferred-psum train step below and the elastic
     data-parallel steps in ``repro.distributed.step``."""
-    if hasattr(jax, "shard_map"):  # jax >= 0.6
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=set(manual_axes), check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as sm  # jax <= 0.5
-
-    from repro.sharding import legacy_manual_axes
-
-    def body(*args):
-        # old Mesh objects carry no axis_types, so constrain() cannot see
-        # which axes are Manual — declare them for the trace explicitly
-        with legacy_manual_axes(manual_axes):
-            return f(*args)
-
-    return sm(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False, auto=frozenset(mesh.axis_names) - set(manual_axes),
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        axis_names=set(manual_axes), check_vma=False,
     )
-
-
-# legacy alias (pre-PR-5 name)
-_shard_map = shard_map_manual
 
 
 def clip_by_global_norm(grads, max_norm: float):
@@ -70,9 +51,6 @@ def clip_by_global_norm(grads, max_norm: float):
     )
     scale = jnp.minimum(1.0, max_norm / (norm + 1e-9))
     return jax.tree.map(lambda g: g * scale, grads), norm
-
-
-_clip = clip_by_global_norm
 
 
 def _grads_over_microbatches(model, params, batch, accum_steps, z_loss, vary_axes=()):
@@ -144,7 +122,7 @@ def build_train_step(
     batch_axes = mesh_data_axes(mesh)
 
     def apply_update(state: TrainState, grads, lr, stage):
-        grads, gnorm = _clip(grads, grad_clip)
+        grads, gnorm = clip_by_global_norm(grads, grad_clip)
         new_params, new_opt = optimizer.update(
             grads, state.opt_state, state.params, lr=lr, stage=stage
         )
@@ -204,7 +182,7 @@ def build_train_step(
                 P(),
             )
             out_specs = (jax.tree.map(lambda _: P(), state), P())
-            fn = _shard_map(
+            fn = shard_map_manual(
                 local_step, mesh, in_specs, out_specs, manual_axes=batch_axes
             )
             return fn(state, batch, lr, stage)

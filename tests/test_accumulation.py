@@ -51,6 +51,7 @@ _DEFERRED_SCRIPT = textwrap.dedent(
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_config
+    from repro.launch.mesh import make_host_mesh
     from repro.models import build_model
     from repro.optim import make_optimizer
     from repro.train.state import TrainState
@@ -61,13 +62,12 @@ _DEFERRED_SCRIPT = textwrap.dedent(
     opt = make_optimizer("sgd")
     params, _ = model.init(jax.random.key(0))
     state = TrainState(params, opt.init(params), jnp.zeros((), jnp.int32))
-    mesh = jax.make_mesh((4, 1), ("data", "model"))
+    mesh = make_host_mesh(data=4, model=1)
     tokens = jax.random.randint(jax.random.key(1), (8, 16), 0, cfg.vocab_size)
     stacked = {"tokens": tokens.reshape(2, 4, 16)}
 
-    with mesh:
-        step_d = build_train_step(model, opt, mesh, accum_steps=2, mode="deferred", donate=False)
-        sd, md = step_d(state, stacked, jnp.float32(0.1), jnp.int32(0))
+    step_d = build_train_step(model, opt, mesh, accum_steps=2, mode="deferred", donate=False)
+    sd, md = step_d(state, stacked, jnp.float32(0.1), jnp.int32(0))
     step_p = build_train_step(model, opt, mesh=None, accum_steps=2, donate=False)
     sp, mp = step_p(state, stacked, jnp.float32(0.1), jnp.int32(0))
 

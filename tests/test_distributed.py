@@ -319,18 +319,15 @@ _POD_SCRIPT = textwrap.dedent(
     opt = make_optimizer("sgd")
     params, _ = model.init(jax.random.key(0))
     state = TrainState(params, opt.init(params), jnp.zeros((), jnp.int32))
-    # pod is pure data parallelism (cf. make_production_mesh): model=1 here —
-    # the legacy partial-auto shard_map cannot partition the scan over a
-    # real model axis on old jax, and that is not what this test pins down
+    # pod is pure data parallelism (cf. make_production_mesh)
     mesh = make_host_mesh(data=2, model=1, pod=2)
     assert mesh.axis_names == ("pod", "data", "model")
     assert mesh.shape["pod"] == 2
     tokens = jax.random.randint(jax.random.key(1), (8, 16), 0, cfg.vocab_size)
     stacked = {"tokens": tokens.reshape(2, 4, 16)}
-    with mesh:
-        step_d = build_train_step(model, opt, mesh, accum_steps=2,
-                                  mode="deferred", donate=False)
-        sd, md = step_d(state, stacked, jnp.float32(0.1), jnp.int32(0))
+    step_d = build_train_step(model, opt, mesh, accum_steps=2,
+                              mode="deferred", donate=False)
+    sd, md = step_d(state, stacked, jnp.float32(0.1), jnp.int32(0))
     step_p = build_train_step(model, opt, mesh=None, accum_steps=2, donate=False)
     sp, mp = step_p(state, stacked, jnp.float32(0.1), jnp.int32(0))
     assert abs(float(md["loss"]) - float(mp["loss"])) < 1e-3, (md["loss"], mp["loss"])

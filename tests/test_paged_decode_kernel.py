@@ -16,8 +16,6 @@ this container, so correctness is proven, not eyeballed:
   with kernel="pallas" vs kernel="xla" on qwen (GQA) and gemma (sliding
   window + logit softcap) configs.
 """
-import math
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -32,38 +30,6 @@ from repro.models.layers.attention import _paged_write
 from repro.serve import PagedContinuousBatchingEngine
 from repro.serve.pages import PagePool
 from repro.serve.step import sample_tokens
-
-
-# ---------------------------------------------------------------------------
-# fixture builder: randomized paged pools with ragged lengths / COW sharing
-# ---------------------------------------------------------------------------
-
-def _paged_setup(seed, *, slots, ps, mp, hkv, d, share=False, dtype=np.float32):
-    """Random page pool + per-slot tables. Returns (k_pages, v_pages, table,
-    positions) with positions[b] = the slot's current decode write position.
-    With ``share`` every odd slot aliases slot 0's first page (a published
-    COW prefix page)."""
-    rng = np.random.default_rng(seed)
-    num_pages = 1 + slots * mp
-    k_pages = rng.normal(size=(num_pages, ps, hkv, d)).astype(dtype)
-    v_pages = rng.normal(size=(num_pages, ps, hkv, d)).astype(dtype)
-    lengths = rng.integers(1, mp * ps + 1, size=slots)
-    table = np.zeros((slots, mp), np.int32)
-    nxt = 1
-    for b in range(slots):
-        n = math.ceil(int(lengths[b]) / ps)
-        table[b, :n] = np.arange(nxt, nxt + n)
-        nxt += n
-    if share and slots > 1:
-        for b in range(1, slots, 2):
-            table[b, 0] = table[0, 0]
-    positions = (lengths - 1).astype(np.int32)
-    return (
-        jnp.asarray(k_pages),
-        jnp.asarray(v_pages),
-        jnp.asarray(table),
-        jnp.asarray(positions),
-    )
 
 
 def _assert_close(out, expect, dtype):
@@ -88,7 +54,7 @@ def _assert_close(out, expect, dtype):
 @settings(max_examples=10, deadline=None)
 def test_decode_matches_ref_property(ps, slots, heads, share, seed):
     hq, hkv, d, mp = heads[0], heads[1], 16, 4
-    kp, vp, table, pos = _paged_setup(
+    kp, vp, table, pos = pref.random_paged_pool(
         seed, slots=slots, ps=ps, mp=mp, hkv=hkv, d=d, share=share
     )
     rng = np.random.default_rng(seed + 1)
@@ -100,7 +66,7 @@ def test_decode_matches_ref_property(ps, slots, heads, share, seed):
 
 @pytest.mark.parametrize("window,softcap", [(None, None), (5, None), (None, 30.0), (7, 30.0)])
 def test_decode_window_softcap(window, softcap):
-    kp, vp, table, pos = _paged_setup(3, slots=3, ps=4, mp=4, hkv=2, d=32)
+    kp, vp, table, pos = pref.random_paged_pool(3, slots=3, ps=4, mp=4, hkv=2, d=32)
     q = jnp.asarray(np.random.default_rng(4).normal(size=(3, 4, 32)).astype(np.float32))
     out = pops.paged_flash_decode(
         q, kp, vp, table, pos, sliding_window=window, softcap=softcap
@@ -112,7 +78,7 @@ def test_decode_window_softcap(window, softcap):
 
 
 def test_decode_bf16_pages():
-    kp, vp, table, pos = _paged_setup(
+    kp, vp, table, pos = pref.random_paged_pool(
         5, slots=2, ps=4, mp=3, hkv=2, d=16, dtype=np.float32
     )
     kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
@@ -137,7 +103,7 @@ def test_decode_bf16_pages():
 @settings(max_examples=10, deadline=None)
 def test_chunk_prefill_matches_ref_property(ps, chunk, heads, seed):
     hq, hkv, d, mp, slots = heads[0], heads[1], 16, 4, 3
-    kp, vp, table, pos = _paged_setup(seed, slots=slots, ps=ps, mp=mp, hkv=hkv, d=d)
+    kp, vp, table, pos = pref.random_paged_pool(seed, slots=slots, ps=ps, mp=mp, hkv=hkv, d=d)
     # the chunk's last token sits at the slot's write position: the queries
     # [pos - chunk + 1, pos] are the chunk being prefilled (KV already
     # scattered, like attention.apply's chunked branch after _paged_write)
@@ -151,7 +117,7 @@ def test_chunk_prefill_matches_ref_property(ps, chunk, heads, seed):
 
 @pytest.mark.parametrize("window,softcap", [(3, None), (None, 20.0)])
 def test_chunk_prefill_window_softcap(window, softcap):
-    kp, vp, table, pos = _paged_setup(7, slots=2, ps=4, mp=4, hkv=2, d=16)
+    kp, vp, table, pos = pref.random_paged_pool(7, slots=2, ps=4, mp=4, hkv=2, d=16)
     pos_start = jnp.maximum(pos - 3, 0)
     q = jnp.asarray(np.random.default_rng(8).normal(size=(2, 4, 4, 16)).astype(np.float32))
     out = pops.paged_chunk_prefill(
@@ -172,8 +138,8 @@ def test_kv_ends_exactly_on_page_boundary():
     of its page; every later logical page is table entry 0 (scratch)."""
     ps, mp, hkv, d = 4, 4, 2, 16
     rng = np.random.default_rng(11)
-    kp = jnp.asarray(rng.normal(size=(1 + 2 * mp, ps, hkv, d)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(1 + 2 * mp, ps, hkv, d)).astype(np.float32))
+    kp = jnp.asarray(rng.normal(size=(1 + 2 * mp, hkv, ps, d)).astype(np.float32))
+    vp = jnp.asarray(rng.normal(size=(1 + 2 * mp, hkv, ps, d)).astype(np.float32))
     table = jnp.asarray([[1, 2, 0, 0], [3, 4, 5, 6]], jnp.int32)
     pos = jnp.asarray([2 * ps - 1, 4 * ps - 1], jnp.int32)  # page-boundary ends
     q = jnp.asarray(rng.normal(size=(2, 4, d)).astype(np.float32))
@@ -188,8 +154,8 @@ def test_scratch_page_never_contributes():
     kernel on the poisoned pool must match the ref on a zeroed-scratch pool."""
     ps, mp, hkv, d = 4, 4, 2, 16
     rng = np.random.default_rng(12)
-    kp = rng.normal(size=(1 + 2 * mp, ps, hkv, d)).astype(np.float32)
-    vp = rng.normal(size=(1 + 2 * mp, ps, hkv, d)).astype(np.float32)
+    kp = rng.normal(size=(1 + 2 * mp, hkv, ps, d)).astype(np.float32)
+    vp = rng.normal(size=(1 + 2 * mp, hkv, ps, d)).astype(np.float32)
     clean_k, clean_v = kp.copy(), vp.copy()
     clean_k[0], clean_v[0] = 0.0, 0.0
     kp[0], vp[0] = 1e4, 1e4  # poisoned scratch
@@ -208,8 +174,8 @@ def test_freshly_admitted_single_token_slot():
     """A slot right after admission: one page, one written token, pos 0."""
     ps, hkv, d = 8, 2, 16
     rng = np.random.default_rng(13)
-    kp = jnp.asarray(rng.normal(size=(3, ps, hkv, d)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(3, ps, hkv, d)).astype(np.float32))
+    kp = jnp.asarray(rng.normal(size=(3, hkv, ps, d)).astype(np.float32))
+    vp = jnp.asarray(rng.normal(size=(3, hkv, ps, d)).astype(np.float32))
     table = jnp.asarray([[1, 0, 0]], jnp.int32)
     pos = jnp.asarray([0], jnp.int32)
     q = jnp.asarray(rng.normal(size=(1, 4, d)).astype(np.float32))
@@ -217,7 +183,7 @@ def test_freshly_admitted_single_token_slot():
     expect = pref.paged_attention_ref(q, kp, vp, table, pos)
     # with a single valid position, attention must return exactly v[pos 0]
     # (repeated over the GQA group), softmax weight 1 on one key
-    v0 = np.repeat(np.asarray(vp)[1, 0], 2, axis=0)  # (hkv, d) -> (hq, d)
+    v0 = np.repeat(np.asarray(vp)[1, :, 0], 2, axis=0)  # (hkv, d) -> (hq, d)
     _assert_close(out, expect, np.float32)
     _assert_close(out[0], v0, np.float32)
 
@@ -237,8 +203,8 @@ def test_preempt_release_readmit_dirty_pages():
     pool.check()
 
     rng = np.random.default_rng(14)
-    dirty_k = jnp.asarray(rng.normal(size=(1 + mp, ps, hkv, d)).astype(np.float32))
-    dirty_v = jnp.asarray(rng.normal(size=(1 + mp, ps, hkv, d)).astype(np.float32))
+    dirty_k = jnp.asarray(rng.normal(size=(1 + mp, hkv, ps, d)).astype(np.float32))
+    dirty_v = jnp.asarray(rng.normal(size=(1 + mp, hkv, ps, d)).astype(np.float32))
     table = np.zeros((1, mp), np.int32)
     table[0, :2] = pages_b
     table = jnp.asarray(table)
@@ -263,7 +229,7 @@ def test_preempt_release_readmit_dirty_pages():
 def test_cow_shared_prefix_pages_alias():
     """Two slots alias the same physical prefix page (published prefix);
     per-slot outputs must each match the ref over their own table view."""
-    kp, vp, table, pos = _paged_setup(15, slots=4, ps=4, mp=4, hkv=2, d=16, share=True)
+    kp, vp, table, pos = pref.random_paged_pool(15, slots=4, ps=4, mp=4, hkv=2, d=16, share=True)
     assert int(table[1, 0]) == int(table[0, 0])  # aliased prefix page
     q = jnp.asarray(np.random.default_rng(16).normal(size=(4, 4, 16)).astype(np.float32))
     out = pops.paged_flash_decode(q, kp, vp, table, pos)
