@@ -154,7 +154,8 @@ class SEBSTrainer:
         if hasattr(self.controller.schedule, "state"):
             meta["schedule"] = self.controller.schedule.state()
         meta.update(self._meta_extra())
-        ckpt.save(update, {"train_state": self._save_view(state)}, meta=meta)
+        with self.tracer.span("train.save", update=update):
+            ckpt.save(update, {"train_state": self._save_view(state)}, meta=meta)
         self._last_saved = update
 
     def _restore(self, ckpt: CheckpointManager, state: TrainState,
@@ -206,6 +207,10 @@ class SEBSTrainer:
     def _comm_counters(self) -> tuple[int, int]:
         """(cumulative bytes per device, cumulative sync events) for the log."""
         return 0, 0
+
+    def _report_comm(self, comm_bytes: int, sync_events: int) -> None:
+        """Re-export the logged comm counters to the registry and the trace;
+        nothing here, where no update communicates."""
 
     def _ready_to_save(self, update: int) -> bool:
         """Whether the run state is checkpoint-consistent at this update
@@ -264,74 +269,70 @@ class SEBSTrainer:
                 # real kill (simulated preemption)
                 interrupted = True
                 break
-            t0 = self._clock()
-            state = self._before_update(state, plan)
-            batch = self._place_batch(self.pipeline.next_batch(plan.batch_size), plan)
-            state, metrics = self._execute(state, batch, plan)
             update += 1
-            state = self._after_update(state, update, plan)
-            loss = float(metrics["loss"])  # blocks: the update reached host
+            t0 = self._clock()
+            # one update's phases as nested spans; with the tracer's
+            # jax_profiler on they share the device's clock in a profile
+            with self.tracer.span(
+                "train.update", update=update, stage=plan.stage, batch=plan.batch_size
+            ) as update_span:
+                state = self._before_update(state, plan)
+                with self.tracer.span("train.data"):
+                    batch = self._place_batch(self.pipeline.next_batch(plan.batch_size), plan)
+                with self.tracer.span("train.dispatch"):
+                    state, metrics = self._execute(state, batch, plan)
+                state = self._after_update(state, update, plan)
+                with self.tracer.span("train.wait"):
+                    loss = float(metrics["loss"])  # blocks: the update reached host
+                update_span.set_arg("loss", loss)
             t1 = self._clock()
-            self.tracer.complete(
-                "train.update",
-                t0,
-                t1,
-                update=update,
-                stage=plan.stage,
-                batch=plan.batch_size,
-                loss=loss,
-            )
-            self.metrics.histogram(
-                "train.update_s", labels={"stage": plan.stage}
-            ).observe(t1 - t0)
-            self.metrics.counter("train.updates").inc()
-            self.metrics.counter("train.samples").inc(plan.batch_size)
-            if sanitize.enabled():
-                sanitize.check_finite_update(
-                    dict(metrics, loss=loss), update=update, stage=plan.stage
-                )
-            # adaptive schedules (core.noise_scale.AdaptiveSEBS) consume
-            # the measured loss to decide stage transitions (Eq. 8 with
-            # observed ε); the GNS estimator consumes the free per-
-            # microbatch grad norms from accumulate mode.
-            if hasattr(self.controller.schedule, "observe"):
-                self.controller.schedule.observe(plan.samples_after, loss)
-            if "grad_sq_big" in metrics and plan.accum_steps > 1:
-                gns.update(
-                    float(metrics["grad_sq_small"]), float(metrics["grad_sq_big"]),
-                    b_small=plan.microbatch, b_big=plan.batch_size,
-                )
-            if update % log_every == 0 or plan.samples_after >= self.controller.schedule.total_samples:
-                log.steps.append(update)
-                log.samples.append(plan.samples_after)
-                log.stages.append(plan.stage)
-                log.batch_sizes.append(plan.batch_size)
-                log.losses.append(loss)
-                log.noise_scales.append(gns.b_noise)
-                comm_bytes, sync_events = self._comm_counters()
-                log.comm_bytes.append(comm_bytes)
-                log.sync_events.append(sync_events)
-                # re-export the cumulative comm ledger and the GNS EMA
-                # through the registry — the obs layer reads the SAME
-                # numbers TrainLog records, not a second count
-                self.metrics.gauge("train.comm_bytes").set(comm_bytes)
-                self.metrics.gauge("train.sync_events").set(sync_events)
-                self.metrics.gauge("train.gns").set(gns.b_noise)
-                if self.tracer.enabled:
-                    self.tracer.counter(
-                        "train.comm", bytes=comm_bytes, syncs=sync_events
+            with self.tracer.span("train.after"):
+                self.metrics.histogram(
+                    "train.update_s", labels={"stage": plan.stage}
+                ).observe(t1 - t0)
+                self.metrics.counter("train.updates").inc()
+                self.metrics.counter("train.samples").inc(plan.batch_size)
+                if sanitize.enabled():
+                    sanitize.check_finite_update(
+                        dict(metrics, loss=loss), update=update, stage=plan.stage
                     )
-                    if not np.isnan(gns.b_noise):  # NaN is invalid trace JSON
+                # adaptive schedules (core.noise_scale.AdaptiveSEBS) consume
+                # the measured loss to decide stage transitions (Eq. 8 with
+                # observed ε); the GNS estimator consumes the free per-
+                # microbatch grad norms from accumulate mode.
+                if hasattr(self.controller.schedule, "observe"):
+                    self.controller.schedule.observe(plan.samples_after, loss)
+                if "grad_sq_big" in metrics and plan.accum_steps > 1:
+                    gns.update(
+                        float(metrics["grad_sq_small"]), float(metrics["grad_sq_big"]),
+                        b_small=plan.microbatch, b_big=plan.batch_size,
+                    )
+                if update % log_every == 0 or plan.samples_after >= self.controller.schedule.total_samples:
+                    log.steps.append(update)
+                    log.samples.append(plan.samples_after)
+                    log.stages.append(plan.stage)
+                    log.batch_sizes.append(plan.batch_size)
+                    log.losses.append(loss)
+                    log.noise_scales.append(gns.b_noise)
+                    comm_bytes, sync_events = self._comm_counters()
+                    log.comm_bytes.append(comm_bytes)
+                    log.sync_events.append(sync_events)
+                    self._report_comm(comm_bytes, sync_events)
+                    # re-export the GNS EMA through the registry — the obs
+                    # layer reads the SAME number TrainLog records
+                    self.metrics.gauge("train.gns").set(gns.b_noise)
+                    if self.tracer.enabled and not np.isnan(gns.b_noise):
+                        # NaN is invalid trace JSON
                         self.tracer.counter("train.gns", b_noise=gns.b_noise)
-            if checkpointer is not None and save_every:
-                # saves SNAP to the next checkpoint-consistent update rather
-                # than being dropped: local-SGD replicas are only consistent
-                # right after an average, and its cadence need not align
-                # with save_every
-                save_pending = save_pending or update % save_every == 0
-                if save_pending and self._ready_to_save(update):
-                    self._save(checkpointer, update, state, log, gns)
-                    save_pending = False
+                if checkpointer is not None and save_every:
+                    # saves SNAP to the next checkpoint-consistent update
+                    # rather than being dropped: local-SGD replicas are only
+                    # consistent right after an average, and its cadence
+                    # need not align with save_every
+                    save_pending = save_pending or update % save_every == 0
+                    if save_pending and self._ready_to_save(update):
+                        self._save(checkpointer, update, state, log, gns)
+                        save_pending = False
         state = self._finalize(state)
         if sanitize.enabled():
             sanitize.audit_tracer(self.tracer, where="(train run end)")

@@ -229,6 +229,13 @@ class ElasticTrainer(SEBSTrainer):
     def _comm_counters(self) -> tuple[int, int]:
         return self.accountant.total_bytes, self.accountant.total_sync_events
 
+    def _report_comm(self, comm_bytes: int, sync_events: int) -> None:
+        # the obs layer reads the SAME cumulative numbers TrainLog records
+        self.metrics.gauge("train.comm_bytes").set(comm_bytes)
+        self.metrics.gauge("train.sync_events").set(sync_events)
+        if self.tracer.enabled:
+            self.tracer.counter("train.comm", bytes=comm_bytes, syncs=sync_events)
+
     def _ready_to_save(self, update: int) -> bool:
         # local-SGD replicas are only checkpoint-consistent right after an
         # average; exact mode is consistent after every update

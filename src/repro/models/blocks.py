@@ -131,64 +131,68 @@ def apply_block(
     new_cache = dict(cache) if cache is not None else None
     decode = cache is not None and x.shape[1] == 1 and cache_index is not None
 
-    h = norm.apply(params["norm1"], x, cfg.norm_eps)
-    if spec.mixer in ("attn", "swa", "cross_attn_block"):
-        window = None
-        if spec.mixer == "swa":
-            window = spec.sliding_window or cfg.sliding_window
-        y, attn_cache = attention.apply(
-            params["attn"], h, cfg,
-            positions=positions, causal=causal, sliding_window=window,
-            cache=None if cache is None else cache.get("attn"),
-            cache_index=cache_index, page_table=page_table,
-        )
-        if new_cache is not None and attn_cache is not None:
-            new_cache["attn"] = attn_cache
-        y = checkpoint_name(y, "mixer_out")
-        x = x + y
-        if spec.mixer == "cross_attn_block" and memory is not None:
-            hx = norm.apply(params["norm_cross"], x, cfg.norm_eps)
-            yx, _ = attention.apply(
-                params["cross_attn"], hx, cfg, positions=positions,
-                causal=False, memory=memory,
+    # named scopes label the block's ops (op_name) in a device profile
+    attn_like = spec.mixer in ("attn", "swa", "cross_attn_block")
+    with jax.named_scope("attention" if attn_like else spec.mixer):
+        h = norm.apply(params["norm1"], x, cfg.norm_eps)
+        if attn_like:
+            window = None
+            if spec.mixer == "swa":
+                window = spec.sliding_window or cfg.sliding_window
+            y, attn_cache = attention.apply(
+                params["attn"], h, cfg,
+                positions=positions, causal=causal, sliding_window=window,
+                cache=None if cache is None else cache.get("attn"),
+                cache_index=cache_index, page_table=page_table,
             )
-            x = x + yx
-    elif spec.mixer == "mamba2":
-        y, mcache = mamba2.apply(
-            params["mamba"], h, cfg,
-            cache=None if cache is None else cache.get("mamba"),
-            cache_index=cache_index,
-        )
-        if new_cache is not None and mcache is not None:
-            new_cache["mamba"] = mcache
-        y = checkpoint_name(y, "mixer_out")
-        x = x + y
-    elif spec.mixer == "rwkv6":
-        rc = None if cache is None else cache.get("rwkv")
-        y, wkv, shift_t = rwkv6.apply_time_mix(params["tmix"], h, cfg, cache=rc, decode=decode)
-        if new_cache is not None:
-            new_cache["rwkv"] = dict(new_cache.get("rwkv", {}))
-            new_cache["rwkv"].update({"wkv": wkv, "shift_t": shift_t})
-        x = x + y
+            if new_cache is not None and attn_cache is not None:
+                new_cache["attn"] = attn_cache
+            y = checkpoint_name(y, "mixer_out")
+            x = x + y
+            if spec.mixer == "cross_attn_block" and memory is not None:
+                hx = norm.apply(params["norm_cross"], x, cfg.norm_eps)
+                yx, _ = attention.apply(
+                    params["cross_attn"], hx, cfg, positions=positions,
+                    causal=False, memory=memory,
+                )
+                x = x + yx
+        elif spec.mixer == "mamba2":
+            y, mcache = mamba2.apply(
+                params["mamba"], h, cfg,
+                cache=None if cache is None else cache.get("mamba"),
+                cache_index=cache_index,
+            )
+            if new_cache is not None and mcache is not None:
+                new_cache["mamba"] = mcache
+            y = checkpoint_name(y, "mixer_out")
+            x = x + y
+        elif spec.mixer == "rwkv6":
+            rc = None if cache is None else cache.get("rwkv")
+            y, wkv, shift_t = rwkv6.apply_time_mix(params["tmix"], h, cfg, cache=rc, decode=decode)
+            if new_cache is not None:
+                new_cache["rwkv"] = dict(new_cache.get("rwkv", {}))
+                new_cache["rwkv"].update({"wkv": wkv, "shift_t": shift_t})
+            x = x + y
 
     if spec.ffn == "none":
         return x, new_cache, aux
-    h = norm.apply(params["norm2"], x, cfg.norm_eps)
-    if spec.ffn == "dense":
-        x = x + checkpoint_name(mlp.apply(params["mlp"], h, cfg), "ffn_out")
-    elif spec.ffn == "moe":
-        y, moe_aux = moe.apply(params["moe"], h, cfg)
-        aux = aux + moe_aux
-        if cfg.moe_dense_residual:
-            y = y + mlp.apply(params["mlp"], h, cfg)
-        x = x + checkpoint_name(y, "ffn_out")
-    elif spec.ffn == "rwkv_cmix":
-        rc = None if cache is None else cache.get("rwkv")
-        y, shift_c = rwkv6.apply_channel_mix(params["cmix"], h, cfg, cache=rc)
-        if new_cache is not None:
-            new_cache["rwkv"] = dict(new_cache.get("rwkv", {}))
-            new_cache["rwkv"]["shift_c"] = shift_c
-        x = x + y
+    with jax.named_scope("mlp" if spec.ffn == "dense" else spec.ffn):
+        h = norm.apply(params["norm2"], x, cfg.norm_eps)
+        if spec.ffn == "dense":
+            x = x + checkpoint_name(mlp.apply(params["mlp"], h, cfg), "ffn_out")
+        elif spec.ffn == "moe":
+            y, moe_aux = moe.apply(params["moe"], h, cfg)
+            aux = aux + moe_aux
+            if cfg.moe_dense_residual:
+                y = y + mlp.apply(params["mlp"], h, cfg)
+            x = x + checkpoint_name(y, "ffn_out")
+        elif spec.ffn == "rwkv_cmix":
+            rc = None if cache is None else cache.get("rwkv")
+            y, shift_c = rwkv6.apply_channel_mix(params["cmix"], h, cfg, cache=rc)
+            if new_cache is not None:
+                new_cache["rwkv"] = dict(new_cache.get("rwkv", {}))
+                new_cache["rwkv"]["shift_c"] = shift_c
+            x = x + y
     return x, new_cache, aux
 
 

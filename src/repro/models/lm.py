@@ -100,7 +100,9 @@ class LanguageModel:
         """batch: {tokens (B,S) int32, [audio_embeds], [vision_embeds]}.
         Returns (logits (B,S,V) f32, aux_loss)."""
         cfg = self.cfg
-        x = self._embed_inputs(params, batch)
+        # jax.named_scope names each part's ops (op_name) in a profile
+        with jax.named_scope("embed"):
+            x = self._embed_inputs(params, batch)
         memory = self._encode(params, batch)
         b, s = batch["tokens"].shape
         positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
@@ -110,8 +112,10 @@ class LanguageModel:
                 params[f"seg{i}"], x, cfg, seg, positions=positions, memory=memory
             )
             aux = aux + a
-        x = norm.apply(params["final_norm"], x, cfg.norm_eps)
-        return embedding.logits(params["embed"] if cfg.tie_embeddings else params["embed"], x, cfg), aux
+        with jax.named_scope("final_norm"):
+            x = norm.apply(params["final_norm"], x, cfg.norm_eps)
+        with jax.named_scope("head"):
+            return embedding.logits(params["embed"], x, cfg), aux
 
     # -- serving ----------------------------------------------------------------
     def init_cache(self, batch: int, cache_len: int, dtype=jnp.bfloat16):

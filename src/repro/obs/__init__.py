@@ -5,12 +5,13 @@ syncs at matched generalization — so the repo routes all its accounting
 through one instrumentation layer instead of per-subsystem stats dicts:
 
 - :mod:`repro.obs.trace` — a span/event :class:`~repro.obs.trace.Tracer`
-  (ring buffer, injected monotonic clock, Chrome ``trace_event`` + JSONL
+  (ring buffer, injected monotonic clock, Chrome ``trace_event``
   export, optional ``jax.profiler`` bracketing). Engines record
   per-request lifecycle spans (enqueue → admit → prefill_done →
   first_token → done) and per-tick spans carrying pool occupancy, queue
   depth, prefix hits, admission stage, and seam-transfer bytes; trainers
-  record per-update spans carrying stage, batch size, loss, and GNS.
+  record per-update spans carrying stage, batch size and loss, with the
+  update's phases (data, dispatch, wait) nested inside, and GNS.
 - :mod:`repro.obs.metrics` — a counter/gauge/histogram
   :class:`~repro.obs.metrics.MetricsRegistry` with labeled series and
   fixed-bucket percentiles (p50/p99 in O(buckets) memory).

@@ -8,7 +8,9 @@ records host-side spans into a bounded ring buffer:
   manager or retroactively via :meth:`complete` when the caller already
   timed the region itself (the engines do this so the float stored in
   ``stats["decode_tick_s"]`` and the float stored in the trace are the
-  SAME number — percentiles derived from either source agree exactly);
+  SAME number — percentiles derived from either source agree exactly).
+  Only :meth:`span` regions reach the JAX profiler's trace (see
+  ``jax_profiler``); :meth:`complete` spans are host-only;
 - **instant events** (``"i"``) — a point in time, e.g. a sync event;
 - **counter events** (``"C"``) — sampled series (pool occupancy, queue
   depth, admission stage) rendered as stacked tracks in Perfetto;
@@ -31,9 +33,8 @@ balanced-stack invariants at run() end.
 
 Export: :meth:`dump_chrome` writes ``{"traceEvents": [...]}`` (Chrome
 ``chrome://tracing`` / Perfetto ``ui.perfetto.dev`` load it directly;
-timestamps converted to microseconds); :meth:`dump_jsonl` writes one raw
-event per line for ad-hoc grepping. ``tools/trace_view.py`` summarizes
-either format.
+timestamps converted to microseconds); ``tools/trace_view.py`` summarizes
+it.
 """
 from __future__ import annotations
 
@@ -64,6 +65,12 @@ class _Span:
         self.args = args
         self._t0 = 0.0
 
+    def set_arg(self, key: str, value: Any) -> None:
+        """Add one arg known only inside the region (e.g. an update's loss)."""
+        if self.args is None:
+            self.args = {}
+        self.args[key] = value
+
     def __enter__(self) -> "_Span":
         self._t0 = self._tracer.clock()
         self._tracer._depth += 1
@@ -84,6 +91,9 @@ class _NullSpan:
     def __enter__(self) -> "_NullSpan":
         return self
 
+    def set_arg(self, key: str, value: Any) -> None:
+        pass
+
     def __exit__(self, *exc) -> None:
         pass
 
@@ -102,10 +112,12 @@ class Tracer:
     default, never invoked at import), so state-mutating callers satisfy
     R103 by routing every read through ``tracer.clock()``.
 
-    ``jax_profiler=True`` additionally brackets each synchronous span in
-    a ``jax.profiler.TraceAnnotation`` so host spans line up with device
-    timelines in on-TPU profiles; jax is imported only when it is asked
-    for, so a tracer without it (and tools/trace_view.py) needs no jax.
+    ``jax_profiler=True`` additionally brackets each :meth:`span` in a
+    ``jax.profiler.TraceAnnotation`` of the same name, carrying the args
+    given at entry, so host spans share the device's clock in on-TPU
+    profiles (:meth:`complete` spans do not: they are timed after the
+    fact). jax is imported only when it is asked for, so a tracer without
+    it (and tools/trace_view.py) needs no jax.
     """
 
     def __init__(
@@ -135,11 +147,12 @@ class Tracer:
         self.events_total += 1
 
     def span(self, name: str, **args: Any):
-        """Context manager timing one synchronous region."""
+        """Context manager timing one synchronous region. The object it
+        returns takes ``set_arg(key, value)`` before the region closes."""
         if not self.enabled:
             return _NULL_SPAN
         if self._annotation is not None:
-            return _AnnotatedSpan(self, name, args or None, self._annotation(name))
+            return _AnnotatedSpan(self, name, args or None, self._annotation(name, **args))
         return _Span(self, name, args or None)
 
     def complete(self, name: str, t0: float, t1: float, **args: Any) -> None:
@@ -266,11 +279,6 @@ class Tracer:
     def dump_chrome(self, path: str) -> None:
         with open(path, "w") as f:
             json.dump(self.to_chrome(), f)
-
-    def dump_jsonl(self, path: str) -> None:
-        with open(path, "w") as f:
-            for e in self.events:
-                f.write(json.dumps(e) + "\n")
 
 
 class _AnnotatedSpan(_Span):

@@ -11,7 +11,11 @@ import jax.numpy as jnp
 
 def lm_loss(model, params, batch, *, z_loss: float = 0.0, aux_weight: float = 0.01):
     logits, aux = model.forward(params, batch)  # (B,S,V) f32
-    tokens = batch["tokens"]
+    with jax.named_scope("head"):
+        return _cross_entropy(logits, aux, batch["tokens"], z_loss, aux_weight)
+
+
+def _cross_entropy(logits, aux, tokens, z_loss, aux_weight):
     labels = jnp.concatenate([tokens[:, 1:], tokens[:, -1:]], axis=1)
     mask = jnp.concatenate(
         [jnp.ones_like(tokens[:, 1:], jnp.float32), jnp.zeros_like(tokens[:, -1:], jnp.float32)],

@@ -53,6 +53,19 @@ def clip_by_global_norm(grads, max_norm: float):
     return jax.tree.map(lambda g: g * scale, grads), norm
 
 
+def _sq_norm(tree):
+    return sum(jnp.sum(jnp.square(x.astype(jnp.float32))) for x in jax.tree.leaves(tree))
+
+
+def _with_grad_sq_big(metrics, grads):
+    """Add ‖mean grad‖² (the GNS estimator's big-batch norm) where the step
+    accumulated over microbatches."""
+    if "grad_sq_small" not in metrics:
+        return metrics
+    with jax.named_scope("grad_accumulate"):
+        return dict(metrics, grad_sq_big=_sq_norm(grads))
+
+
 def _grads_over_microbatches(model, params, batch, accum_steps, z_loss, vary_axes=()):
     """Mean loss/grads over the (accum, micro, ...) leading axes of batch.
 
@@ -67,10 +80,11 @@ def _grads_over_microbatches(model, params, batch, accum_steps, z_loss, vary_axe
     def body(acc, mb):
         gsum, lsum, asum, sqsum = acc
         (_, m), g = jax.value_and_grad(loss_fn, has_aux=True)(params, mb)
-        # per-microbatch squared grad norm — feeds the McCandlish
-        # gradient-noise-scale estimator (core/noise_scale.py) for free
-        sq = sum(jnp.sum(jnp.square(x.astype(jnp.float32))) for x in jax.tree.leaves(g))
-        return (tree_add(gsum, g), lsum + m["loss"], asum + m["aux"], sqsum + sq), None
+        with jax.named_scope("grad_accumulate"):
+            # per-microbatch squared grad norm — feeds the McCandlish
+            # gradient-noise-scale estimator (core/noise_scale.py) for free
+            sq = _sq_norm(g)
+            return (tree_add(gsum, g), lsum + m["loss"], asum + m["aux"], sqsum + sq), None
 
     if accum_steps < 0:  # unrolled python loop (mode="unrolled"): XLA can
         # hoist loop-invariant weight all-gathers and defer the gradient
@@ -90,7 +104,8 @@ def _grads_over_microbatches(model, params, batch, accum_steps, z_loss, vary_axe
     if vary_axes:
         carry0 = jax.tree.map(lambda x: jax.lax.pvary(x, tuple(vary_axes)), carry0)
     (gsum, lsum, asum, sqsum), _ = jax.lax.scan(body, carry0, batch)
-    grads = tree_scale(gsum, 1.0 / accum_steps)
+    with jax.named_scope("grad_accumulate"):
+        grads = tree_scale(gsum, 1.0 / accum_steps)
     metrics = {
         "loss": lsum / accum_steps,
         "aux": asum / accum_steps,
@@ -121,6 +136,7 @@ def build_train_step(
         mode = "psum_each"
     batch_axes = mesh_data_axes(mesh)
 
+    @jax.named_scope("optimizer")
     def apply_update(state: TrainState, grads, lr, stage):
         grads, gnorm = clip_by_global_norm(grads, grad_clip)
         new_params, new_opt = optimizer.update(
@@ -134,11 +150,7 @@ def build_train_step(
             grads, metrics = _grads_over_microbatches(
                 model, state.params, batch, accum_steps, z_loss
             )
-            if "grad_sq_small" in metrics:
-                metrics = dict(metrics, grad_sq_big=sum(
-                    jnp.sum(jnp.square(g.astype(jnp.float32)))
-                    for g in jax.tree.leaves(grads)
-                ))
+            metrics = _with_grad_sq_big(metrics, grads)
             new_state, gnorm = apply_update(state, grads, lr, stage)
             metrics = dict(metrics, grad_norm=gnorm)
             return new_state, metrics
@@ -160,11 +172,7 @@ def build_train_step(
             # is the only gradient synchronization.
             grads = jax.lax.pmean(grads, batch_axes)  # repro-lint: disable=R101 -- mesh width is fixed for this executable's lifetime; cross-width bit-identity is repro.distributed's contract (span_tree_sum), not this deferred path's
             metrics = jax.lax.pmean(metrics, batch_axes)  # repro-lint: disable=R101 -- same fixed-width executable as the grads pmean above
-            if "grad_sq_small" in metrics:
-                metrics = dict(metrics, grad_sq_big=sum(
-                    jnp.sum(jnp.square(g.astype(jnp.float32)))
-                    for g in jax.tree.leaves(grads)
-                ))
+            metrics = _with_grad_sq_big(metrics, grads)
             new_state, gnorm = apply_update(state, grads, lr, stage)
             metrics = dict(metrics, grad_norm=gnorm)
             return new_state, metrics

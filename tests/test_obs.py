@@ -152,20 +152,17 @@ def test_export_roundtrips_through_trace_view(tmp_path):
     tr.mark_request(0, "prefill_done")
     tr.mark_request(0, "first_token")
     tr.end_request(0)
-    chrome, jsonl = tmp_path / "t.json", tmp_path / "t.jsonl"
+    chrome = tmp_path / "t.json"
     tr.dump_chrome(str(chrome))
-    tr.dump_jsonl(str(jsonl))
     sys.path.insert(0, str(REPO / "tools"))
     try:
         import trace_view
     finally:
         sys.path.pop(0)
-    ev_c, fmt_c = trace_view.load_events(str(chrome))
-    ev_j, fmt_j = trace_view.load_events(str(jsonl))
-    assert fmt_c == "chrome" and fmt_j == "jsonl"
-    assert len(ev_c) == len(ev_j) == tr.events_total
-    # both formats normalize to seconds and agree (chrome rounds to ns)
-    for a, b in zip(ev_c, ev_j):
+    ev_c = trace_view.load_events(str(chrome))
+    assert len(ev_c) == tr.events_total
+    # normalized back to seconds, the tracer's own events (chrome rounds to ns)
+    for a, b in zip(ev_c, tr.events):
         assert a["ph"] == b["ph"] and a["name"] == b["name"]
         assert a["ts"] == pytest.approx(b["ts"], abs=1e-9)
     summary = trace_view.summarize(ev_c)
@@ -417,6 +414,132 @@ def test_disagg_tokens_identical_with_tracing_on():
     assert metrics.counter("serve.seam_bytes").value == eng.stats["seam_bytes"]
     assert len(tracer.durations("serve.stream")) == eng.stats["transfers"]
     assert tracer.depth == 0 and tracer.open_requests == 0
+
+
+class _StubAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs entries/exits."""
+
+    log: list = []
+
+    def __init__(self, name, **kwargs):
+        self.name, self.kwargs = name, kwargs
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, self.kwargs))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, None))
+
+
+def test_annotated_spans_enter_and_exit_in_nesting_order(monkeypatch):
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _StubAnnotation)
+    monkeypatch.setattr(_StubAnnotation, "log", [])
+    tr = Tracer(clock=FakeClock(), jax_profiler=True)
+    with tr.span("outer", update=3, stage=1) as sp:
+        with tr.span("inner"):
+            pass
+        with tr.span("inner2"):
+            tr.instant("not-annotated")
+        sp.set_arg("loss", 2.5)
+    tr.complete("host-only", 0.0, 1.0)  # timed after the fact: no annotation
+    assert _StubAnnotation.log == [
+        ("enter", "outer", {"update": 3, "stage": 1}),
+        ("enter", "inner", {}), ("exit", "inner", None),
+        ("enter", "inner2", {}), ("exit", "inner2", None),
+        ("exit", "outer", None),
+    ]
+    outer = [e for e in tr.events if e["name"] == "outer"][0]
+    assert outer["args"] == {"update": 3, "stage": 1, "loss": 2.5}
+    assert tr.depth == 0
+    # the disabled span takes set_arg too, and still records nothing
+    NULL_TRACER.span("x").set_arg("loss", 1.0)
+    assert NULL_TRACER.events_total == 0
+
+
+def _tiny_trainer(**obs):
+    """The smoke qwen2.5-3b under SEBS in accumulate mode (microbatch 2,
+    so stage 1 accumulates and reads the noise-scale norms)."""
+    cfg, model, params = _setup()
+    optimizer = make_optimizer("psgd", gamma=1e4)
+    sched = SEBS(b1=2, C1=6, rho=2.0, num_stages=2, eta=0.05)
+    ds = TokenDataset(vocab_size=cfg.vocab_size, seq_len=16, seed=0)
+    trainer = SEBSTrainer(model, optimizer, sched, DataPipeline(ds),
+                          microbatch=2, mode="accumulate", **obs)
+    state = TrainState(params, optimizer.init(params), jnp.zeros((), jnp.int32))
+    return trainer, state
+
+
+def test_trainer_phase_spans_nest_per_update(monkeypatch):
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _StubAnnotation)
+    monkeypatch.setattr(_StubAnnotation, "log", [])
+    tracer, metrics = Tracer(clock=FakeClock(), jax_profiler=True), MetricsRegistry()
+    trainer, state = _tiny_trainer(tracer=tracer, metrics=metrics)
+    _, log = trainer.run(state, log_every=1)
+    n = len(log.steps)
+    assert n == 6 and log.stages == [0, 0, 0, 1, 1, 1]
+    assert tracer.depth == 0
+    spans = [e for e in tracer.events if e["ph"] == "X"]
+    # recorded as each closes: the three phases, their update, then after
+    per_update = ["train.data", "train.dispatch", "train.wait", "train.update",
+                  "train.after"]
+    assert [e["name"] for e in spans] == per_update * n
+    for i in range(n):
+        data, dispatch, wait, update, after = spans[5 * i: 5 * i + 5]
+        end = update["ts"] + update["dur"]
+        for child in (data, dispatch, wait):
+            assert update["ts"] <= child["ts"] and child["ts"] + child["dur"] <= end
+        assert data["ts"] + data["dur"] <= dispatch["ts"]
+        assert dispatch["ts"] + dispatch["dur"] <= wait["ts"]
+        assert after["ts"] >= end
+        assert update["args"] == {"update": i + 1, "stage": log.stages[i],
+                                  "batch": log.batch_sizes[i], "loss": log.losses[i]}
+    # every annotation closed in nesting order, update and stage on the update's
+    stack = []
+    for kind, name, kwargs in _StubAnnotation.log:
+        if kind == "enter":
+            stack.append(name)
+            if name == "train.update":
+                assert {"update", "stage"} <= set(kwargs)
+        else:
+            assert stack.pop() == name
+    assert not stack
+    assert sum(1 for k, name, _ in _StubAnnotation.log
+               if k == "enter" and name == "train.update") == n
+    # a trainer that does not communicate emits no comm gauges or track
+    assert not any(e["name"] == "train.comm" for e in tracer.events)
+    assert not any(k.startswith(("train.comm_bytes", "train.sync_events"))
+                   for k in metrics.snapshot())
+
+
+def test_trainer_disabled_tracer_records_nothing_and_losses_match():
+    trainer, state = _tiny_trainer(tracer=Tracer(enabled=False))
+    _, off = trainer.run(state, log_every=1)
+    assert trainer.tracer.events_total == 0 and trainer.tracer.depth == 0
+    trainer, state = _tiny_trainer(tracer=Tracer(clock=FakeClock()))
+    _, on = trainer.run(state, log_every=1)
+    assert trainer.tracer.events_total > 0
+    assert on.losses == off.losses  # bit-identical, not approx
+    assert on.noise_scales[-1] == off.noise_scales[-1]
+
+
+def test_train_step_ops_carry_layer_scopes():
+    """The step's parts are named scopes: each appears in the compiled
+    program's op_name metadata, which is what a device profile shows."""
+    import re
+
+    from repro.train.step import build_train_step
+
+    cfg, model, params = _setup()
+    optimizer = make_optimizer("psgd", gamma=1e4)
+    state = TrainState(params, optimizer.init(params), jnp.zeros((), jnp.int32))
+    step = build_train_step(model, optimizer, None, accum_steps=2)
+    batch = {"tokens": jnp.zeros((2, 2, 16), jnp.int32)}
+    text = step.lower(state, batch, jnp.float32(0.1), jnp.int32(0)).compile().as_text()
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("embed", "attention", "mlp", "final_norm", "head",
+                  "grad_accumulate", "optimizer"):
+        pattern = re.compile(r"(^|[/(])" + scope + r"([/)]|$)")
+        assert any(pattern.search(n) for n in op_names), scope
 
 
 def test_trainer_losses_bit_identical_with_metrics_on():

@@ -4,10 +4,9 @@
     PYTHONPATH=src python tools/trace_view.py /tmp/serve_trace.json
     PYTHONPATH=src python tools/trace_view.py --json /tmp/train_trace.json
 
-Accepts either export format of :class:`repro.obs.trace.Tracer`: a Chrome
+Reads the export of :class:`repro.obs.trace.Tracer`: a Chrome
 ``trace_event`` JSON object (``{"traceEvents": [...]}``, timestamps in µs —
-the Perfetto-loadable artifact) or raw JSONL (one event per line,
-timestamps in seconds). The trace is validated structurally first — a
+the Perfetto-loadable artifact). The trace is validated structurally first — a
 malformed file (bad JSON, events missing required fields, a complete span
 without ``dur``, an async event without ``id``) exits nonzero, which is
 what the CI obs-smoke job gates on.
@@ -28,7 +27,7 @@ import json
 import sys
 from collections import defaultdict
 from pathlib import Path
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional
 
 _REPO_ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(_REPO_ROOT / "src"))
@@ -45,42 +44,24 @@ _ASYNC = ("b", "n", "e")
 _KNOWN = ("X", "i", "C") + _ASYNC
 
 
-def load_events(path: str) -> Tuple[List[Dict[str, Any]], str]:
-    """Parse a chrome or JSONL trace into (events, format). Timestamps are
-    normalized to SECONDS regardless of input format."""
+def load_events(path: str) -> List[Dict[str, Any]]:
+    """Parse a chrome trace into events, timestamps in SECONDS."""
     try:
         text = Path(path).read_text()
     except OSError as e:
         raise TraceError(f"cannot read {path}: {e}") from e
     if not text.strip():
         raise TraceError(f"{path} is empty")
-    # a JSONL line is itself a JSON object, so "starts with {" cannot tell
-    # the formats apart: a chrome trace is ONE document, JSONL is one per line
     try:
         obj = json.loads(text)
-    except json.JSONDecodeError as whole_err:
-        events = []
-        for lineno, line in enumerate(text.splitlines(), 1):
-            if not line.strip():
-                continue
-            try:
-                events.append(json.loads(line))
-            except json.JSONDecodeError:
-                raise TraceError(
-                    f"{path}: neither chrome trace JSON ({whole_err}) nor "
-                    f"JSONL (line {lineno} is not a JSON object)"
-                ) from whole_err
-        scale, fmt = 1.0, "jsonl"
-    else:
-        if isinstance(obj, dict) and "traceEvents" not in obj and "ph" in obj:
-            return _validated([obj], 1.0), "jsonl"  # single-event JSONL
-        if not isinstance(obj, dict) or "traceEvents" not in obj:
-            raise TraceError(f"{path}: chrome trace must be an object with 'traceEvents'")
-        events = obj["traceEvents"]
-        if not isinstance(events, list):
-            raise TraceError(f"{path}: 'traceEvents' must be a list")
-        scale, fmt = 1e-6, "chrome"
-    return _validated(events, scale), fmt
+    except json.JSONDecodeError as e:
+        raise TraceError(f"{path}: not chrome trace JSON ({e})") from e
+    if not isinstance(obj, dict) or "traceEvents" not in obj:
+        raise TraceError(f"{path}: chrome trace must be an object with 'traceEvents'")
+    events = obj["traceEvents"]
+    if not isinstance(events, list):
+        raise TraceError(f"{path}: 'traceEvents' must be a list")
+    return _validated(events, 1e-6)
 
 
 def _validated(events: List[Any], scale: float) -> List[Dict[str, Any]]:
@@ -217,20 +198,19 @@ def render(summary: Dict[str, Any]) -> str:
 
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(prog="trace_view", description=__doc__.split("\n")[0])
-    ap.add_argument("trace", help="chrome trace JSON or raw JSONL from repro.obs")
+    ap.add_argument("trace", help="chrome trace JSON from repro.obs")
     ap.add_argument("--json", action="store_true", help="machine-readable summary")
     args = ap.parse_args(argv)
     try:
-        events, fmt = load_events(args.trace)
+        events = load_events(args.trace)
     except TraceError as e:
         print(f"trace_view: MALFORMED: {e}", file=sys.stderr)
         return 2
     summary = summarize(events)
-    summary["format"] = fmt
     if args.json:
         print(json.dumps(summary, indent=2, sort_keys=True))
     else:
-        print(f"{args.trace} [{fmt}] OK")
+        print(f"{args.trace} OK")
         print(render(summary))
     return 0
 
