@@ -40,7 +40,7 @@ from repro.obs.metrics import NULL_METRICS, MetricsRegistry
 from repro.obs.trace import NULL_TRACER, Tracer
 from repro.optim.base import Optimizer
 from repro.train.state import TrainState
-from repro.train.step import build_train_step
+from repro.train.step import build_train_step, unrolls
 
 
 @dataclass
@@ -204,6 +204,10 @@ class SEBSTrainer:
         """Called after each update (local-SGD averaging, comm accounting)."""
         return state
 
+    def _step_unrolled(self, plan: StepPlan) -> bool:
+        """Whether this update's step ran its microbatch loop unrolled."""
+        return unrolls(plan.accum_steps, self.accum_mode)
+
     def _comm_counters(self) -> tuple[int, int]:
         """(cumulative bytes per device, cumulative sync events) for the log."""
         return 0, 0
@@ -292,6 +296,8 @@ class SEBSTrainer:
                 ).observe(t1 - t0)
                 self.metrics.counter("train.updates").inc()
                 self.metrics.counter("train.samples").inc(plan.batch_size)
+                if self._step_unrolled(plan):
+                    self.metrics.counter("train.updates_unrolled").inc()
                 if sanitize.enabled():
                     sanitize.check_finite_update(
                         dict(metrics, loss=loss), update=update, stage=plan.stage

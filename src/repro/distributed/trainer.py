@@ -195,6 +195,9 @@ class ElasticTrainer(SEBSTrainer):
             }
         return state, metrics
 
+    def _step_unrolled(self, plan: StepPlan) -> bool:
+        return False  # the elastic steps sum microbatches in their own loop
+
     def _after_update(self, state: TrainState, update: int, plan: StepPlan) -> TrainState:
         mp = self._mp
         self._updates_done = update

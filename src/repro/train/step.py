@@ -15,19 +15,24 @@ Two gradient-accumulation execution modes (DESIGN.md §5):
 
 ``accum_steps`` is static per compilation; SEBS's ``accumulate`` mode
 therefore compiles one step per stage (S ≈ 3–5 total compilations).
+
+Up to ``UNROLL_MAX_ACCUM`` microbatches the scan is unrolled into
+straight-line code: the f32 gradient sum then fuses into the weight-gradient
+dots and the last add and the 1/n scale into the optimizer update, where a
+rolled loop writes each microbatch's gradient and reads it back.
+``mode="unrolled"`` is ``psum_each`` unrolled at every width.
 """
 from __future__ import annotations
 
-import functools
-from typing import Any, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.sharding import batch_spec, mesh_data_axes, named_sharding
+from repro.sharding import mesh_data_axes
 from repro.train.loss import lm_loss
-from repro.train.state import TrainState, is_axes_leaf, state_axes
+from repro.train.state import TrainState
 from repro.utils.tree import tree_add, tree_scale
 
 
@@ -66,12 +71,20 @@ def _with_grad_sq_big(metrics, grads):
         return dict(metrics, grad_sq_big=_sq_norm(grads))
 
 
-def _grads_over_microbatches(model, params, batch, accum_steps, z_loss, vary_axes=()):
-    """Mean loss/grads over the (accum, micro, ...) leading axes of batch.
+# Compiled for a v5e, the qwen2.5-3b 4-layer step fits unrolled over 4
+# microbatches; over 8 it runs out of HBM, and unrolled by 4 it needs 12.8 GB
+# of temporaries where the rolled loop needs 7.1.
+UNROLL_MAX_ACCUM = 4
 
-    ``vary_axes``: only needed when called inside a check_vma=True shard_map
-    (the scan's zero carries must carry the varying annotation); the
-    deferred train step runs with check_vma=False and leaves it empty."""
+
+def unrolls(accum_steps: int, mode: str = "deferred") -> bool:
+    """Whether the step built for ``accum_steps`` and ``mode`` runs its
+    microbatch loop unrolled."""
+    return accum_steps > 1 and (mode == "unrolled" or accum_steps <= UNROLL_MAX_ACCUM)
+
+
+def _grads_over_microbatches(model, params, batch, accum_steps, z_loss, unroll):
+    """Mean loss/grads over the (accum, micro, ...) leading axes of batch."""
     loss_fn = lambda p, mb: lm_loss(model, p, mb, z_loss=z_loss)
     if accum_steps == 1:
         (_, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
@@ -86,24 +99,9 @@ def _grads_over_microbatches(model, params, batch, accum_steps, z_loss, vary_axe
             sq = _sq_norm(g)
             return (tree_add(gsum, g), lsum + m["loss"], asum + m["aux"], sqsum + sq), None
 
-    if accum_steps < 0:  # unrolled python loop (mode="unrolled"): XLA can
-        # hoist loop-invariant weight all-gathers and defer the gradient
-        # all-reduce past the accumulation sum (partial-sum propagation)
-        n = -accum_steps
-        gsum = jax.tree.map(lambda w: jnp.zeros(w.shape, jnp.float32), params)
-        lsum = asum = sqsum = jnp.zeros((), jnp.float32)
-        for i in range(n):
-            mb = jax.tree.map(lambda x: x[i], batch)
-            (gsum, lsum, asum, sqsum), _ = body((gsum, lsum, asum, sqsum), mb)
-        grads = tree_scale(gsum, 1.0 / n)
-        return grads, {"loss": lsum / n, "aux": asum / n, "grad_sq_small": sqsum / n}
-
     zeros = jax.tree.map(lambda w: jnp.zeros(w.shape, jnp.float32), params)
     z = jnp.zeros((), jnp.float32)
-    carry0 = (zeros, z, z, z)
-    if vary_axes:
-        carry0 = jax.tree.map(lambda x: jax.lax.pvary(x, tuple(vary_axes)), carry0)
-    (gsum, lsum, asum, sqsum), _ = jax.lax.scan(body, carry0, batch)
+    (gsum, lsum, asum, sqsum), _ = jax.lax.scan(body, (zeros, z, z, z), batch, unroll=unroll)
     with jax.named_scope("grad_accumulate"):
         grads = tree_scale(gsum, 1.0 / accum_steps)
     metrics = {
@@ -131,8 +129,8 @@ def build_train_step(
     Batch leaves are (B, ...) when accum_steps == 1, else (accum, micro, ...).
     """
     assert mode in ("deferred", "psum_each", "unrolled")
-    if mode == "unrolled" and accum_steps > 1:
-        accum_steps = -accum_steps  # flag for the unrolled python loop
+    unroll = unrolls(accum_steps, mode)
+    if mode == "unrolled":
         mode = "psum_each"
     batch_axes = mesh_data_axes(mesh)
 
@@ -148,7 +146,7 @@ def build_train_step(
 
         def step(state, batch, lr, stage):
             grads, metrics = _grads_over_microbatches(
-                model, state.params, batch, accum_steps, z_loss
+                model, state.params, batch, accum_steps, z_loss, unroll
             )
             metrics = _with_grad_sq_big(metrics, grads)
             new_state, gnorm = apply_update(state, grads, lr, stage)
@@ -163,7 +161,7 @@ def build_train_step(
 
         def local_step(state, batch, lr, stage):
             grads, metrics = _grads_over_microbatches(
-                model, state.params, batch, accum_steps, z_loss
+                model, state.params, batch, accum_steps, z_loss, unroll
             )
             # THE deferred all-reduce: grads stay device-local through the
             # whole microbatch scan (check_vma=False → no automatic psum at

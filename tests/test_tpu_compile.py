@@ -1,6 +1,7 @@
-"""Compile the serving hot path for a TPU v5e chip that is described, not
-attached: the three paged-decode Pallas kernels (``interpret=False``) and one
-paged decode tick of the XLA path, at qwen2.5-3b widths.
+"""Compile the hot paths for a TPU v5e chip that is described, not attached:
+the three paged-decode Pallas kernels (``interpret=False``), one paged decode
+tick of the XLA path and the SEBS accumulate-4 train step, at qwen2.5-3b
+widths.
 
 Interpret mode cannot see what the chip's compiler refuses (block shapes off
 the (8, 128) tiling, VMEM overuse, HBM overuse); these compiles do. The
@@ -8,6 +9,9 @@ topology is described only inside a fixture: only one process at a time may
 load the TPU library, and a module-level call would make the test workers
 collect different tests.
 """
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -16,7 +20,10 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.paged_decode import ops
 from repro.models import build_model
+from repro.optim import make_optimizer
 from repro.serve.step import build_paged_decode_step
+from repro.train.state import TrainState
+from repro.train.step import build_train_step
 
 CFG = get_config("qwen2.5-3b", "full").replace(param_dtype="bfloat16")
 SLOTS, PAGE, MAX_PAGES, CHUNK = 8, 16, 18, 128
@@ -102,3 +109,30 @@ def test_paged_decode_tick_xla_compiles(spec):
     ).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15 * 2**30
+
+
+def test_accumulate4_train_step_compiles_unrolled(spec):
+    """The SEBS stage-2 step (4 layers, f32 params, bf16 compute, psgd,
+    4 microbatches of 2 x 512) runs its microbatches as straight-line code,
+    no microbatch ``while``, and fits one chip."""
+    cfg = get_config("qwen2.5-3b", "full")
+    (seg,) = cfg.segments
+    cfg = cfg.replace(segments=(dataclasses.replace(seg, repeat=4),),
+                      param_dtype="float32", compute_dtype="bfloat16")
+    model = build_model(cfg)
+    optimizer = make_optimizer("psgd", gamma=1e4)
+
+    def shapes(tree):
+        return jax.tree.map(lambda x: spec(x.shape, x.dtype), tree)
+
+    def init():
+        params, _ = model.init(jax.random.key(0))
+        return TrainState(params, optimizer.init(params), jnp.zeros((), jnp.int32))
+
+    state = shapes(jax.eval_shape(init))
+    step = build_train_step(model, optimizer, accum_steps=4)
+    compiled = step.lower(state, {"tokens": spec((4, 2, 512), jnp.int32)},
+                          spec((), jnp.float32), spec((), jnp.int32)).compile()
+    assert not re.search(r'op_name="jit\(step\)/while"', compiled.as_text())
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes <= 12.3e9

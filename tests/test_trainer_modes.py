@@ -8,6 +8,7 @@ from repro.configs import get_config
 from repro.core import SEBS, DBSGD, SEBSTrainer
 from repro.data import DataPipeline, TokenDataset
 from repro.models import build_model
+from repro.obs.metrics import MetricsRegistry
 from repro.optim import make_optimizer
 from repro.train.state import TrainState
 
@@ -47,6 +48,21 @@ def test_unrolled_accum_mode_runs():
     trainer, state = _trainer(sched, "accumulate", accum_mode="unrolled")
     state, log = trainer.run(state, log_every=1)
     assert all(np.isfinite(log.losses))
+
+
+@pytest.mark.parametrize("accum_mode", ["psum_each", "unrolled"])
+def test_updates_unrolled_counter(accum_mode):
+    """`train.updates_unrolled` counts the updates at accumulate 2-4 (any
+    width above 1 in unrolled mode), never the accumulate-1 ones."""
+    sched = SEBS(b1=4, C1=32, rho=2.0, num_stages=3, eta=0.05)
+    trainer, state = _trainer(sched, "accumulate", accum_mode=accum_mode)
+    trainer.metrics = MetricsRegistry()
+    state, log = trainer.run(state, log_every=1)
+    accums = [b // 4 for b in log.batch_sizes]
+    assert sorted(set(accums)) == [1, 2, 4]
+    unrolled = trainer.metrics.counter("train.updates_unrolled").value
+    assert unrolled == sum(a > 1 for a in accums)
+    assert trainer.metrics.counter("train.updates").value == len(accums)
 
 
 def test_dbsgd_schedule_through_trainer():
