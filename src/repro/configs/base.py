@@ -87,6 +87,12 @@ class ModelConfig:
     attn_logit_softcap: Optional[float] = None  # gemma2: 50.0
     final_logit_softcap: Optional[float] = None  # gemma2: 30.0
     sliding_window: int = 4096  # window used by "swa" mixers
+    rope: bool = True  # False: no positional encoding (granite-4.0-h's NoPE)
+    attn_scale: Optional[float] = None  # softmax scale; None → 1/sqrt(head_dim)
+    # residual-path multipliers (granite-4.0-h); 1.0 leaves a program as it is
+    embed_scale: float = 1.0  # multiplies the token embeddings
+    residual_scale: float = 1.0  # multiplies each mixer/FFN branch before its add
+    logit_scale: float = 1.0  # divides the logits
     attn_chunk: Optional[int] = 1024  # flash-style query chunking for the
     #   pure-JAX path: memory O(S·chunk) instead of O(S²). None → dense
     #   (used by the roofline cost compiles, where while-loop bodies would

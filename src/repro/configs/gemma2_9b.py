@@ -20,6 +20,7 @@ CONFIG = ModelConfig(
     vocab_size=256000,
     attn_logit_softcap=50.0,
     final_logit_softcap=30.0,
+    embed_scale=3584**0.5,  # embeddings times sqrt(d_model)
     sliding_window=4096,
     segments=(SegmentSpec(body=_BODY, repeat=21),),
 )
@@ -44,6 +45,6 @@ def smoke() -> ModelConfig:
     return CONFIG.replace(
         name="gemma2-9b-smoke",
         d_model=256, num_heads=4, num_kv_heads=2, head_dim=64, d_ff=512,
-        vocab_size=512,
+        vocab_size=512, embed_scale=256**0.5,
         segments=(SegmentSpec(body=_BODY, repeat=1),),
     )

@@ -2,7 +2,8 @@
 
 Each assigned architecture module defines ``CONFIG`` (the exact published
 shape, cited) and ``smoke()`` (a reduced same-family variant: ≤2 layers,
-d_model ≤ 512, ≤ 4 experts) for CPU tests.
+d_model ≤ 512, ≤ 4 experts; a hybrid keeps one whole period of its layer
+pattern) for CPU tests.
 """
 from __future__ import annotations
 
@@ -17,6 +18,7 @@ ARCHS: List[str] = [
     "gemma2-9b",
     "rwkv6-1.6b",
     "zamba2-2.7b",
+    "granite-4.0-h-micro",
     "arctic-480b",
     "whisper-tiny",
     "dbrx-132b",

@@ -1,6 +1,7 @@
 """Decoder blocks and scanned segments.
 
-A *block* is (pre-norm → mixer → residual, pre-norm → ffn → residual). A
+A *block* is (pre-norm → mixer → residual, pre-norm → ffn → residual), each
+branch times ``cfg.residual_scale`` before its add. A
 *segment* is ``repeat`` iterations of a tuple of blocks (the "body"),
 executed with ``lax.scan`` over weights stacked on a leading ``layers``
 axis — HLO stays O(1) in depth, which keeps the 95-layer deepseek-67b and
@@ -27,6 +28,13 @@ from repro.utils.tree import tree_stack
 # ---------------------------------------------------------------------------
 # single block
 # ---------------------------------------------------------------------------
+
+
+def _branch(cfg: ModelConfig, y):
+    """A mixer or FFN output as it joins the residual stream."""
+    if cfg.residual_scale == 1.0:
+        return y
+    return y * jnp.asarray(cfg.residual_scale, y.dtype)
 
 
 def init_block(key, cfg: ModelConfig, spec: BlockSpec, name: str):
@@ -148,14 +156,14 @@ def apply_block(
             if new_cache is not None and attn_cache is not None:
                 new_cache["attn"] = attn_cache
             y = checkpoint_name(y, "mixer_out")
-            x = x + y
+            x = x + _branch(cfg, y)
             if spec.mixer == "cross_attn_block" and memory is not None:
                 hx = norm.apply(params["norm_cross"], x, cfg.norm_eps)
                 yx, _ = attention.apply(
                     params["cross_attn"], hx, cfg, positions=positions,
                     causal=False, memory=memory,
                 )
-                x = x + yx
+                x = x + _branch(cfg, yx)
         elif spec.mixer == "mamba2":
             y, mcache = mamba2.apply(
                 params["mamba"], h, cfg,
@@ -165,34 +173,34 @@ def apply_block(
             if new_cache is not None and mcache is not None:
                 new_cache["mamba"] = mcache
             y = checkpoint_name(y, "mixer_out")
-            x = x + y
+            x = x + _branch(cfg, y)
         elif spec.mixer == "rwkv6":
             rc = None if cache is None else cache.get("rwkv")
             y, wkv, shift_t = rwkv6.apply_time_mix(params["tmix"], h, cfg, cache=rc, decode=decode)
             if new_cache is not None:
                 new_cache["rwkv"] = dict(new_cache.get("rwkv", {}))
                 new_cache["rwkv"].update({"wkv": wkv, "shift_t": shift_t})
-            x = x + y
+            x = x + _branch(cfg, y)
 
     if spec.ffn == "none":
         return x, new_cache, aux
     with jax.named_scope("mlp" if spec.ffn == "dense" else spec.ffn):
         h = norm.apply(params["norm2"], x, cfg.norm_eps)
         if spec.ffn == "dense":
-            x = x + checkpoint_name(mlp.apply(params["mlp"], h, cfg), "ffn_out")
+            x = x + _branch(cfg, checkpoint_name(mlp.apply(params["mlp"], h, cfg), "ffn_out"))
         elif spec.ffn == "moe":
             y, moe_aux = moe.apply(params["moe"], h, cfg)
             aux = aux + moe_aux
             if cfg.moe_dense_residual:
                 y = y + mlp.apply(params["mlp"], h, cfg)
-            x = x + checkpoint_name(y, "ffn_out")
+            x = x + _branch(cfg, checkpoint_name(y, "ffn_out"))
         elif spec.ffn == "rwkv_cmix":
             rc = None if cache is None else cache.get("rwkv")
             y, shift_c = rwkv6.apply_channel_mix(params["cmix"], h, cfg, cache=rc)
             if new_cache is not None:
                 new_cache["rwkv"] = dict(new_cache.get("rwkv", {}))
                 new_cache["rwkv"]["shift_c"] = shift_c
-            x = x + y
+            x = x + _branch(cfg, y)
     return x, new_cache, aux
 
 

@@ -1,7 +1,8 @@
 """Grouped-query attention with the features the assigned archs need:
 
 - GQA (num_kv_heads <= num_heads), optional QKV bias (qwen2.5),
-- rotary embeddings,
+- rotary embeddings, or none (``cfg.rope`` False: NoPE),
+- the config's softmax scale (``cfg.attn_scale``; default 1/sqrt(head_dim)),
 - causal / sliding-window (gemma2 local, long-context dense variant) masks,
 - attention logit soft-capping (gemma2),
 - cross-attention (whisper decoder),
@@ -174,6 +175,9 @@ def _sdpa_chunked(q, k, v, cfg, *, chunk: int, causal: bool, window: Optional[in
     Memory per block: (B, heads, chunk, S) logits instead of (B, heads, S, S)
     — the pure-JAX stand-in for the Pallas flash kernel's VMEM tiling (the
     kernel is used on real TPU; this path keeps CPU/compile memory honest).
+    Each block is rematerialised in the backward, as a flash backward
+    recomputes its scores, so the gradient keeps one block's scores and not
+    every block's.
     """
     b, s, hq, hd = q.shape
     nc = s // chunk
@@ -181,6 +185,7 @@ def _sdpa_chunked(q, k, v, cfg, *, chunk: int, causal: bool, window: Optional[in
     qc = jnp.moveaxis(q.reshape(b, nc, chunk, hq, hd), 1, 0)  # (nc,B,chunk,hq,hd)
     k_pos = jnp.arange(s)[None, :]
 
+    @jax.checkpoint
     def body(_, args):
         i, qblk = args
         q_pos = i * chunk + jnp.arange(chunk)[None, :]
@@ -228,8 +233,12 @@ def apply(
     chunked = cache is not None and s > 1 and page_table is not None and memory is None
     q, k, v = _project_qkv(params, x, memory, cfg)
     q = constrain(q, ("batch", "seq", "heads", None))
+    if cfg.attn_scale is not None:
+        # every path (einsum, flash, paged) scales q.k by head_dim**-0.5:
+        # folding the rest into q gives the config's scale on all of them
+        q = q * jnp.asarray(cfg.attn_scale * cfg.resolved_head_dim**0.5, q.dtype)
 
-    if memory is None:
+    if memory is None and cfg.rope:
         q = rope.apply_rope(q, positions, cfg.rope_theta)
         if decode or chunked:
             k = rope.apply_rope(k, positions, cfg.rope_theta)

@@ -1,6 +1,7 @@
 """Token embedding and logit head with vocab padding (so the vocabulary
 dimension shards cleanly over the 16-way ``model`` axis, e.g. whisper's
-51865 → 51968) and gemma-style final-logit soft-capping."""
+51865 → 51968), the config's embedding multiplier and logit divisor, and
+gemma-style final-logit soft-capping."""
 from __future__ import annotations
 
 import jax
@@ -24,8 +25,8 @@ def init(key, cfg, name: str = "embed"):
 
 def embed(params, tokens, cfg):
     x = jnp.take(params["table"], tokens, axis=0).astype(jnp.dtype(cfg.compute_dtype))
-    if cfg.name.startswith("gemma"):
-        x = x * jnp.asarray(cfg.d_model**0.5, x.dtype)
+    if cfg.embed_scale != 1.0:
+        x = x * jnp.asarray(cfg.embed_scale, x.dtype)
     return constrain(x, ("batch", "seq", "embed"))
 
 
@@ -35,6 +36,8 @@ def logits(params, x, cfg):
     else:
         w = params["unembed"].astype(x.dtype)
     out = jnp.einsum("bsd,dv->bsv", x, w).astype(jnp.float32)
+    if cfg.logit_scale != 1.0:
+        out = out / cfg.logit_scale
     cap = cfg.final_logit_softcap
     if cap is not None:
         out = cap * jnp.tanh(out / cap)

@@ -1,21 +1,34 @@
-"""Mamba2 (SSD) mixer [arXiv:2405.21060], as used by zamba2-2.7b.
+"""Mamba2 (SSD) mixer [arXiv:2405.21060], as used by zamba2-2.7b and
+granite-4.0-h.
 
 Structure: in_proj → (x, z, B, C, dt); short causal depthwise conv over
 (x,B,C); selective state-space recurrence with per-head scalar decay
-``a_t = exp(dt_t * A)`` realized through the shared gated-linear-attention
-scan; gated output ``y * silu(z)``; out_proj.
+``a_t = exp(dt_t * A)``; gated RMSNorm ``rmsnorm(y * silu(z))``; out_proj.
+B and C are one group shared by every head (``n_groups`` 1, all these
+models use).
 
-Decode keeps two cache entries per layer: the SSM state (B,H,hd,state) and
+Training and prefill run the SSM in its chunked dual form (:func:`ssd`,
+chunks of ``SSD_CHUNK`` tokens): matmuls inside a chunk, the state
+passed only between chunks, so the backward holds chunk states and not one
+state per token; prefill starts from the cache's state and leaves the final
+one there. Decode takes one step of the recurrence (``gla_step``).
+
+Decode keeps two cache entries per layer: the SSM state (B,H,state,hd) and
 the rolling conv window (B, conv_w-1, conv_channels).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers.linear_attention import gla_scan, gla_step
+from repro.models.layers.linear_attention import gla_step
 from repro.sharding import constrain
 from repro.utils.prng import fold_in_name
+
+
+SSD_CHUNK = 256  # Mamba2's chunk_size, as granite-4.0-h and zamba2 publish it
 
 
 def _dims(cfg):
@@ -79,10 +92,54 @@ def _split_proj(proj, cfg, d_in, nh):
 
 
 def _gated_norm(params, y, z, eps):
-    yf = y.astype(jnp.float32)
-    var = jnp.mean(jnp.square(yf), axis=-1, keepdims=True)
-    yn = yf * (var + eps) ** -0.5 * (1.0 + params["norm_scale"].astype(jnp.float32))
-    return (yn * jax.nn.silu(z.astype(jnp.float32))).astype(y.dtype)
+    """Mamba2's gated RMSNorm (``norm_before_gate=False``): the gate first,
+    then the norm over all d_inner channels (one group), then the gain."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    var = jnp.mean(jnp.square(g), axis=-1, keepdims=True)
+    out = g * (var + eps) ** -0.5 * (1.0 + params["norm_scale"].astype(jnp.float32))
+    return out.astype(y.dtype)
+
+
+def ssd(x, dt, a, bm, cm, chunk: int, initial_state=None):
+    """The state-space model ``h_t = exp(dt_t a) h_{t-1} + dt_t B_t x_t^T``,
+    ``y_t = C_t h_t``, in Mamba2's chunked dual form: one scan over chunks of
+    ``chunk`` tokens that carries the state; inside a chunk the masked
+    quadratic form ``(L o C B^T) (dt x)`` with ``L[i, j] = exp(sum_{j<t<=i}
+    dt_t a)``, plus the entering state read through ``C``. Each chunk is
+    recomputed in the backward, so the gradient keeps one chunk's (Q, Q, H)
+    decay and the states between chunks. A length that ``chunk`` does not
+    divide is padded with ``dt = 0`` tokens, which neither decay nor feed the
+    state. Float32 throughout, products at full precision. x (B,S,H,P), dt
+    (B,S,H), a (H,), bm and cm (B,S,N), initial_state (B,H,N,P) or None for
+    zeros; returns y (B,S,H,P) and the final state, both float32."""
+    b, s, h, p = x.shape
+    n = bm.shape[-1]
+    chunk = min(chunk, s)
+    nc = -(-s // chunk)
+    mm = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
+
+    def chunks(t):  # (B,S,...) -> (nc,B,Q,...), zero-padded at the end
+        t = jnp.pad(t.astype(jnp.float32), [(0, 0), (0, nc * chunk - s)] + [(0, 0)] * (t.ndim - 2))
+        return jnp.moveaxis(t.reshape((b, nc, chunk) + t.shape[2:]), 1, 0)
+
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))[:, :, None]
+
+    @jax.checkpoint
+    def step(state, xs):
+        xc, dtc, bc, cc = xs                     # (B,Q,H,P) (B,Q,H) (B,Q,N) (B,Q,N)
+        xdt = xc * dtc[..., None]
+        cum = jnp.cumsum(dtc * a, axis=1)        # (B,Q,H): log decay since the chunk began
+        decay = jnp.exp(jnp.where(causal, cum[:, :, None] - cum[:, None], -jnp.inf))
+        y = mm("bij,bijh,bjhp->bihp", mm("bin,bjn->bij", cc, bc), decay, xdt)
+        y = y + mm("bin,bhnp->bihp", cc, state) * jnp.exp(cum)[..., None]
+        tail = jnp.exp(cum[:, -1:] - cum)[..., None]  # decay from each token to the chunk's end
+        state = state * jnp.exp(cum[:, -1])[:, :, None, None] + mm("bjn,bjhp->bhnp", bc, xdt * tail)
+        return state, y
+
+    state = (jnp.zeros((b, h, n, p), jnp.float32) if initial_state is None
+             else initial_state.astype(jnp.float32))
+    state, y = jax.lax.scan(step, state, (chunks(x), chunks(dt), chunks(bm), chunks(cm)))
+    return jnp.moveaxis(y, 0, 1).reshape(b, nc * chunk, h, p)[:, :s], state
 
 
 def apply(params, x, cfg, *, cache=None, cache_index=None):
@@ -142,26 +199,27 @@ def apply(params, x, cfg, *, cache=None, cache_index=None):
 
     dtp = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"].astype(jnp.float32))  # (B,S,H)
     a = -jnp.exp(params["A_log"])  # (H,) negative
-    log_decay = dtp * a  # (B,S,H)  log a_t = dt * A
 
     xh = xin.reshape(b, s, nh, hd)
-    # linear-attention mapping: q=C, k=B (shared over heads), v=dt*x
-    q = jnp.broadcast_to(cmat[:, :, None, :], (b, s, nh, cfg.ssm_state))
-    kk = jnp.broadcast_to(bmat[:, :, None, :], (b, s, nh, cfg.ssm_state))
-    vv = (xh.astype(jnp.float32) * dtp[..., None]).astype(dtype)
-    lw = jnp.broadcast_to(log_decay[..., None], (b, s, nh, cfg.ssm_state))
-
     if decode:
+        # linear-attention mapping: q=C, k=B (shared over heads), v=dt*x
+        q = jnp.broadcast_to(cmat[:, :, None, :], (b, s, nh, cfg.ssm_state))
+        kk = jnp.broadcast_to(bmat[:, :, None, :], (b, s, nh, cfg.ssm_state))
+        vv = (xh.astype(jnp.float32) * dtp[..., None]).astype(dtype)
+        lw = jnp.broadcast_to((dtp * a)[..., None], (b, s, nh, cfg.ssm_state))  # log a_t = dt A
         y1, new_state = gla_step(
             cache["ssm"], q[:, 0], kk[:, 0], vv[:, 0], lw[:, 0], include_current=True
         )
         y = y1[:, None]  # (B,1,H,hd)
         new_cache = {"ssm": new_state, "conv": new_conv}
     else:
-        # carry the SSM state in from the cache (zeros when fresh) so chunked
-        # prefill continues the recurrence exactly where the last chunk ended
-        init_state = cache["ssm"] if cache is not None else None
-        y, final_state = gla_scan(q, kk, vv, lw, include_current=True, initial_state=init_state)
+        # a whole sequence (training) or a prefill chunk, which carries the
+        # SSM state in from the cache and continues exactly where the last
+        # chunk ended
+        with jax.named_scope("ssm_scan"):
+            y, final_state = ssd(xh, dtp, a, bmat, cmat, SSD_CHUNK,
+                                 None if cache is None else cache["ssm"])
+        y = y.astype(dtype)
         if cache is not None:
             new_cache = {"ssm": final_state, "conv": new_conv}
     y = y + xh * params["D"].astype(y.dtype)[None, None, :, None]

@@ -136,3 +136,34 @@ def test_accumulate4_train_step_compiles_unrolled(spec):
     assert not re.search(r'op_name="jit\(step\)/while"', compiled.as_text())
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes <= 12.3e9
+
+
+def test_hybrid_reshape_stage2_step_fits(spec):
+    """train-sebs-hybrid's largest step: granite-4.0-h-micro cut to one
+    period (mamba x5, attention, mamba x4) and 25,088 vocabulary rows, f32
+    params, bf16 compute, psgd, the reshape-mode batch of stage 2 (4 rows of
+    2,048 tokens), fits one chip with room to spare."""
+    import json
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    sys.path.insert(0, str(root))
+    from bench import program_hybrid
+
+    c = json.loads((root / "bench/configs/granite-4.0-h-micro-train10l.json").read_text())
+    mix = json.loads((root / "bench/traffic/sebs-ladder-hybrid.json").read_text())
+    model = program_hybrid.build(c)
+    optimizer = make_optimizer("psgd", gamma=mix["gamma"])
+
+    def init():
+        params, _ = model.init(jax.random.key(0))
+        return TrainState(params, optimizer.init(params), jnp.zeros((), jnp.int32))
+
+    state = jax.tree.map(lambda x: spec(x.shape, x.dtype), jax.eval_shape(init))
+    rows = mix["b1"] * mix["rho"] ** (mix["stages"] - 1)
+    compiled = build_train_step(model, optimizer).lower(
+        state, {"tokens": spec((rows, mix["seq"]), jnp.int32)},
+        spec((), jnp.float32), spec((), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes <= 15.0e9
