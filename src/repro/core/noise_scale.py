@@ -27,21 +27,21 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import List, Optional
 
-import jax
-import jax.numpy as jnp
+import numpy as np
 
 from repro.core.schedules import StageInfo
 
 
-def microbatch_grad_sq_norms(grads_sum_sq: jnp.ndarray, grad_big_sq: jnp.ndarray,
-                             b_small: int, b_big: int):
-    """Pure function combining the two squared norms into (trΣ, |G|², B_noise).
+def microbatch_grad_sq_norms(grads_sum_sq, grad_big_sq, b_small: int, b_big: int):
+    """Pure function combining the two squared norms into (trΣ, |G|², B_noise),
+    in the inputs' precision (float32 scalars on the host for the trainer's
+    estimator).
 
     ``grads_sum_sq``: E over microbatches of ‖g_micro‖² (each over b_small
     samples); ``grad_big_sq``: ‖mean grad‖² (over b_big samples)."""
     tr_sigma = (grads_sum_sq - grad_big_sq) / (1.0 / b_small - 1.0 / b_big)
     g_sq = (b_big * grad_big_sq - b_small * grads_sum_sq) / (b_big - b_small)
-    b_noise = tr_sigma / jnp.maximum(g_sq, 1e-20)
+    b_noise = tr_sigma / np.maximum(g_sq, 1e-20)
     return tr_sigma, g_sq, b_noise
 
 
@@ -54,8 +54,11 @@ class GradientNoiseScale:
     _g_sq: Optional[float] = None
 
     def update(self, sum_sq_small: float, sq_big: float, b_small: int, b_big: int) -> float:
+        # NumPy on the host, not jnp: device programs here would queue behind
+        # the update the trainer keeps in flight, and reading them back
+        # would wait for it
         tr_s, g_s, _ = microbatch_grad_sq_norms(
-            jnp.float32(sum_sq_small), jnp.float32(sq_big), b_small, b_big
+            np.float32(sum_sq_small), np.float32(sq_big), b_small, b_big
         )
         tr_s, g_s = float(tr_s), float(g_s)
         if self._tr_sigma is None:

@@ -77,6 +77,24 @@ class TrainLog:
         return log
 
 
+# the metrics the run loop reads back from each update
+_READ_KEYS = ("loss", "grad_sq_small", "grad_sq_big")
+
+
+@dataclass
+class _Dispatched:
+    """An update dispatched to the device, with what its bookkeeping needs
+    once its metrics are read back."""
+
+    update: int
+    plan: StepPlan
+    metrics: dict           # device arrays until read
+    span: Any               # its ``train.update`` span: takes the loss
+    t0: float               # its start, on the tracer's clock
+    comm: tuple[int, int]   # ``_comm_counters()`` as of this update
+    save: bool              # a checkpoint is due here
+
+
 class SEBSTrainer:
     def __init__(
         self,
@@ -256,6 +274,15 @@ class SEBSTrainer:
         ``stop_after_updates`` exits the loop after that many updates —
         the preemption hook the kill-equivalence tests and the CI resume
         smoke job use to simulate a mid-run kill.
+
+        One update is kept in flight: update k+1 is dispatched before update
+        k's loss and noise-scale norms are read back (one ``device_get``),
+        so the host's bookkeeping overlaps the device. The log, losses and
+        final state are those of the synchronous order. Spans: update k's
+        ``train.update`` holds its ``train.data`` and ``train.dispatch``
+        and the ``train.wait`` on update k-1; one ``train.after`` per
+        update read follows it. Where an update is drained (read before the
+        next dispatch), its own read joins that wait.
         """
         log = TrainLog()
         gns = GradientNoiseScale()
@@ -264,6 +291,59 @@ class SEBSTrainer:
         if resume and checkpointer is not None:
             state, update = self._restore(checkpointer, state, log, gns)
         interrupted = False
+        schedule = self.controller.schedule
+        # an adaptive schedule (core.noise_scale.AdaptiveSEBS) picks its next
+        # plan from the loss (Eq. 8 with observed ε): it reads every update
+        # before the next is dispatched
+        observes = hasattr(schedule, "observe")
+
+        def settle(d: _Dispatched, host: dict, t1: float) -> None:
+            """Update ``d``'s bookkeeping from its metrics, read back."""
+            plan, loss = d.plan, float(host["loss"])
+            d.span.set_arg("loss", loss)
+            with self.tracer.span("train.after", update=d.update):
+                self.metrics.histogram(
+                    "train.update_s", labels={"stage": plan.stage}
+                ).observe(t1 - d.t0)
+                self.metrics.counter("train.updates").inc()
+                self.metrics.counter("train.samples").inc(plan.batch_size)
+                if self._step_unrolled(plan):
+                    self.metrics.counter("train.updates_unrolled").inc()
+                if sanitize.enabled():
+                    sanitize.check_finite_update(
+                        dict(d.metrics, loss=loss), update=d.update, stage=plan.stage
+                    )
+                if observes:
+                    schedule.observe(plan.samples_after, loss)
+                # the GNS estimator consumes the free per-microbatch grad
+                # norms from accumulate mode
+                if "grad_sq_big" in host and plan.accum_steps > 1:
+                    gns.update(
+                        float(host["grad_sq_small"]), float(host["grad_sq_big"]),
+                        b_small=plan.microbatch, b_big=plan.batch_size,
+                    )
+                if d.update % log_every == 0 or plan.samples_after >= schedule.total_samples:
+                    log.steps.append(d.update)
+                    log.samples.append(plan.samples_after)
+                    log.stages.append(plan.stage)
+                    log.batch_sizes.append(plan.batch_size)
+                    log.losses.append(loss)
+                    log.noise_scales.append(gns.b_noise)
+                    log.comm_bytes.append(d.comm[0])
+                    log.sync_events.append(d.comm[1])
+                    self._report_comm(*d.comm)
+                    # re-export the GNS EMA through the registry — the obs
+                    # layer reads the SAME number TrainLog records
+                    self.metrics.gauge("train.gns").set(gns.b_noise)
+                    if self.tracer.enabled and not np.isnan(gns.b_noise):
+                        # NaN is invalid trace JSON
+                        self.tracer.counter("train.gns", b_noise=gns.b_noise)
+                if d.save:
+                    # a save drains its update first: ``state`` is still its
+                    # output, not yet donated to the next step
+                    self._save(checkpointer, d.update, state, log, gns)
+
+        inflight: Optional[_Dispatched] = None  # dispatched, not yet read
         for plan in self.controller.plans(start_samples=self.pipeline.samples_consumed):
             if stop_after_updates is not None and update >= stop_after_updates:
                 # checked BEFORE the update so a resume whose restored
@@ -286,59 +366,37 @@ class SEBSTrainer:
                 with self.tracer.span("train.dispatch"):
                     state, metrics = self._execute(state, batch, plan)
                 state = self._after_update(state, update, plan)
-                with self.tracer.span("train.wait"):
-                    loss = float(metrics["loss"])  # blocks: the update reached host
-                update_span.set_arg("loss", loss)
-            t1 = self._clock()
-            with self.tracer.span("train.after"):
-                self.metrics.histogram(
-                    "train.update_s", labels={"stage": plan.stage}
-                ).observe(t1 - t0)
-                self.metrics.counter("train.updates").inc()
-                self.metrics.counter("train.samples").inc(plan.batch_size)
-                if self._step_unrolled(plan):
-                    self.metrics.counter("train.updates_unrolled").inc()
-                if sanitize.enabled():
-                    sanitize.check_finite_update(
-                        dict(metrics, loss=loss), update=update, stage=plan.stage
-                    )
-                # adaptive schedules (core.noise_scale.AdaptiveSEBS) consume
-                # the measured loss to decide stage transitions (Eq. 8 with
-                # observed ε); the GNS estimator consumes the free per-
-                # microbatch grad norms from accumulate mode.
-                if hasattr(self.controller.schedule, "observe"):
-                    self.controller.schedule.observe(plan.samples_after, loss)
-                if "grad_sq_big" in metrics and plan.accum_steps > 1:
-                    gns.update(
-                        float(metrics["grad_sq_small"]), float(metrics["grad_sq_big"]),
-                        b_small=plan.microbatch, b_big=plan.batch_size,
-                    )
-                if update % log_every == 0 or plan.samples_after >= self.controller.schedule.total_samples:
-                    log.steps.append(update)
-                    log.samples.append(plan.samples_after)
-                    log.stages.append(plan.stage)
-                    log.batch_sizes.append(plan.batch_size)
-                    log.losses.append(loss)
-                    log.noise_scales.append(gns.b_noise)
-                    comm_bytes, sync_events = self._comm_counters()
-                    log.comm_bytes.append(comm_bytes)
-                    log.sync_events.append(sync_events)
-                    self._report_comm(comm_bytes, sync_events)
-                    # re-export the GNS EMA through the registry — the obs
-                    # layer reads the SAME number TrainLog records
-                    self.metrics.gauge("train.gns").set(gns.b_noise)
-                    if self.tracer.enabled and not np.isnan(gns.b_noise):
-                        # NaN is invalid trace JSON
-                        self.tracer.counter("train.gns", b_noise=gns.b_noise)
+                save = False
                 if checkpointer is not None and save_every:
                     # saves SNAP to the next checkpoint-consistent update
                     # rather than being dropped: local-SGD replicas are only
                     # consistent right after an average, and its cadence
                     # need not align with save_every
                     save_pending = save_pending or update % save_every == 0
-                    if save_pending and self._ready_to_save(update):
-                        self._save(checkpointer, update, state, log, gns)
-                        save_pending = False
+                    save = save_pending and self._ready_to_save(update)
+                    save_pending = save_pending and not save
+                done = _Dispatched(update, plan, metrics, update_span, t0,
+                                   self._comm_counters(), save)
+                if inflight is not None:
+                    self.metrics.counter("train.updates_overlapped").inc()
+                # read before the next dispatch where something needs this
+                # update settled: an observing schedule, a save (the next
+                # step donates the state it writes), the stop limit and the
+                # last plan (run() returns with nothing in flight)
+                drain = (observes or save
+                         or plan.samples_after >= schedule.total_samples
+                         or (stop_after_updates is not None and update >= stop_after_updates))
+                due = [d for d in (inflight, done if drain else None) if d is not None]
+                host = []
+                if due:
+                    # blocks until the last of them reached the host
+                    with self.tracer.span("train.wait", update=due[-1].update):
+                        host = jax.device_get([{k: d.metrics[k] for k in _READ_KEYS
+                                                if k in d.metrics} for d in due])
+                t1 = self._clock()
+            for d, h in zip(due, host):
+                settle(d, h, t1)
+            inflight = None if drain else done
         state = self._finalize(state)
         if sanitize.enabled():
             sanitize.audit_tracer(self.tracer, where="(train run end)")

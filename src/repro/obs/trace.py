@@ -57,19 +57,24 @@ class _Span:
     One instance per ``span()`` call when enabled; the disabled path
     returns the shared :data:`_NULL_SPAN` and allocates nothing."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_ev")
 
     def __init__(self, tracer: "Tracer", name: str, args: Optional[Dict[str, Any]]):
         self._tracer = tracer
         self.name = name
         self.args = args
         self._t0 = 0.0
+        self._ev: Optional[Dict[str, Any]] = None  # the recorded event, once closed
 
     def set_arg(self, key: str, value: Any) -> None:
-        """Add one arg known only inside the region (e.g. an update's loss)."""
+        """Add one arg known only inside the region or after it closed (an
+        update's loss, read back once a later update is in flight): a
+        closed span's recorded event takes it too."""
         if self.args is None:
             self.args = {}
         self.args[key] = value
+        if self._ev is not None:
+            self._ev["args"] = self.args
 
     def __enter__(self) -> "_Span":
         self._t0 = self._tracer.clock()
@@ -77,10 +82,13 @@ class _Span:
         return self
 
     def __exit__(self, *exc) -> None:
-        self._tracer._depth -= 1
-        self._tracer.complete(
-            self.name, self._t0, self._tracer.clock(), **(self.args or {})
-        )
+        tracer = self._tracer
+        tracer._depth -= 1
+        self._ev = {"ph": "X", "name": self.name, "ts": self._t0,
+                    "dur": tracer.clock() - self._t0}
+        if self.args:
+            self._ev["args"] = self.args
+        tracer._emit(self._ev)
 
 
 class _NullSpan:
@@ -148,7 +156,7 @@ class Tracer:
 
     def span(self, name: str, **args: Any):
         """Context manager timing one synchronous region. The object it
-        returns takes ``set_arg(key, value)`` before the region closes."""
+        returns takes ``set_arg(key, value)``, before or after it closes."""
         if not self.enabled:
             return _NULL_SPAN
         if self._annotation is not None:

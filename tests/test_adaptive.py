@@ -44,6 +44,17 @@ def test_gns_ema_converges():
     assert gns.b_noise == pytest.approx(18.2857 / 2.8571, rel=1e-3)
 
 
+def test_gns_update_stays_on_the_host():
+    """The trainer feeds the estimator while the next update is in flight,
+    where a device program would queue behind that update: the update moves
+    nothing to the device, and its numbers are the float32 arithmetic's."""
+    gns = GradientNoiseScale()
+    with jax.transfer_guard("disallow"):
+        gns.update(sum_sq_small=12.3, sq_big=4.1, b_small=2, b_big=16)
+    tr_s, g_sq, _ = microbatch_grad_sq_norms(jnp.float32(12.3), jnp.float32(4.1), 2, 16)
+    assert (gns._tr_sigma, gns._g_sq) == (float(tr_s), float(g_sq))
+
+
 def test_adaptive_sebs_grows_with_observed_contraction():
     sched = AdaptiveSEBS(b1=8, eta=0.1, total=10_000, rho_max=4.0,
                          min_stage_samples=100, smooth=0.0)

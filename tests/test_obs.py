@@ -451,6 +451,14 @@ def test_annotated_spans_enter_and_exit_in_nesting_order(monkeypatch):
     outer = [e for e in tr.events if e["name"] == "outer"][0]
     assert outer["args"] == {"update": 3, "stage": 1, "loss": 2.5}
     assert tr.depth == 0
+    # an arg set after the span closed reaches its recorded event, also one
+    # recorded without args
+    sp.set_arg("grad", 0.5)
+    assert outer["args"] == {"update": 3, "stage": 1, "loss": 2.5, "grad": 0.5}
+    with tr.span("bare") as bare:
+        pass
+    bare.set_arg("loss", 1.5)
+    assert [e for e in tr.events if e["name"] == "bare"][0]["args"] == {"loss": 1.5}
     # the disabled span takes set_arg too, and still records nothing
     NULL_TRACER.span("x").set_arg("loss", 1.0)
     assert NULL_TRACER.events_total == 0
@@ -479,20 +487,35 @@ def test_trainer_phase_spans_nest_per_update(monkeypatch):
     assert n == 6 and log.stages == [0, 0, 0, 1, 1, 1]
     assert tracer.depth == 0
     spans = [e for e in tracer.events if e["ph"] == "X"]
-    # recorded as each closes: the three phases, their update, then after
-    per_update = ["train.data", "train.dispatch", "train.wait", "train.update",
-                  "train.after"]
-    assert [e["name"] for e in spans] == per_update * n
-    for i in range(n):
-        data, dispatch, wait, update, after = spans[5 * i: 5 * i + 5]
+    # one update in flight: update k's train.update holds its data and
+    # dispatch and the wait on update k-1 (nothing to read at k = 1; the
+    # last update is drained, so its wait reads k-1 and k), and one
+    # train.after per update read follows it; recorded as each closes
+    reads = [[]] + [[k] for k in range(1, n - 1)] + [[n - 1, n]]
+    expected = []
+    for r in reads:
+        expected += (["train.data", "train.dispatch"] + ["train.wait"] * bool(r)
+                     + ["train.update"] + ["train.after"] * len(r))
+    assert [e["name"] for e in spans] == expected
+    at = 0
+    for i, r in enumerate(reads):
+        inner = 2 + bool(r)
+        group = spans[at: at + inner + 1 + len(r)]
+        at += len(group)
+        phases, update, afters = group[:inner], group[inner], group[inner + 1:]
         end = update["ts"] + update["dur"]
-        for child in (data, dispatch, wait):
+        for child in phases:
             assert update["ts"] <= child["ts"] and child["ts"] + child["dur"] <= end
-        assert data["ts"] + data["dur"] <= dispatch["ts"]
-        assert dispatch["ts"] + dispatch["dur"] <= wait["ts"]
-        assert after["ts"] >= end
+        for a, b in zip(phases, phases[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"]
+        if r:
+            assert phases[-1]["args"] == {"update": r[-1]}
+        assert [a["args"] for a in afters] == [{"update": k} for k in r]
+        assert all(a["ts"] >= end for a in afters)
+        # the loss lands on the span of the update that produced it
         assert update["args"] == {"update": i + 1, "stage": log.stages[i],
                                   "batch": log.batch_sizes[i], "loss": log.losses[i]}
+    assert metrics.counter("train.updates_overlapped").value == n - 1
     # every annotation closed in nesting order, update and stage on the update's
     stack = []
     for kind, name, kwargs in _StubAnnotation.log:
