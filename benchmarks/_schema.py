@@ -10,7 +10,7 @@ job gates on the result).
 
 A record is one metric observation:
 
-- ``name``       unique within its module (``serve_continuous_load16_tok_per_s``),
+- ``name``       unique within its module (``paged_decode_gather_jnp_b8``),
 - ``value``      a finite number,
 - ``unit``       explicit ("tok/s", "us/token", "bytes", "count", ...) — the
                  legacy CSV had a single ``us_per_call`` header that silently

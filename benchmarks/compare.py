@@ -23,10 +23,10 @@ Usage::
 
     PYTHONPATH=src python -m benchmarks.compare \
         --baseline benchmarks/baselines --current . \
-        --modules table_comm,kernels,serve,serve_prefix
+        --modules table_comm,kernels
 
     # per-metric override (relative band):
-    python -m benchmarks.compare --tolerance serve_continuous_load16_tok_per_s=0.4
+    python -m benchmarks.compare --tolerance paged_decode_gather_jnp_b8=0.4
 """
 from __future__ import annotations
 

@@ -30,7 +30,7 @@ from typing import List, Optional
 
 from benchmarks import _schema
 from benchmarks import adaptive_sebs, fig1_util, fig2_optimal_batch, fig3_stagewise
-from benchmarks import kernel_bench, roofline_report, serve_prefix, serve_throughput
+from benchmarks import kernel_bench, roofline_report
 from benchmarks import table1_updates, table_comm
 
 MODULES = {
@@ -42,13 +42,11 @@ MODULES = {
     "kernels": kernel_bench,
     "roofline": roofline_report,
     "adaptive": adaptive_sebs,
-    "serve": serve_throughput,
-    "serve_prefix": serve_prefix,
 }
 
 # the CI bench-trajectory subset: cheap enough for every PR, covers comm
-# accounting, kernel timings, and both serving engines
-CHEAP_SUBSET = ("table_comm", "kernels", "serve", "serve_prefix")
+# accounting and kernel timings
+CHEAP_SUBSET = ("table_comm", "kernels")
 
 
 def main(argv: Optional[List[str]] = None) -> None:
@@ -80,25 +78,12 @@ def main(argv: Optional[List[str]] = None) -> None:
         raise SystemExit(f"--out-root {out_root} exists and is not a directory")
     out_root.mkdir(parents=True, exist_ok=True)
     roofline_report.ALLOW_MISSING = roofline_report.ALLOW_MISSING or args.allow_missing
-    # a module whose work needs its own process (serve's 2-device interference
-    # child) starts it here, before this process initialises a JAX backend:
-    # a chip belongs to one process at a time
-    early_errors = {}
-    for name in names:
-        hook = getattr(MODULES[name], "before_backend", None)
-        if hook is not None:
-            try:
-                hook()
-            except Exception as e:  # noqa: BLE001 — reported with the module
-                early_errors[name] = e
     env = _env.fingerprint()
     print(_schema.CSV_HEADER)
     failures = []
     for name in names:
         t0 = time.time()
         try:
-            if name in early_errors:
-                raise early_errors[name]
             records = _schema.as_records(MODULES[name].run())
             for rec in records:
                 print(rec.csv_row(), flush=True)

@@ -1,13 +1,14 @@
-"""Serving demo: static batch vs continuous batching with a stagewise
-admission ramp — through the same model code the 524k-context dry-run
-lowers.
+"""Serving demo: static batch vs the paged continuous-batching engine with
+a stagewise admission ramp — through the same model code the 524k-context
+dry-run lowers.
 
     PYTHONPATH=src python examples/serve_demo.py [--arch rwkv6-1.6b]
 
-The continuous engine starts with a single decode slot, and as the queue
-keeps the ring under sustained pressure it enlarges the slot budget
+The paged engine starts with a single decode slot, and as the queue keeps
+the ring under sustained pressure it enlarges the slot budget
 geometrically (b₁ρˢ — SEBS's stagewise batch enlargement applied to
-serving), recycling freed slots for queued requests mid-decode-loop.
+serving), recycling freed slots and their KV pages for queued requests
+mid-decode-loop.
 """
 import sys, os
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -19,7 +20,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.models import build_model
-from repro.serve import ContinuousBatchingEngine, ServeEngine
+from repro.serve import PagedContinuousBatchingEngine, ServeEngine
 
 
 def main():
@@ -46,19 +47,20 @@ def main():
     for i, row in enumerate(ref):
         print(f"  request {i}: prompt={row[:8].tolist()} -> generated={row[8:].tolist()}")
 
-    # continuous batching: FIFO queue, slot recycling, stagewise admission
-    engine = ContinuousBatchingEngine(
-        model, params, cache_len=64, max_slots=args.slots, b1=1, rho=2.0, patience=1
+    # continuous batching: FIFO queue, slot + page recycling, stagewise admission
+    engine = PagedContinuousBatchingEngine(
+        model, params, cache_len=64, max_slots=args.slots, b1=1, rho=2.0, patience=1,
+        page_size=8, prefill_chunks=(8,),
     )
     ids = [engine.submit(p, max_new_tokens=args.new_tokens) for p in prompts]
     results = engine.run()
-    print(f"\n[continuous] {args.requests} requests through <= {args.slots} slots:")
+    print(f"\n[paged] {args.requests} requests through <= {args.slots} slots:")
     for rid in ids:
         row = results[rid]
         print(f"  request {rid}: prompt={row[:8].tolist()} -> generated={row[8:].tolist()}")
     print(
         f"\nadmission ladder {engine.admission.ladder} "
-        f"(one compiled decode variant per stage: {engine.decode_compiles} compiles), "
+        f"(at most one compiled decode variant per stage: {engine.decode_compiles} compiles), "
         f"peak ring width {engine.stats['peak_width']}, "
         f"{engine.stats['ticks']} decode ticks for {engine.stats['decoded_tokens']} tokens"
     )

@@ -56,21 +56,12 @@ COMPILE_BUCKETS: Tuple[CompileBucket, ...] = (
         "one executable per static-batch shape",
     ),
     CompileBucket(
-        "serve.decode.slot", "repro/serve/step.py", "build_slot_decode_step",
-        "one executable per admission-stage ring width",
-    ),
-    CompileBucket(
         "serve.decode.paged", "repro/serve/step.py", "build_paged_decode_step",
         "one executable per admission-stage ring width",
     ),
     CompileBucket(
         "serve.prefill.chunk", "repro/serve/step.py", "build_chunk_prefill_step",
         "one executable per declared prefill_chunks bucket",
-    ),
-    CompileBucket(
-        "serve.engine.encdec_prefill", "repro/serve/engine.py",
-        "ContinuousBatchingEngine.__init__",
-        "one encoder+prefill executable per engine",
     ),
     CompileBucket(
         "serve.stream.export", "repro/serve/step.py", "build_page_export_step",
@@ -83,16 +74,10 @@ COMPILE_BUCKETS: Tuple[CompileBucket, ...] = (
         "scatter)",
     ),
     CompileBucket(
-        "serve.engine.disagg_workers", "repro/serve/engine.py",
-        "_PrefillWorker.__init__",
-        "two fixed-shape helpers per prefill worker (COW page copy, state-row "
-        "zero), one executable each",
-    ),
-    CompileBucket(
-        "serve.engine.paged_helpers", "repro/serve/engine.py",
-        "PagedContinuousBatchingEngine.__init__",
-        "three fixed-shape helpers per engine (page copy, state-row zero, "
-        "encoder), one executable each",
+        "serve.engine.worker_helpers", "repro/serve/engine.py",
+        "PagedWorker.__init__",
+        "three fixed-shape helpers per paged worker (COW page copy, state-row "
+        "zero, encoder for encoder-decoder models), one executable each",
     ),
     # -- serving kernels (paged flash decode; interpret off-TPU) ------------
     CompileBucket(

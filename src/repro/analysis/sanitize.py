@@ -159,7 +159,7 @@ def audit_engine_compiles(engine: Any, *, where: str = "") -> None:
 
     - decode variants: one cache entry per admission-ladder width actually
       reached, never a width outside the ladder, one executable each
-      (bucket ``serve.decode.slot`` / ``serve.decode.paged``);
+      (bucket ``serve.decode.paged``);
     - chunk-prefill variants: keys ⊆ ``prefill_chunks``, one executable each
       (bucket ``serve.prefill.chunk``).
 

@@ -3,13 +3,10 @@
     # static batch (seed behaviour)
     PYTHONPATH=src python -m repro.launch.serve --arch rwkv6-1.6b --batch 4
 
-    # continuous batching with a stagewise admission ramp
-    PYTHONPATH=src python -m repro.launch.serve --engine continuous \
-        --requests 12 --slots 8 --b1 2 --rho 2.0
-
-    # paged KV cache + radix prefix sharing + chunked prefill
+    # paged KV cache + radix prefix sharing + chunked prefill, with a
+    # stagewise admission ramp
     PYTHONPATH=src python -m repro.launch.serve --engine paged \
-        --requests 12 --slots 4 --page-size 16 --chunk 32 --prefix-cache
+        --requests 12 --slots 4 --b1 1 --rho 2.0 --page-size 16 --chunk 32
 
     # disaggregated prefill/decode across two submeshes (8 host devices)
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
@@ -38,7 +35,6 @@ from repro.launch.mesh import make_disagg_submeshes
 from repro.models import build_model
 from repro.obs import MetricsRegistry, Tracer
 from repro.serve import (
-    ContinuousBatchingEngine,
     DisaggregatedEngine,
     PagedContinuousBatchingEngine,
     ServeEngine,
@@ -54,16 +50,16 @@ def main(argv: Optional[List[str]] = None) -> Dict[str, Any]:
     ap.add_argument("--variant", default="smoke", choices=["smoke", "full"])
     ap.add_argument("--param-dtype", default=None, choices=["float32", "bfloat16"],
                     help="weight dtype (default: the config's own)")
-    ap.add_argument("--engine", choices=["static", "continuous", "paged", "disagg"],
+    ap.add_argument("--engine", choices=["static", "paged", "disagg"],
                     default="static")
     ap.add_argument("--batch", type=int, default=4, help="static: batch size")
-    ap.add_argument("--requests", type=int, default=8, help="continuous: request count")
-    ap.add_argument("--slots", type=int, default=4, help="continuous: max slot-ring width")
+    ap.add_argument("--requests", type=int, default=8, help="paged/disagg: request count")
+    ap.add_argument("--slots", type=int, default=4, help="paged/disagg: max slot-ring width")
     ap.add_argument("--b1", type=int, default=None,
-                    help="continuous: initial slot budget (default: --slots, no ramp)")
-    ap.add_argument("--rho", type=float, default=2.0, help="continuous: stage growth factor")
+                    help="paged/disagg: initial slot budget (default: --slots, no ramp)")
+    ap.add_argument("--rho", type=float, default=2.0, help="paged/disagg: stage growth factor")
     ap.add_argument("--patience", type=int, default=2,
-                    help="continuous: sustained-load ticks before a stage bump")
+                    help="paged/disagg: sustained-load ticks before a stage bump")
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--top-k", type=int, default=0)
     ap.add_argument("--prompt-len", type=int, default=8)
@@ -136,11 +132,11 @@ def main(argv: Optional[List[str]] = None) -> Dict[str, Any]:
     if args.pages is not None and args.pages < 2:
         ap.error(f"--pages must be >= 2 (pool reserves scratch page 0; got {args.pages})")
     if args.engine == "static" and args.b1 is not None:
-        ap.error("--b1 requires --engine continuous or paged")
+        ap.error("--b1 requires --engine paged or disagg")
     if args.engine == "static" and (args.trace or args.metrics):
         ap.error("--trace/--metrics require a scheduled engine "
-                 "(--engine continuous, paged, or disagg)")
-    if args.engine not in ("paged", "disagg"):
+                 "(--engine paged or disagg)")
+    if args.engine == "static":
         if args.pages is not None:
             ap.error("--pages requires --engine paged or disagg")
         if args.chunk is not None:
@@ -214,7 +210,7 @@ def main(argv: Optional[List[str]] = None) -> Dict[str, Any]:
             dict(zip(decode_mesh.axis_names, decode_mesh.devices.shape)),
             engine.decode_device,
         )
-    elif args.engine == "paged":
+    else:
         engine = PagedContinuousBatchingEngine(
             model, params, cache_len=args.cache_len, max_slots=args.slots,
             b1=args.b1, rho=args.rho, patience=args.patience,
@@ -222,12 +218,6 @@ def main(argv: Optional[List[str]] = None) -> Dict[str, Any]:
             prefix_cache=args.prefix_cache,
             prefill_chunks=tuple(args.chunk) if args.chunk else (32,),
             kernel=args.kernel,
-            **obs_kwargs,
-        )
-    else:
-        engine = ContinuousBatchingEngine(
-            model, params, cache_len=args.cache_len, max_slots=args.slots,
-            b1=args.b1, rho=args.rho, patience=args.patience,
             **obs_kwargs,
         )
     prompts = np.asarray(
@@ -251,18 +241,17 @@ def main(argv: Optional[List[str]] = None) -> Dict[str, Any]:
         engine.admission.ladder, engine.stats["peak_width"], engine.stats["ticks"],
         engine.stats["decoded_tokens"], engine.decode_compiles,
     )
-    if args.engine in ("paged", "disagg"):
-        mem = engine.memory_stats()
-        log.info(
-            "pages peak %d/%d | prefix hit-rate %.0f%% | prefill computed %d "
-            "(%d reused) | %d chunk step(s) compiled | kv peak %d KiB "
-            "(dense-equiv %d KiB)",
-            mem["pages_peak"], mem["pages_capacity"],
-            100 * mem["prefix_hit_rate"],
-            engine.stats["prefill_tokens_computed"],
-            engine.stats["prefix_tokens_reused"], engine.prefill_compiles,
-            mem["kv_bytes_peak"] // 1024, mem["kv_bytes_dense_equiv"] // 1024,
-        )
+    mem = engine.memory_stats()
+    log.info(
+        "pages peak %d/%d | prefix hit-rate %.0f%% | prefill computed %d "
+        "(%d reused) | %d chunk step(s) compiled | kv peak %d KiB "
+        "(dense-equiv %d KiB)",
+        mem["pages_peak"], mem["pages_capacity"],
+        100 * mem["prefix_hit_rate"],
+        engine.stats["prefill_tokens_computed"],
+        engine.stats["prefix_tokens_reused"], engine.prefill_compiles,
+        mem["kv_bytes_peak"] // 1024, mem["kv_bytes_dense_equiv"] // 1024,
+    )
     if args.engine == "disagg":
         log.info(
             "streamed %d transfer(s), %d page(s), %d KiB over the seam | "

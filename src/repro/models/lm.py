@@ -272,28 +272,6 @@ class LanguageModel:
         self._map_paged(cache, count, lambda l: l)
         return total
 
-    # -- continuous-batching slot helpers ------------------------------------
-    # Cache leaves are stacked over the scanned ``layers`` axis
-    # (init_segment_cache), so the batch/slot dimension is axis 1:
-    # (layers, batch, ...).
-
-    def cache_insert(self, cache, slot_cache, slot: int):
-        """In-place-style insertion of a batch-1 ``slot_cache`` (e.g. a fresh
-        prefill) into row ``slot`` of a wider slot-ring ``cache``."""
-        return jax.tree.map(
-            lambda full, one: jax.lax.dynamic_update_slice_in_dim(
-                full, one.astype(full.dtype), slot, axis=1
-            ),
-            cache,
-            slot_cache,
-        )
-
-    def cache_extract(self, cache, slot: int):
-        """Batch-1 slice of row ``slot`` (inverse of :meth:`cache_insert`)."""
-        return jax.tree.map(
-            lambda full: jax.lax.dynamic_slice_in_dim(full, slot, 1, axis=1), cache
-        )
-
     def prefill(self, params, batch, cache, memory=None):
         """Full-sequence forward filling the cache. Returns (logits, cache).
         ``memory`` may carry a precomputed encoder output (else it is

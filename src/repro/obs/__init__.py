@@ -28,10 +28,9 @@ and audited at run() end by
 :func:`repro.analysis.sanitize.audit_tracer`).
 
 Consumers: ``launch/serve.py --trace/--metrics``, ``launch/train.py
---trace/--metrics``, ``benchmarks/serve_throughput.py`` (SLO percentiles
-derive from tracer spans via
-:func:`~repro.obs.metrics.nearest_rank`), and ``tools/trace_view.py``
-(per-phase p50/p99 per request class, per-stage update timing).
+--trace/--metrics``, and ``tools/trace_view.py`` (per-phase p50/p99 per
+request class via :func:`~repro.obs.metrics.nearest_rank`, per-stage
+update timing).
 """
 from repro.obs.metrics import (
     Counter,

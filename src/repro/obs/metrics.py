@@ -12,7 +12,7 @@ O(buckets) however long the run. :meth:`Histogram.percentile` applies the
 same nearest-rank rule as :func:`nearest_rank` over the bucketed counts
 and returns the upper bound of the bucket holding the rank-th sample —
 deterministic, and exact at bucket resolution. When sample-exact
-percentiles are needed (the serve benchmark's SLO numbers), derive them
+percentiles are needed (serving SLO numbers), derive them
 from tracer span durations with :func:`nearest_rank`; the consistency
 between the two paths is pinned by ``tests/test_obs.py``.
 
@@ -41,9 +41,7 @@ __all__ = [
 def nearest_rank(xs: Sequence[float], q: float) -> float:
     """Nearest-rank percentile: the smallest element with at least
     ``q/100`` of the sample at or below it — ``sorted(xs)[ceil(q/100·n)-1]``.
-    Pure-python port of the serve benchmark's ``_pct`` (its
-    ``PERCENTILE_METHOD = "nearest-rank"``), bit-identical on the same
-    floats. NaN on an empty sample."""
+    NaN on an empty sample."""
     n = len(xs)
     if n == 0:
         return float("nan")

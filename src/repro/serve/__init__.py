@@ -2,14 +2,17 @@
 
 Engines (``repro.serve.engine``):
 
-- :class:`ServeEngine` — static batch, dense per-row KV cache.
-- :class:`ContinuousBatchingEngine` — FIFO queue + slot ring + stagewise
-  (b₁ρˢ) admission ramp over a dense cache.
-- :class:`PagedContinuousBatchingEngine` — the same scheduling over a
-  **paged** cache with radix prefix sharing and chunked prefill.
-- :class:`DisaggregatedEngine` — the paged engine split into a prefill
-  worker and a decode worker on disjoint submeshes, each with its own
-  page pool; finished prefills stream their KV pages across.
+- :class:`ServeEngine` — static batch, dense per-row KV cache; the greedy
+  reference the scheduled engine is tested against.
+- :class:`PagedContinuousBatchingEngine` — the serving engine: FIFO queue +
+  slot ring + stagewise (b₁ρˢ) admission ramp over a **paged** cache with
+  radix prefix sharing and chunked prefill.
+- :class:`DisaggregatedEngine` — the same engine laid out as a prefill
+  worker and a decode worker on disjoint submeshes, each with its own page
+  pool; finished prefills stream their KV pages across.
+
+Both scheduled engines are built from :class:`~repro.serve.engine.PagedWorker`:
+one page pool, its cache and its compiled steps on one device.
 
 Memory model of the paged engine (``repro.serve.pages``):
 
@@ -63,7 +66,6 @@ from repro.serve.step import (
     build_page_import_step,
     build_paged_decode_step,
     build_prefill_step,
-    build_slot_decode_step,
     sample_tokens,
 )
 from repro.serve.pages import (
@@ -85,9 +87,8 @@ from repro.serve.scheduler import (
     Transfer,
     TransferQueue,
 )
-from repro.serve.slots import PagedSlotManager, SlotManager
+from repro.serve.slots import PagedSlotManager
 from repro.serve.engine import (
-    ContinuousBatchingEngine,
     DisaggregatedEngine,
     PagedContinuousBatchingEngine,
     ServeEngine,
@@ -96,7 +97,6 @@ from repro.serve.engine import (
 __all__ = [
     "AdmissionController",
     "AdmissionPlan",
-    "ContinuousBatchingEngine",
     "DisaggregatedEngine",
     "PageExport",
     "PageImport",
@@ -107,7 +107,6 @@ __all__ = [
     "Request",
     "RequestScheduler",
     "ServeEngine",
-    "SlotManager",
     "Transfer",
     "TransferQueue",
     "build_chunk_prefill_step",
@@ -116,7 +115,6 @@ __all__ = [
     "build_page_import_step",
     "build_paged_decode_step",
     "build_prefill_step",
-    "build_slot_decode_step",
     "export_pages",
     "import_pages",
     "plan_admission",

@@ -1,24 +1,29 @@
 """Serving engines.
 
-Two engines share the model's prefill/decode cache path:
-
-- :class:`ServeEngine` — the static-batch baseline: one fixed batch of
+- :class:`ServeEngine` — the static-batch reference: one fixed batch of
   same-length prompts, prefilled together and decoded greedily in lockstep.
-- :class:`ContinuousBatchingEngine` — the production-shaped path: requests
-  enter a FIFO queue (:mod:`repro.serve.scheduler`), are prefilled one at a
-  time and *inserted into a freed slot of the live KV cache mid-decode-loop*
-  (``LanguageModel.cache_insert``), and a fixed-shape jitted decode tick
-  advances every slot at its own depth with per-slot sampling params. The
-  active slot budget ramps stagewise (b₁ρˢ) under sustained load via
-  :class:`~repro.serve.scheduler.AdmissionController` — the serving mirror
-  of SEBS's stagewise batch enlargement — and each stage compiles exactly
-  one decode variant (``engine._decodes``, mirroring
-  ``SEBSTrainer._steps``).
+- :class:`PagedContinuousBatchingEngine` — the serving path: requests enter
+  a FIFO queue (:mod:`repro.serve.scheduler`), are prefilled in chunks into
+  a paged KV cache with radix prefix sharing, and a fixed-shape jitted
+  decode tick advances every slot at its own depth with per-slot sampling
+  params. The active slot budget ramps stagewise (b₁ρˢ) under sustained
+  load via :class:`~repro.serve.scheduler.AdmissionController` — the
+  serving mirror of SEBS's stagewise batch enlargement — and each stage
+  compiles exactly one decode variant (mirroring ``SEBSTrainer._steps``).
+- :class:`DisaggregatedEngine` — the same engine laid out as a prefill
+  worker and a decode worker on disjoint submeshes.
+
+Both scheduled engines are built from :class:`PagedWorker` (one page pool,
+its cache and its compiled steps on one device, with the phase bodies that
+touch them) and share intake, stats and tracing through
+:class:`_PagedEngine`. Each keeps its own ``run()`` loop: the order of the
+phases decides the RNG draws, the stats and the trace.
 """
 from __future__ import annotations
 
+import functools
 from collections import deque
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -44,7 +49,7 @@ from repro.serve.scheduler import (
     Transfer,
     TransferQueue,
 )
-from repro.serve.slots import PagedSlotManager, SlotManager
+from repro.serve.slots import PagedSlotManager
 from repro.serve.step import (
     build_chunk_prefill_step,
     build_decode_step,
@@ -52,7 +57,6 @@ from repro.serve.step import (
     build_page_import_step,
     build_paged_decode_step,
     build_prefill_step,
-    build_slot_decode_step,
     sample_tokens,
 )
 
@@ -90,38 +94,240 @@ class ServeEngine:
         return np.asarray(jnp.concatenate(out, axis=1))
 
 
-class ContinuousBatchingEngine:
-    """Continuous-batching engine with a stagewise admission ramp.
+class PagedWorker:
+    """One page pool on one device, and everything that touches it.
 
-    Usage: ``submit()`` any number of requests (mixed prompt lengths,
-    per-request ``max_new_tokens`` / ``temperature`` / ``top_k``), then
-    ``run()`` to completion. ``run`` returns ``{request_id: (P+new,) tokens}``.
+    Holds the params, a :class:`~repro.serve.pages.PagePool` (+ optional
+    :class:`~repro.serve.pages.RadixPrefixIndex`), the paged cache (KV slab
+    + ``num_slots`` recurrent-state rows, allocated once — stage ramps only
+    widen host arrays and the compiled tick), and the executable caches the
+    sanitizer audits: ``_decodes`` per ring width, ``_chunk_steps`` per
+    chunk bucket. ``admission`` bounds the tick widths the audit admits: the
+    engine's SEBS controller, or a one-rung ladder at the fixed ring width
+    of the disaggregated prefill worker's tail tick. A worker built with no
+    ``prefill_chunks`` may never compile a chunk-prefill variant.
 
-    ``b1``/``rho``/``max_slots``/``patience`` parameterize the admission
-    ramp; the default ``b1=None`` starts at ``max_slots`` (no ramp). With
-    ``b1 < max_slots`` the slot ring starts narrow and is enlarged
-    geometrically only under sustained queue pressure, so light traffic pays
-    the smallest decode batch and heavy traffic amortizes per-token dispatch
-    over a wide ring — one compiled decode variant per stage.
+    The phase bodies — admit, one prefill chunk, one compiled tick, publish,
+    release — are shared by both engines; the order they run in belongs to
+    each engine's loop. Counters land in the engine's ``stats`` dict.
     """
 
     def __init__(
         self,
+        name: str,
         model: LanguageModel,
         params,
-        cache_len: int = 256,
-        max_slots: int = 8,
-        b1: Optional[int] = None,
-        rho: float = 2.0,
-        patience: int = 2,
-        admission: Optional[AdmissionController] = None,
-        seed: int = 0,
-        tracer: Optional[Tracer] = None,
-        metrics: Optional[MetricsRegistry] = None,
+        admission: AdmissionController,
+        stats: Dict[str, Any],
+        num_slots: int,
+        num_pages: int,
+        page_size: int,
+        share: bool,
+        prefill_chunks=(),
     ):
+        self.name = name
         self.model = model
         self.params = params
+        self.admission = admission
+        self.stats = stats  # the engine's dict: reset in place, never replaced
+        self.pool = PagePool(num_pages, page_size)
+        self.index = RadixPrefixIndex(self.pool) if share else None
+        self.prefill_chunks = tuple(sorted(set(int(c) for c in prefill_chunks)))
+        assert min(self.prefill_chunks, default=1) >= 1
+        self.cache = model.init_paged_cache(num_pages, page_size, num_slots)
+        self._decodes: Dict[int, Any] = {}  # ring width -> paged decode tick
+        self._chunk_steps: Dict[int, Any] = {}  # chunk size -> prefill step
+        self.decode_compiles = 0
+        self.prefill_compiles = 0
+        self._copy_page = jax.jit(model.paged_copy_page)
+        self._zero_state = jax.jit(model.paged_zero_state_row)
+        self.encode = jax.jit(model._encode) if model.cfg.is_encoder_decoder else None
+
+    # -- compiled-step caches ------------------------------------------------
+    # both steps donate the paged cache: the worker's only reference is
+    # reassigned from each step's return, and without donation every call
+    # pays a pool-sized memcpy before it computes anything
+    def decode_for(self, width: int):
+        if width not in self._decodes:
+            self._decodes[width] = build_paged_decode_step(
+                self.model, width, donate=True
+            )
+            self.decode_compiles += 1
+        return self._decodes[width]
+
+    def chunk_for(self, size: int):
+        if size not in self._chunk_steps:
+            self._chunk_steps[size] = build_chunk_prefill_step(self.model, donate=True)
+            self.prefill_compiles += 1
+        return self._chunk_steps[size]
+
+    # page streaming (disaggregated engine): built on first use, so only the
+    # worker that exports or imports holds the step. The export gather reads
+    # the *current* cache value, never a donated buffer; the adoption
+    # scatter donates the cache, else every adoption copies the whole pool
+    # in front of the next decode tick
+    @functools.cached_property
+    def export_step(self):
+        return build_page_export_step(self.model)
+
+    @functools.cached_property
+    def import_step(self):
+        return build_page_import_step(self.model, donate=True)
+
+    # -- sanitizer seam ------------------------------------------------------
+    def audit_pages(self, slots: PagedSlotManager, what: str) -> None:
+        """REPRO_SANITIZE=1 hook: exact refcount reconstruction of this
+        pool after every pool-mutating transition."""
+        if sanitize.enabled():
+            plans = [s.plan for s in slots.slots if not s.free]
+            sanitize.audit_page_pool(
+                self.pool, self.index, plans, where=f"after {self.name} {what}"
+            )
+
+    # -- phase bodies --------------------------------------------------------
+    def admit(self, slots: PagedSlotManager, i: int, req, total: int):
+        """Plan pages for ``total`` positions of ``req`` and install it in
+        slot ``i`` with a fresh state row. Returns the plan, or None when the
+        pool cannot fit it even after eviction."""
+        plan = plan_admission(
+            self.pool, self.index, req.prompt, total, share=self.index is not None
+        )
+        if plan is None:
+            return None
+        if plan.cow_src is not None:
+            # copy-on-write: duplicate the divergence page, reuse its first
+            # reuse_len % page_size positions, overwrite from there on
+            self.cache = self._copy_page(
+                self.cache, jnp.int32(plan.cow_src), jnp.int32(plan.new_pages[0])
+            )
+            self.stats["cow_copies"] += 1
+        self.cache = self._zero_state(self.cache, jnp.int32(i))
+        slots.admit(i, req, plan)
+        self.stats["prefix_tokens_reused"] += plan.reuse_len
+        self.stats["prompt_tokens_total"] += len(req.prompt)
+        self.audit_pages(slots, f"admit(slot {i})")
+        return plan
+
+    def prefill_chunk(self, slots: PagedSlotManager, i: int, memory=None):
+        """Prefill the largest declared chunk that fits slot ``i``'s
+        remaining prompt and return its logits; None for a sub-chunk tail,
+        which a tick teacher-forces. ``memory`` is the engine's per-slot
+        encoder buffer (encoder-decoder models only)."""
+        slot = slots.slots[i]
+        bucket = max(
+            (c for c in self.prefill_chunks if c <= slot.prompt_remaining), default=None
+        )
+        if bucket is None:
+            return None
+        step = self.chunk_for(bucket)
+        toks = jnp.asarray(slot.request.prompt[slot.fill : slot.fill + bucket][None, :])
+        if memory is not None:
+            memory = jax.lax.dynamic_slice_in_dim(memory, i, 1, axis=0)
+        logits, self.cache = step(
+            self.params,
+            toks,
+            self.cache,
+            jnp.int32(slot.fill),
+            jnp.int32(i),
+            jnp.asarray(slots.page_table[i : i + 1]),
+            memory=memory,
+        )
+        slot.fill += bucket
+        self.stats["prefill_chunks"] += 1
+        self.stats["prefill_tokens_computed"] += bucket
+        return logits
+
+    def tick(self, slots: PagedSlotManager, width: int, key, memory=None):
+        """One fixed-shape tick at ``width``: decoding slots advance one
+        token, prefilling slots teacher-force their sub-chunk prompt tail
+        (counted as prefill). Returns the device tokens and the numbers of
+        decoded and teacher-forced lanes."""
+        active = slots.active_mask()
+        step = self.decode_for(width)
+        n_forced = sum(
+            1 for i in range(width) if active[i] and slots.slots[i].prefilling
+        )
+        nxt, self.cache = step(
+            self.params,
+            jnp.asarray(slots.feed_tokens()[:, None]),
+            self.cache,
+            jnp.asarray(slots.positions()),
+            jnp.asarray(slots.page_table),
+            jnp.asarray(active),
+            jnp.asarray(slots.temperatures()),
+            jnp.asarray(slots.top_ks()),
+            key,
+            memory=memory,
+        )
+        self.stats["prefill_tokens_computed"] += n_forced
+        return nxt, int(active.sum()) - n_forced, n_forced
+
+    def publish(self, slots: PagedSlotManager, i: int) -> None:
+        """Register slot ``i``'s full prompt pages in the radix index, once."""
+        slot = slots.slots[i]
+        if self.index is None or slot.published:
+            return
+        publish_prefix(self.index, slot.request.prompt, slot.plan.pages)
+        slot.published = True
+        self.audit_pages(slots, f"publish(slot {i})")
+
+    def release(self, slots: PagedSlotManager, i: int) -> None:
+        release_pages(self.pool, slots.slots[i].plan.pages)
+        slots.release(i)
+        self.audit_pages(slots, f"release(slot {i})")
+
+
+class _PagedEngine:
+    """What both scheduled engines share: request intake, the admission
+    ramp, the engine's one RNG stream, stats, tracing, and the bodies that
+    touch them (first-token sampling, the decode tick's record, request
+    completion). Subclasses build their workers and their ``run()`` loop."""
+
+    def __init__(
+        self,
+        model: LanguageModel,
+        cache_len: int,
+        max_slots: int,
+        b1: Optional[int],
+        rho: float,
+        patience: int,
+        admission: Optional[AdmissionController],
+        seed: int,
+        page_size: int,
+        num_pages: Optional[int],
+        prefix_cache: bool,
+        kernel: str,
+        tracer: Optional[Tracer],
+        metrics: Optional[MetricsRegistry],
+    ):
+        if kernel not in ("xla", "pallas"):
+            raise ValueError(f"kernel must be 'xla' or 'pallas', got {kernel!r}")
+        if model.cfg.decode_kernel != kernel:
+            # same params, same pytree: only the attention/sampler dispatch
+            # inside the jitted steps changes
+            model = type(model)(model.cfg.replace(decode_kernel=kernel))
+        self.kernel = kernel
+        self.model = model
         self.cache_len = cache_len
+        self.page_size = page_size
+        self.max_pages = -(-cache_len // page_size)  # logical pages per slot
+        self.max_slots = max_slots
+        # default (decode) pool: dense-equivalent capacity (+ scratch page 0);
+        # pass a smaller num_pages to run under memory pressure (LRU eviction
+        # / deferred admission kick in)
+        self.num_pages = (
+            num_pages if num_pages is not None else 1 + max_slots * self.max_pages
+        )
+        # recurrent-state (SSM/RWKV) and encoder-decoder prefix state is not
+        # addressable by token content alone: sharing silently disables
+        cfg = model.cfg
+        mixers = {b.mixer for s in cfg.segments for b in s.body}
+        self.prefix_sharing = (
+            bool(prefix_cache)
+            and not cfg.is_encoder_decoder
+            and not cfg.num_vision_tokens
+            and mixers <= {"attn", "swa"}
+        )
         self.admission = admission or AdmissionController(
             b1=b1 if b1 is not None else max_slots,
             rho=rho,
@@ -135,19 +341,8 @@ class ContinuousBatchingEngine:
         self.metrics = metrics if metrics is not None else NULL_METRICS
         self._clock = self.tracer.clock
         self.scheduler = RequestScheduler(clock=self._clock, tracer=self.tracer)
-        # jax.jit caches prefill executables per prompt length internally
-        self._prefill = build_prefill_step(model, donate=False)
-
-        def prefill_encdec(params, batch, cache):
-            # encode once, share the memory between prefill and decode
-            memory = model._encode(params, batch)
-            logits, cache = model.prefill(params, batch, cache, memory=memory)
-            return logits, cache, memory
-
-        self._prefill_encdec = jax.jit(prefill_encdec)
-        self._decodes: Dict[int, Any] = {}  # ring width -> jitted decode tick
-        self.decode_compiles = 0  # compile-count hook (cf. SEBSTrainer._steps)
         self._rng = jax.random.key(seed)
+        self._chunk_rr = 0  # round-robin cursor over prefilling slots
         self.stats: Dict[str, Any] = self._fresh_stats()
 
     @staticmethod
@@ -158,15 +353,32 @@ class ContinuousBatchingEngine:
             "peak_width": 0,
             # bounded: a long-lived engine ticks indefinitely
             "stage_history": deque(maxlen=4096),
+            "prefill_chunks": 0,
+            "prefill_tokens_computed": 0,
+            "prefix_tokens_reused": 0,
+            "prompt_tokens_total": 0,
+            "cow_copies": 0,
+            # wall time per tick from the tick's start to the decode tokens
+            # landing on host — the latency a decoding slot experiences per
+            # token, INCLUDING any prompt chunk that the single-mesh tick
+            # interleaves in front of the decode step (the head-of-line
+            # block disaggregation removes). Only ticks that decoded ≥ 1
+            # real (non-teacher-forced) token are recorded.
+            "decode_tick_s": deque(maxlen=4096),
         }
 
     def reset_stats(self) -> None:
         """Zero every counter for a fresh measurement window, in place (the
-        dict identity is stable — callers may hold a reference). Compiled
-        decode variants and the admission ramp are untouched; pair with
-        ``engine.admission.reset()`` to restart the ramp too."""
+        dict identity is stable — callers and workers hold a reference), and
+        rebase every pool's monotonic high-water mark, so the next
+        ``memory_stats()`` reports the peak of the new window — not a
+        cold-start warmup's. Published prefix pages, compiled steps and the
+        admission ramp are kept; pair with ``engine.admission.reset()`` to
+        restart the ramp too."""
         self.stats.clear()
         self.stats.update(self._fresh_stats())
+        for worker in self.workers:
+            worker.pool.peak_used = worker.pool.used
 
     # -- request intake ------------------------------------------------------
     def submit(
@@ -179,6 +391,8 @@ class ContinuousBatchingEngine:
         tag: str = "",
     ) -> int:
         prompt = np.asarray(prompt, np.int32).reshape(-1)
+        # max_pages rounds cache_len UP to a page multiple; don't let that
+        # widen the per-request bound
         assert prompt.size + max_new_tokens <= self.cache_len, "cache_len too small"
         if self.model.cfg.is_encoder_decoder and memory is None:
             raise ValueError("encoder-decoder model requires per-request audio memory")
@@ -191,146 +405,104 @@ class ContinuousBatchingEngine:
             tag=tag,
         )
 
-    # -- compiled-step caches ------------------------------------------------
-    def _decode_for(self, width: int):
-        if width not in self._decodes:
-            self._decodes[width] = build_slot_decode_step(self.model, donate=False)
-            self.decode_compiles += 1
-        return self._decodes[width]
+    # -- bodies shared by both loops -----------------------------------------
+    def _next_key(self):
+        self._rng, key = jax.random.split(self._rng)
+        return key
 
-    # -- device-state plumbing ----------------------------------------------
-    def _grow_cache(self, cache, new_width: int):
-        # cache_insert handles arbitrary-width inserts: the old ring is one
-        # wide "slot" written at row 0 of the fresh, wider cache
-        grown = self.model.init_cache(new_width, self.cache_len)
-        return self.model.cache_insert(grown, cache, 0)
-
-    def _prefill_request(self, req):
-        """Batch-1 prefill of one admitted request. Returns the sampled first
-        token, the request's batch-1 cache (ready for ``cache_insert``), and
-        the encoder memory row (encoder-decoder models only)."""
-        batch = {"tokens": jnp.asarray(req.prompt[None, :], jnp.int32)}
-        cache = self.model.init_cache(1, self.cache_len)
-        memory_row = None
-        if self.model.cfg.is_encoder_decoder:
-            batch["audio_embeds"] = jnp.asarray(req.memory)
-            logits, cache, memory_row = self._prefill_encdec(self.params, batch, cache)
-        else:
-            logits, cache = self._prefill(self.params, batch, cache)
-        self._rng, sub = jax.random.split(self._rng)
+    def _sample_first(self, req, logits) -> int:
         first = sample_tokens(
             logits[:, -1, : self.model.cfg.vocab_size].astype(jnp.float32),
-            sub,
+            self._next_key(),
             jnp.asarray([req.temperature], jnp.float32),
             jnp.asarray([req.top_k], jnp.int32),
         )
-        return int(first[0]), cache, memory_row
+        return int(first[0])
 
-    # -- the serve loop ------------------------------------------------------
-    def run(self) -> Dict[int, np.ndarray]:
-        """Drive admission + decode until every submitted request is done.
-        Returns results for the requests completed during THIS call only
-        (re-running a long-lived engine does not replay old results)."""
-        completed: Dict[int, np.ndarray] = {}
-        width = self.admission.budget()
-        slots = SlotManager(width)
-        cache = self.model.init_cache(width, self.cache_len)
-        memory_buf = None
-        if self.model.cfg.is_encoder_decoder:
-            cfg = self.model.cfg
-            memory_buf = jnp.zeros(
-                (width, cfg.encoder_seq, cfg.d_model), jnp.dtype(cfg.compute_dtype)
+    def _decode_tick(
+        self, worker: PagedWorker, slots: PagedSlotManager, width: int, t_tick: float,
+        memory=None,
+    ) -> np.ndarray:
+        """One decode tick on ``worker`` with its stats, trace span and
+        metrics; the span opens at ``t_tick``. Returns the tick's tokens on
+        the host."""
+        nxt, n_decoded, n_forced = worker.tick(slots, width, self._next_key(), memory)
+        self.stats["ticks"] += 1
+        self.stats["decoded_tokens"] += n_decoded
+        self.stats["stage_history"].append(self.admission.stage)
+        nxt = np.asarray(nxt)  # block: the tick's tokens reach the host
+        if n_decoded > 0:
+            # one clock read, shared by the stat deque and the trace span:
+            # percentiles derived from either source agree on the exact
+            # same floats
+            t_now = self._clock()
+            self.stats["decode_tick_s"].append(t_now - t_tick)
+            self.tracer.complete(
+                "serve.decode_tick",
+                t_tick,
+                t_now,
+                width=width,
+                decoded=n_decoded,
+                forced=n_forced,
             )
+            self.metrics.histogram("serve.decode_tick_s").observe(t_now - t_tick)
+        self.metrics.counter("serve.decoded_tokens").inc(n_decoded)
+        self.metrics.counter("serve.ticks").inc()
+        return nxt
 
-        while self.scheduler.has_work():
-            # 1. stagewise ramp: enlarge the ring under sustained pressure
-            budget = self.admission.observe(self.scheduler.demand)
-            if budget > width:
-                cache = self._grow_cache(cache, budget)
-                slots.grow(budget)
-                if memory_buf is not None:
-                    pad = jnp.zeros(
-                        (budget - width,) + memory_buf.shape[1:], memory_buf.dtype
-                    )
-                    memory_buf = jnp.concatenate([memory_buf, pad], axis=0)
-                width = budget
-            self.stats["peak_width"] = max(self.stats["peak_width"], width)
+    def _trace_counters(self, width: int, pool: Dict[str, int], **queue) -> None:
+        if not self.tracer.enabled:
+            return
+        self.tracer.counter("serve.pool", **pool)
+        self.tracer.counter(
+            "serve.queue",
+            waiting=self.scheduler.num_waiting,
+            running=self.scheduler.num_running,
+            **queue,
+        )
+        self.tracer.counter("serve.admission", stage=self.admission.stage, budget=width)
+        self.tracer.counter(
+            "serve.prefix",
+            reused=self.stats["prefix_tokens_reused"],
+            total=self.stats["prompt_tokens_total"],
+        )
 
-            # 2. admit queued requests into freed slots (mid-decode-loop
-            #    in-place cache insertion)
-            for i in slots.free_indices():
-                req = self.scheduler.pop_waiting()
-                if req is None:
-                    break
-                first, slot_cache, memory_row = self._prefill_request(req)
-                cache = self.model.cache_insert(cache, slot_cache, i)
-                if memory_row is not None:
-                    memory_buf = jax.lax.dynamic_update_slice_in_dim(
-                        memory_buf, memory_row.astype(memory_buf.dtype), i, axis=0
-                    )
-                slots.admit(i, req, first)
-                # dense prefill is synchronous: handoff + first token land
-                # together at admission
-                self.scheduler.prefill_done(req)
-                self.scheduler.first_token(req)
-                if len(req.generated) >= req.max_new_tokens:
-                    self.scheduler.finish(req)
-                    completed[req.id] = req.tokens()
-                    slots.release(i)
-            if not slots.num_active():
-                continue
+    def _complete(
+        self, worker: PagedWorker, slots: PagedSlotManager, i: int, completed
+    ) -> None:
+        """Finish slot ``i``'s request and return its pages. A request that
+        finishes in the tick it started decoding (tail path,
+        ``max_new_tokens == 1``) gets its handoff stamped here; both stamps
+        are idempotent."""
+        req = slots.slots[i].request
+        self.scheduler.prefill_done(req)
+        self.scheduler.first_token(req)
+        self.scheduler.finish(req)
+        completed[req.id] = req.tokens()
+        worker.release(slots, i)
 
-            # 3. one fixed-shape decode tick over the whole ring
-            t_tick = self._clock()
-            step = self._decode_for(width)
-            self._rng, sub = jax.random.split(self._rng)
-            nxt, cache, _ = step(
-                self.params,
-                jnp.asarray(slots.tokens[:, None]),
-                cache,
-                jnp.asarray(slots.positions()),
-                jnp.asarray(slots.active_mask()),
-                jnp.asarray(slots.temperatures()),
-                jnp.asarray(slots.top_ks()),
-                sub,
-                memory=memory_buf,
-            )
-            self.stats["ticks"] += 1
-            self.stats["decoded_tokens"] += slots.num_active()
-            self.stats["stage_history"].append(self.admission.stage)
-            nxt = np.asarray(nxt)  # block: the tick's tokens reach the host
-            if self.tracer.enabled:
-                t_now = self._clock()
-                self.tracer.complete(
-                    "serve.decode_tick",
-                    t_tick,
-                    t_now,
-                    width=width,
-                    decoded=slots.num_active(),
-                )
-                self.tracer.counter(
-                    "serve.queue",
-                    waiting=self.scheduler.num_waiting,
-                    running=self.scheduler.num_running,
-                )
-                self.tracer.counter(
-                    "serve.admission", stage=self.admission.stage, budget=width
-                )
-                self.metrics.histogram("serve.decode_tick_s").observe(t_now - t_tick)
-            self.metrics.counter("serve.decoded_tokens").inc(slots.num_active())
-            self.metrics.counter("serve.ticks").inc()
-
-            # 4. bookkeeping: collect finished requests, free their slots
-            for i in slots.advance(nxt):
-                req = slots.slots[i].request
-                self.scheduler.finish(req)
-                completed[req.id] = req.tokens()
-                slots.release(i)
-
+    def _audit_run_end(self) -> None:
         if sanitize.enabled():
-            sanitize.audit_engine_compiles(self, where="(run end)")
+            for worker in self.workers:
+                sanitize.audit_engine_compiles(worker, where=f"(run end, {worker.name})")
             sanitize.audit_tracer(self.tracer, where="(run end)")
-        return completed
+
+    # -- reporting -----------------------------------------------------------
+    def memory_stats(self) -> Dict[str, Any]:
+        """KV memory accounting (attention leaves only; recurrent state is
+        O(1)/slot in both layouts): each pool's high-water mark vs what a
+        dense per-slot cache pins for the same ring."""
+        per_page = self.model.paged_kv_bytes_per_page(self.page_size)
+        dense_rows = max(self.stats["peak_width"], 1)
+        return {
+            "page_size": self.page_size,
+            **self._pool_stats(per_page),
+            "kv_bytes_dense_equiv": dense_rows * self.max_pages * per_page,
+            "prefix_hit_rate": (
+                self.stats["prefix_tokens_reused"]
+                / max(self.stats["prompt_tokens_total"], 1)
+            ),
+        }
 
     def latencies(self) -> Dict[int, float]:
         """Per-request wall-clock latency (submit → finish) for DONE requests."""
@@ -341,21 +513,28 @@ class ContinuousBatchingEngine:
         }
 
 
-class PagedContinuousBatchingEngine:
+class PagedContinuousBatchingEngine(_PagedEngine):
     """Continuous batching over a paged KV cache with radix prefix sharing.
 
-    Differences vs :class:`ContinuousBatchingEngine`:
+    Usage: ``submit()`` any number of requests (mixed prompt lengths,
+    per-request ``max_new_tokens`` / ``temperature`` / ``top_k``), then
+    ``run()`` to completion. ``run`` returns ``{request_id: (P+new,) tokens}``.
 
+    - **Admission ramp**: ``b1``/``rho``/``max_slots``/``patience``
+      parameterize it; the default ``b1=None`` starts at ``max_slots`` (no
+      ramp). With ``b1 < max_slots`` the slot ring starts narrow and is
+      enlarged geometrically only under sustained queue pressure — one
+      compiled decode variant per stage.
     - **Memory**: attention KV lives in a :class:`~repro.serve.pages.PagePool`
       of ``page_size``-token pages; a slot holds a page *table*, not a dense
       ``cache_len`` row, so resident KV scales with live tokens (high-water
-      mark reported in ``stats``) instead of ``max_slots × cache_len``.
+      mark reported by ``memory_stats()``) instead of
+      ``max_slots × cache_len``.
     - **Prefix sharing**: prompts sharing a prefix alias the same published,
       immutable pages through a :class:`~repro.serve.pages.RadixPrefixIndex`
       (token-granular: the divergence page is copy-on-written). Enabled for
       attention-only decoder models; recurrent-state (SSM/RWKV) and
-      encoder-decoder families silently disable it — their prefix state is
-      not addressable by token content alone.
+      encoder-decoder families silently disable it.
     - **Chunked prefill**: a prompt is computed in fixed-size chunks (one
       compiled executable per entry of ``prefill_chunks``, since position
       offsets are traced), at most one chunk per engine tick, interleaved
@@ -363,9 +542,7 @@ class PagedContinuousBatchingEngine:
       sub-chunk tail rides the regular decode tick teacher-forced — zero
       extra compiled shapes for arbitrary prompt lengths.
 
-    Greedy outputs are token-identical to the static :class:`ServeEngine`;
-    the SEBS admission ladder (one compiled decode variant per stage) is
-    unchanged.
+    Greedy outputs are token-identical to the static :class:`ServeEngine`.
     """
 
     def __init__(
@@ -387,211 +564,34 @@ class PagedContinuousBatchingEngine:
         tracer: Optional[Tracer] = None,
         metrics: Optional[MetricsRegistry] = None,
     ):
-        if kernel not in ("xla", "pallas"):
-            raise ValueError(f"kernel must be 'xla' or 'pallas', got {kernel!r}")
-        if model.cfg.decode_kernel != kernel:
-            # same params, same pytree: only the attention/sampler dispatch
-            # inside the jitted steps changes
-            model = type(model)(model.cfg.replace(decode_kernel=kernel))
-        self.kernel = kernel
-        self.model = model
-        self.params = params
-        self.cache_len = cache_len
-        self.page_size = page_size
-        self.max_pages = -(-cache_len // page_size)  # logical pages per slot
-        # default pool: dense-equivalent capacity (+ scratch page 0); pass a
-        # smaller num_pages to run under memory pressure (LRU eviction /
-        # deferred admission kick in)
-        self.num_pages = (
-            num_pages if num_pages is not None else 1 + max_slots * self.max_pages
+        super().__init__(
+            model, cache_len, max_slots, b1, rho, patience, admission, seed,
+            page_size, num_pages, prefix_cache, kernel, tracer, metrics,
         )
-        self.pool = PagePool(self.num_pages, page_size)
-        self.prefix_sharing = bool(prefix_cache) and self._sharing_supported(model)
-        self.index = RadixPrefixIndex(self.pool) if self.prefix_sharing else None
-        self.prefill_chunks = tuple(sorted(set(int(c) for c in prefill_chunks)))
-        assert self.prefill_chunks and min(self.prefill_chunks) >= 1
-        self.max_slots = max_slots
-        self.admission = admission or AdmissionController(
-            b1=b1 if b1 is not None else max_slots,
-            rho=rho,
-            max_slots=max_slots,
-            patience=patience,
+        self.worker = PagedWorker(
+            "paged", self.model, params, self.admission, self.stats, max_slots,
+            self.num_pages, page_size, self.prefix_sharing, prefill_chunks,
         )
-        self.tracer = tracer if tracer is not None else NULL_TRACER
-        self.metrics = metrics if metrics is not None else NULL_METRICS
-        self._clock = self.tracer.clock
-        self.scheduler = RequestScheduler(clock=self._clock, tracer=self.tracer)
-        # device state: paged KV slab + full-width recurrent state, allocated
-        # once — stage ramps only widen host arrays and the compiled tick
-        self.cache = model.init_paged_cache(self.num_pages, page_size, max_slots)
-        self._decodes: Dict[int, Any] = {}  # ring width -> paged decode tick
-        self._chunk_steps: Dict[int, Any] = {}  # chunk size -> prefill step
-        self.decode_compiles = 0
-        self.prefill_compiles = 0
-        self._copy_page = jax.jit(model.paged_copy_page)
-        self._zero_state = jax.jit(model.paged_zero_state_row)
-        self._encode = jax.jit(model._encode) if model.cfg.is_encoder_decoder else None
-        self._rng = jax.random.key(seed)
-        self._chunk_rr = 0  # round-robin cursor over prefilling slots
-        self.stats: Dict[str, Any] = self._fresh_stats()
+        self.workers = (self.worker,)
 
-    @staticmethod
-    def _fresh_stats() -> Dict[str, Any]:
-        stats = ContinuousBatchingEngine._fresh_stats()
-        stats.update(
-            prefill_chunks=0,
-            prefill_tokens_computed=0,
-            prefix_tokens_reused=0,
-            prompt_tokens_total=0,
-            cow_copies=0,
-            # wall time per tick from the first prefill-chunk dispatch to the
-            # decode tokens landing on host — the latency a decoding slot
-            # experiences per token, INCLUDING any prompt chunk that the tick
-            # interleaved in front of the decode step (the head-of-line block
-            # disaggregation removes). Only ticks that decoded ≥ 1 real
-            # (non-teacher-forced) token are recorded.
-            decode_tick_s=deque(maxlen=4096),
-        )
-        return stats
+    @property
+    def decode_compiles(self) -> int:
+        return self.worker.decode_compiles
 
-    def reset_stats(self) -> None:
-        """Zero every counter (the dense engine's plus the paged extras)
-        and rebase the page pool's monotonic high-water mark, so the next
-        ``memory_stats()`` reports the peak of the new measurement window —
-        not a cold-start warmup's. Published prefix pages and compiled
-        steps are kept (steady-state semantics)."""
-        self.stats.clear()
-        self.stats.update(self._fresh_stats())
-        self.pool.peak_used = self.pool.used
-
-    @staticmethod
-    def _sharing_supported(model: LanguageModel) -> bool:
-        cfg = model.cfg
-        mixers = {b.mixer for s in cfg.segments for b in s.body}
-        return (
-            not cfg.is_encoder_decoder
-            and not cfg.num_vision_tokens
-            and mixers <= {"attn", "swa"}
-        )
-
-    # -- request intake ------------------------------------------------------
-    def submit(
-        self,
-        prompt,
-        max_new_tokens: int = 16,
-        temperature: float = 0.0,
-        top_k: int = 0,
-        memory=None,
-        tag: str = "",
-    ) -> int:
-        prompt = np.asarray(prompt, np.int32).reshape(-1)
-        # same per-request bound as the dense engines (max_pages rounds
-        # cache_len UP to a page multiple; don't let that widen the contract)
-        assert prompt.size + max_new_tokens <= self.cache_len, "cache_len too small"
-        if self.model.cfg.is_encoder_decoder and memory is None:
-            raise ValueError("encoder-decoder model requires per-request audio memory")
-        return self.scheduler.submit(
-            prompt,
-            max_new_tokens,
-            temperature=temperature,
-            top_k=top_k,
-            memory=memory,
-            tag=tag,
-        )
-
-    # -- compiled-step caches ------------------------------------------------
-    # both steps donate the paged cache: the engine's only reference is
-    # reassigned from each step's return, and without donation every tick
-    # pays a pool-sized memcpy before it computes anything
-    def _decode_for(self, width: int):
-        if width not in self._decodes:
-            self._decodes[width] = build_paged_decode_step(
-                self.model, width, donate=True
-            )
-            self.decode_compiles += 1
-        return self._decodes[width]
-
-    def _chunk_for(self, size: int):
-        if size not in self._chunk_steps:
-            self._chunk_steps[size] = build_chunk_prefill_step(self.model, donate=True)
-            self.prefill_compiles += 1
-        return self._chunk_steps[size]
-
-    # -- sanitizer seam ------------------------------------------------------
-    def _audit_pages(self, slots: PagedSlotManager, where: str) -> None:
-        """REPRO_SANITIZE=1 hook: exact refcount reconstruction after every
-        pool-mutating transition (admit / publish / finish)."""
-        if sanitize.enabled():
-            plans = [s.plan for s in slots.slots if not s.free]
-            sanitize.audit_page_pool(self.pool, self.index, plans, where=where)
-
-    # -- admission -----------------------------------------------------------
-    def _admit(self, slots: PagedSlotManager, i: int, req, memory_buf):
-        total = len(req.prompt) + req.max_new_tokens
-        plan = plan_admission(
-            self.pool, self.index, req.prompt, total, share=self.prefix_sharing
-        )
-        if plan is None:
-            return None, memory_buf
-        if plan.cow_src is not None:
-            # copy-on-write: duplicate the divergence page, reuse its first
-            # reuse_len % page_size positions, overwrite from there on
-            self.cache = self._copy_page(
-                self.cache, jnp.int32(plan.cow_src), jnp.int32(plan.new_pages[0])
-            )
-            self.stats["cow_copies"] += 1
-        self.cache = self._zero_state(self.cache, jnp.int32(i))
-        if self._encode is not None:
-            row = self._encode(self.params, {"audio_embeds": jnp.asarray(req.memory)})
-            memory_buf = jax.lax.dynamic_update_slice_in_dim(
-                memory_buf, row.astype(memory_buf.dtype), i, axis=0
-            )
-        slots.admit(i, req, plan)
-        self.stats["prefix_tokens_reused"] += plan.reuse_len
-        self.stats["prompt_tokens_total"] += len(req.prompt)
-        self._audit_pages(slots, where=f"after admit(slot {i})")
-        return plan, memory_buf
-
-    def _sample_first(self, req, logits):
-        self._rng, sub = jax.random.split(self._rng)
-        first = sample_tokens(
-            logits[:, -1, : self.model.cfg.vocab_size].astype(jnp.float32),
-            sub,
-            jnp.asarray([req.temperature], jnp.float32),
-            jnp.asarray([req.top_k], jnp.int32),
-        )
-        return int(first[0])
-
-    def _finish(self, slots: PagedSlotManager, i: int, completed):
-        slot = slots.slots[i]
-        req = slot.request
-        release_pages(self.pool, slot.plan.pages)
-        # a request finishing in the same tick it started decoding (tail
-        # path, max_new_tokens == 1) reaches here before the bookkeeping
-        # loop stamped its handoff; both stamps are idempotent
-        self.scheduler.prefill_done(req)
-        self.scheduler.first_token(req)
-        self.scheduler.finish(req)
-        completed[req.id] = req.tokens()
-        slots.release(i)
-        self._audit_pages(slots, where=f"after release(slot {i})")
-
-    def _maybe_publish(self, slots: PagedSlotManager, i: int):
-        slot = slots.slots[i]
-        if self.index is None or slot.published or not slot.decoding:
-            return
-        publish_prefix(self.index, slot.request.prompt, slot.plan.pages)
-        slot.published = True
-        self._audit_pages(slots, where=f"after publish(slot {i})")
+    @property
+    def prefill_compiles(self) -> int:
+        return self.worker.prefill_compiles
 
     # -- the serve loop ------------------------------------------------------
     def run(self) -> Dict[int, np.ndarray]:
         """Drive admission + chunked prefill + decode until every submitted
-        request is done. Returns results completed during THIS call."""
+        request is done. Returns results completed during THIS call (re-
+        running a long-lived engine does not replay old results)."""
         completed: Dict[int, np.ndarray] = {}
+        worker = self.worker
         width = self.admission.budget()
         slots = PagedSlotManager(
-            width, self.max_pages, chunk_floor=min(self.prefill_chunks)
+            width, self.max_pages, chunk_floor=min(worker.prefill_chunks)
         )
         memory_buf = None
         if self.model.cfg.is_encoder_decoder:
@@ -616,10 +616,14 @@ class PagedContinuousBatchingEngine:
                 req = self.scheduler.pop_waiting()
                 if req is None:
                     break
-                plan, memory_buf = self._admit(slots, i, req, memory_buf)
-                if plan is None:
+                if worker.admit(slots, i, req, len(req.prompt) + req.max_new_tokens) is None:
                     self.scheduler.requeue(req)
                     break
+                if memory_buf is not None:
+                    row = worker.encode(worker.params, {"audio_embeds": jnp.asarray(req.memory)})
+                    memory_buf = jax.lax.dynamic_update_slice_in_dim(
+                        memory_buf, row.astype(memory_buf.dtype), i, axis=0
+                    )
                 admitted += 1
             if slots.num_active() == 0:
                 if admitted == 0 and self.scheduler.has_work():
@@ -631,7 +635,7 @@ class PagedContinuousBatchingEngine:
                     # wedged eviction raised here spuriously — and a request
                     # requeued at the final tick was lost with the run.
                     raise RuntimeError(
-                        f"page pool ({self.pool.capacity} pages of {self.page_size}) "
+                        f"page pool ({worker.pool.capacity} pages of {self.page_size}) "
                         "cannot fit the next request even after eviction"
                     )
                 if not self.scheduler.has_work():
@@ -643,298 +647,74 @@ class PagedContinuousBatchingEngine:
             t_tick = self._clock()
             prefilling = slots.prefilling_indices()
             self._chunk_rr += 1
-            for i in prefilling[self._chunk_rr % max(len(prefilling), 1):] + \
-                    prefilling[: self._chunk_rr % max(len(prefilling), 1)]:
-                slot = slots.slots[i]
-                rem = slot.prompt_remaining
-                bucket = max(
-                    (c for c in self.prefill_chunks if c <= rem), default=None
-                )
-                if bucket is None:
+            off = self._chunk_rr % max(len(prefilling), 1)
+            for i in prefilling[off:] + prefilling[:off]:
+                logits = worker.prefill_chunk(slots, i, memory_buf)
+                if logits is None:
                     continue  # sub-chunk tail: teacher-forced by the tick below
-                step = self._chunk_for(bucket)
+                slot = slots.slots[i]
                 req = slot.request
-                toks = jnp.asarray(req.prompt[slot.fill : slot.fill + bucket][None, :])
-                mem = None
-                if memory_buf is not None:
-                    mem = jax.lax.dynamic_slice_in_dim(memory_buf, i, 1, axis=0)
-                logits, self.cache = step(
-                    self.params,
-                    toks,
-                    self.cache,
-                    jnp.int32(slot.fill),
-                    jnp.int32(i),
-                    jnp.asarray(slots.page_table[i : i + 1]),
-                    memory=mem,
-                )
-                slot.fill += bucket
-                self.stats["prefill_chunks"] += 1
-                self.stats["prefill_tokens_computed"] += bucket
                 if slot.prompt_remaining == 0:
                     slots.start_decoding(i, self._sample_first(req, logits))
                     self.scheduler.prefill_done(req)
                     self.scheduler.first_token(req)
-                    self._maybe_publish(slots, i)
+                    worker.publish(slots, i)
                     if len(req.generated) >= req.max_new_tokens:
-                        self._finish(slots, i, completed)
+                        self._complete(worker, slots, i, completed)
                 break
 
             # 4. one fixed-shape decode tick: decoding slots advance one
             #    token, prefilling slots teacher-force their prompt tail
-            active = slots.active_mask()
-            if not active.any():
+            if not slots.active_mask().any():
                 continue
-            step = self._decode_for(width)
-            self._rng, sub = jax.random.split(self._rng)
-            n_forced = sum(
-                1 for i in range(width) if active[i] and slots.slots[i].prefilling
+            nxt = self._decode_tick(worker, slots, width, t_tick, memory_buf)
+            self._trace_counters(
+                width, {"used": worker.pool.used, "capacity": worker.pool.capacity}
             )
-            nxt, self.cache = step(
-                self.params,
-                jnp.asarray(slots.feed_tokens()[:, None]),
-                self.cache,
-                jnp.asarray(slots.positions()),
-                jnp.asarray(slots.page_table),
-                jnp.asarray(active),
-                jnp.asarray(slots.temperatures()),
-                jnp.asarray(slots.top_ks()),
-                sub,
-                memory=memory_buf,
-            )
-            n_decoded = int(active.sum()) - n_forced
-            self.stats["ticks"] += 1
-            self.stats["decoded_tokens"] += n_decoded
-            self.stats["prefill_tokens_computed"] += n_forced
-            self.stats["stage_history"].append(self.admission.stage)
-            nxt = np.asarray(nxt)  # block: the tick's tokens reach the host
-            if n_decoded > 0:
-                # one clock read, shared by the stat deque and the trace
-                # span: percentiles derived from either source agree on
-                # the exact same floats
-                t_now = self._clock()
-                self.stats["decode_tick_s"].append(t_now - t_tick)
-                self.tracer.complete(
-                    "serve.decode_tick",
-                    t_tick,
-                    t_now,
-                    width=width,
-                    decoded=n_decoded,
-                    forced=n_forced,
-                )
-                self.metrics.histogram("serve.decode_tick_s").observe(t_now - t_tick)
-            if self.tracer.enabled:
-                self.tracer.counter(
-                    "serve.pool", used=self.pool.used, capacity=self.pool.capacity
-                )
-                self.tracer.counter(
-                    "serve.queue",
-                    waiting=self.scheduler.num_waiting,
-                    running=self.scheduler.num_running,
-                )
-                self.tracer.counter(
-                    "serve.admission", stage=self.admission.stage, budget=width
-                )
-                self.tracer.counter(
-                    "serve.prefix",
-                    reused=self.stats["prefix_tokens_reused"],
-                    total=self.stats["prompt_tokens_total"],
-                )
-            self.metrics.counter("serve.decoded_tokens").inc(n_decoded)
-            self.metrics.counter("serve.ticks").inc()
-            self.metrics.gauge("serve.pool_used").set(self.pool.used)
+            self.metrics.gauge("serve.pool_used").set(worker.pool.used)
 
             # 5. bookkeeping: newly-decoding slots timestamp their handoff
             #    and publish their prefix, finished requests release pages
             for i in slots.advance(nxt):
-                self._maybe_publish(slots, i)
-                self._finish(slots, i, completed)
-            for i in range(width):
-                slot = slots.slots[i]
-                if slot.free:
+                worker.publish(slots, i)
+                self._complete(worker, slots, i, completed)
+            for i, slot in enumerate(slots.slots):
+                if not slot.decoding:
                     continue
-                if slot.decoding and slot.request.t_prefill_done == 0.0:
+                if slot.request.t_prefill_done == 0.0:
                     # tail-path handoff: advance() appended the first token
                     # inside this tick
                     self.scheduler.prefill_done(slot.request)
                     self.scheduler.first_token(slot.request)
-                self._maybe_publish(slots, i)
+                worker.publish(slots, i)
 
-        if sanitize.enabled():
-            sanitize.audit_engine_compiles(self, where="(run end)")
-            sanitize.audit_tracer(self.tracer, where="(run end)")
+        self._audit_run_end()
         return completed
 
-    # -- reporting -----------------------------------------------------------
-    def latencies(self) -> Dict[int, float]:
+    def _pool_stats(self, per_page: int) -> Dict[str, Any]:
+        pool = self.worker.pool
         return {
-            rid: req.latency
-            for rid, req in self.scheduler.requests.items()
-            if req.state == DONE
-        }
-
-    def memory_stats(self) -> Dict[str, Any]:
-        """KV memory accounting (attention leaves only; recurrent state is
-        O(1)/slot in both layouts): the paged high-water mark vs what the
-        dense engine pins for the same ring."""
-        per_page = self.model.paged_kv_bytes_per_page(self.page_size)
-        dense_rows = max(self.stats["peak_width"], 1)
-        return {
-            "page_size": self.page_size,
-            "pages_capacity": self.pool.capacity,
-            "pages_peak": self.pool.peak_used,
-            "kv_bytes_peak": self.pool.peak_used * per_page,
-            "kv_bytes_dense_equiv": dense_rows * self.max_pages * per_page,
-            "prefix_hit_rate": (
-                self.stats["prefix_tokens_reused"]
-                / max(self.stats["prompt_tokens_total"], 1)
-            ),
+            "pages_capacity": pool.capacity,
+            "pages_peak": pool.peak_used,
+            "kv_bytes_peak": pool.peak_used * per_page,
         }
 
 
-class _DisaggWorker:
-    """Shared shape of the two disaggregated workers: a private page pool
-    (+ optional radix index), params and a paged cache committed to the
-    worker's submesh lead device, and the executable caches the sanitizer
-    audits. ``audit_engine_compiles`` duck-types against these attributes;
-    ``admission`` bounds the worker's tick widths — the engine's SEBS
-    controller for the decode worker, a single-rung ladder at the fixed
-    ring width for the prefill worker's tail tick."""
-
-    def __init__(
-        self,
-        model: LanguageModel,
-        params,
-        device,
-        admission: AdmissionController,
-        num_pages: int,
-        page_size: int,
-        prefix_cache: bool,
-    ):
-        self.model = model
-        self.params = params
-        self.device = device
-        self.admission = admission
-        self.pool = PagePool(num_pages, page_size)
-        self.index = RadixPrefixIndex(self.pool) if prefix_cache else None
-        self.cache = None  # committed by DisaggregatedEngine.__init__
-        self._decodes: Dict[int, Any] = {}
-        self._chunk_steps: Dict[int, Any] = {}
-        self.prefill_chunks: Tuple[int, ...] = ()
-        self.decode_compiles = 0
-        self.prefill_compiles = 0
-
-    def audit_pages(self, slots: PagedSlotManager, where: str) -> None:
-        """REPRO_SANITIZE=1 hook: exact refcount reconstruction for THIS
-        worker's pool after every pool-mutating transition."""
-        if sanitize.enabled():
-            plans = [s.plan for s in slots.slots if not s.free]
-            sanitize.audit_page_pool(self.pool, self.index, plans, where=where)
-
-
-class _PrefillWorker(_DisaggWorker):
-    """Prefill half: chunked prefill at its own ring width and chunk shape,
-    plus the COW-copy / state-zero / page-export helpers. Prompt tails
-    shorter than the smallest chunk bucket ride the worker's own
-    teacher-forced tick — the same ``build_paged_decode_step`` executable
-    family as the single-mesh tail path (the chunked-attention branch
-    requires ≥ 2 tokens), compiled once at the fixed prefill ring width.
-    The worker's ladder is the single rung ``[ring]``, so the compile audit
-    bounds it to exactly that one tick variant."""
-
-    def __init__(
-        self,
-        model: LanguageModel,
-        params,
-        device,
-        ring: int,
-        num_pages: int,
-        page_size: int,
-        prefix_cache: bool,
-        prefill_chunks,
-    ):
-        super().__init__(
-            model,
-            params,
-            device,
-            AdmissionController(b1=ring, max_slots=ring),
-            num_pages,
-            page_size,
-            prefix_cache,
-        )
-        self.ring = ring
-        self.prefill_chunks = tuple(sorted(set(int(c) for c in prefill_chunks)))
-        assert self.prefill_chunks and min(self.prefill_chunks) >= 1
-        self._copy_page = jax.jit(model.paged_copy_page)
-        self._zero_state = jax.jit(model.paged_zero_state_row)
-        self._export = build_page_export_step(model)
-
-    # chunk + tail steps donate the prefill cache (only reference is
-    # reassigned per step); the export gather reads the *current* cache
-    # value and never an old donated buffer
-    def chunk_for(self, size: int):
-        if size not in self._chunk_steps:
-            self._chunk_steps[size] = build_chunk_prefill_step(self.model, donate=True)
-            self.prefill_compiles += 1
-        return self._chunk_steps[size]
-
-    def tick(self):
-        """The tail tick, compiled at the prefill ring width."""
-        if self.ring not in self._decodes:
-            self._decodes[self.ring] = build_paged_decode_step(
-                self.model, self.ring, donate=True
-            )
-            self.decode_compiles += 1
-        return self._decodes[self.ring]
-
-
-class _DecodeWorker(_DisaggWorker):
-    """Decode half: pure fixed-shape decode ticks behind the SEBS admission
-    ladder, plus the page-import scatter that adopts streamed prefills.
-    ``prefill_chunks`` stays ``()`` and ``_chunk_steps`` stays ``{}`` by
-    construction — the REPRO_SANITIZE compile audit *enforces* that this
-    worker never compiles a chunk-prefill variant."""
-
-    def __init__(
-        self,
-        model: LanguageModel,
-        params,
-        device,
-        admission: AdmissionController,
-        num_pages: int,
-        page_size: int,
-        prefix_cache: bool,
-    ):
-        super().__init__(
-            model, params, device, admission, num_pages, page_size, prefix_cache
-        )
-        # the adoption scatter donates the decode cache: the worker's only
-        # reference is reassigned from the step's return, and without
-        # donation every adoption copies the full decode pool, queueing a
-        # pool-sized memcpy in front of the next decode tick
-        self._import = build_page_import_step(model, donate=True)
-
-    def decode_for(self, width: int):
-        if width not in self._decodes:
-            self._decodes[width] = build_paged_decode_step(
-                self.model, width, donate=True
-            )
-            self.decode_compiles += 1
-        return self._decodes[width]
-
-
-class DisaggregatedEngine:
+class DisaggregatedEngine(_PagedEngine):
     """Disaggregated prefill/decode serving across two submeshes.
 
-    Splits :class:`PagedContinuousBatchingEngine` into two workers on
-    disjoint device groups (:func:`~repro.launch.mesh.make_disagg_submeshes`
-    carves them from one ``("pod", "data", "model")`` host mesh; each worker
+    The paged engine laid out as two workers (:class:`PagedWorker`) on
+    disjoint device groups (:func:`~repro.launch.mesh.make_disagg_submeshes` carves
+    them from one ``("pod", "data", "model")`` host mesh; each worker
     anchors to its submesh's lead device):
 
     - the **prefill worker** runs chunked prefill at its own ring width
       (``prefill_slots``) and chunk shape against a private
       :class:`~repro.serve.pages.PagePool` — long prompts no longer share a
       tick with decode, so they can use large chunk buckets without
-      stretching any running request's inter-token latency;
+      stretching any running request's inter-token latency. Prompt tails
+      shorter than the smallest chunk ride the worker's own teacher-forced
+      tick, compiled once at the fixed prefill ring width;
     - the **decode worker** runs pure fixed-shape decode ticks behind the
       SEBS admission ladder against its own pool; it compiles *no*
       chunk-prefill variants (one executable per ladder stage, period).
@@ -994,22 +774,16 @@ class DisaggregatedEngine:
         tracer: Optional[Tracer] = None,
         metrics: Optional[MetricsRegistry] = None,
     ):
-        if kernel not in ("xla", "pallas"):
-            raise ValueError(f"kernel must be 'xla' or 'pallas', got {kernel!r}")
+        super().__init__(
+            model, cache_len, max_slots, b1, rho, patience, admission, seed,
+            page_size, num_pages, prefix_cache, kernel, tracer, metrics,
+        )
         if model.cfg.is_encoder_decoder:
             raise NotImplementedError(
                 "disaggregated serving does not support encoder-decoder models: "
                 "per-request encoder memory is dense per-slot state and does "
                 "not page-stream"
             )
-        if model.cfg.decode_kernel != kernel:
-            model = type(model)(model.cfg.replace(decode_kernel=kernel))
-        self.kernel = kernel
-        self.model = model
-        self.cache_len = cache_len
-        self.page_size = page_size
-        self.max_pages = -(-cache_len // page_size)
-        self.max_slots = max_slots
         self.prefill_slots = int(prefill_slots)
         assert self.prefill_slots >= 1
         devices = jax.devices()
@@ -1019,25 +793,9 @@ class DisaggregatedEngine:
             decode_device = devices[1] if len(devices) > 1 else devices[0]
         self.prefill_device = prefill_device
         self.decode_device = decode_device
-        self.prefix_sharing = bool(prefix_cache) and (
-            PagedContinuousBatchingEngine._sharing_supported(model)
-        )
-        self.admission = admission or AdmissionController(
-            b1=b1 if b1 is not None else max_slots,
-            rho=rho,
-            max_slots=max_slots,
-            patience=patience,
-        )
-        self.tracer = tracer if tracer is not None else NULL_TRACER
-        self.metrics = metrics if metrics is not None else NULL_METRICS
-        self._clock = self.tracer.clock
-        self.scheduler = RequestScheduler(clock=self._clock, tracer=self.tracer)
         self.transfers = TransferQueue()
         # independent pools: decode sized like the single-mesh engine,
         # prefill sized to its own (smaller) ring — prompts only
-        self.num_pages = (
-            num_pages if num_pages is not None else 1 + max_slots * self.max_pages
-        )
         self.prefill_pages = (
             prefill_pages
             if prefill_pages is not None
@@ -1048,53 +806,28 @@ class DisaggregatedEngine:
         # replicated per worker, each cache is committed to its worker's
         # device, so every executable dispatches on its own submesh and the
         # only bytes crossing at runtime are streamed page blocks
-        self.prefill = _PrefillWorker(
-            model,
-            jax.device_put(params, prefill_device),
-            prefill_device,
-            self.prefill_slots,
-            self.prefill_pages,
-            page_size,
-            self.prefix_sharing,
-            prefill_chunks,
+        self.prefill = PagedWorker(
+            "prefill", self.model, jax.device_put(params, prefill_device),
+            AdmissionController(b1=self.prefill_slots, max_slots=self.prefill_slots),
+            self.stats, self.prefill_slots, self.prefill_pages, page_size,
+            self.prefix_sharing, prefill_chunks,
         )
-        self.decode = _DecodeWorker(
-            model,
-            jax.device_put(params, decode_device),
-            decode_device,
-            self.admission,
-            self.num_pages,
-            page_size,
+        self.decode = PagedWorker(
+            "decode", self.model, jax.device_put(params, decode_device),
+            self.admission, self.stats, max_slots, self.num_pages, page_size,
             self.prefix_sharing,
         )
-        self.prefill.cache = jax.device_put(
-            model.init_paged_cache(self.prefill_pages, page_size, self.prefill_slots),
-            prefill_device,
-        )
-        self.decode.cache = jax.device_put(
-            model.init_paged_cache(self.num_pages, page_size, max_slots),
-            decode_device,
-        )
-        self._rng = jax.random.key(seed)
-        self._chunk_rr = 0
-        self.stats: Dict[str, Any] = self._fresh_stats()
+        self.prefill.cache = jax.device_put(self.prefill.cache, prefill_device)
+        self.decode.cache = jax.device_put(self.decode.cache, decode_device)
+        self.workers = (self.prefill, self.decode)
 
-    @staticmethod
-    def _fresh_stats() -> Dict[str, Any]:
-        stats = PagedContinuousBatchingEngine._fresh_stats()
+    def _fresh_stats(self) -> Dict[str, Any]:
+        stats = super()._fresh_stats()
         stats.update(transfers=0, pages_streamed=0, pages_adopted=0, seam_bytes=0)
         return stats
 
-    def reset_stats(self) -> None:
-        """Zero every counter and rebase BOTH pools' high-water marks (see
-        :meth:`PagedContinuousBatchingEngine.reset_stats`)."""
-        self.stats.clear()
-        self.stats.update(self._fresh_stats())
-        self.prefill.pool.peak_used = self.prefill.pool.used
-        self.decode.pool.peak_used = self.decode.pool.used
-
     # compiled-variant counters, shaped like the single-mesh engine's for
-    # launcher/benchmark logging: decode variants only ever live on the
+    # launcher logging: decode variants only ever live on the
     # decode worker, chunk variants only on the prefill worker
     @property
     def decode_compiles(self) -> int:
@@ -1103,21 +836,6 @@ class DisaggregatedEngine:
     @property
     def prefill_compiles(self) -> int:
         return self.prefill.prefill_compiles
-
-    # -- request intake ------------------------------------------------------
-    def submit(
-        self,
-        prompt,
-        max_new_tokens: int = 16,
-        temperature: float = 0.0,
-        top_k: int = 0,
-        tag: str = "",
-    ) -> int:
-        prompt = np.asarray(prompt, np.int32).reshape(-1)
-        assert prompt.size + max_new_tokens <= self.cache_len, "cache_len too small"
-        return self.scheduler.submit(
-            prompt, max_new_tokens, temperature=temperature, top_k=top_k, tag=tag
-        )
 
     # -- the streaming seam --------------------------------------------------
     def _stream(self, block):
@@ -1136,29 +854,7 @@ class DisaggregatedEngine:
             self.tracer.counter("serve.seam", cum_bytes=self.stats["seam_bytes"])
         return out
 
-    def _sample_first(self, req, logits):
-        self._rng, sub = jax.random.split(self._rng)
-        first = sample_tokens(
-            logits[:, -1, : self.model.cfg.vocab_size].astype(jnp.float32),
-            sub,
-            jnp.asarray([req.temperature], jnp.float32),
-            jnp.asarray([req.top_k], jnp.int32),
-        )
-        return int(first[0])
-
     # -- prefill side --------------------------------------------------------
-    def _admit_prefill(self, pslots: PagedSlotManager, i: int, req, plan):
-        if plan.cow_src is not None:
-            self.prefill.cache = self.prefill._copy_page(
-                self.prefill.cache, jnp.int32(plan.cow_src), jnp.int32(plan.new_pages[0])
-            )
-            self.stats["cow_copies"] += 1
-        self.prefill.cache = self.prefill._zero_state(self.prefill.cache, jnp.int32(i))
-        pslots.admit(i, req, plan)
-        self.stats["prefix_tokens_reused"] += plan.reuse_len
-        self.stats["prompt_tokens_total"] += len(req.prompt)
-        self.prefill.audit_pages(pslots, where=f"after prefill admit(slot {i})")
-
     def _chunk_tick(self, pslots: PagedSlotManager, completed) -> None:
         """One chunk per prefilling slot per engine tick (round-robin start,
         so no slot starves inside the ring). Each slot takes the largest
@@ -1172,29 +868,10 @@ class DisaggregatedEngine:
         self._chunk_rr += 1
         off = self._chunk_rr % len(prefilling)
         for i in prefilling[off:] + prefilling[:off]:
-            slot = pslots.slots[i]
-            rem = slot.prompt_remaining
-            bucket = max(
-                (c for c in self.prefill.prefill_chunks if c <= rem), default=None
-            )
-            if bucket is None:
-                continue  # sub-chunk tail: teacher-forced by _tail_tick
-            step = self.prefill.chunk_for(bucket)
-            req = slot.request
-            toks = jnp.asarray(req.prompt[slot.fill : slot.fill + bucket][None, :])
-            logits, self.prefill.cache = step(
-                self.prefill.params,
-                toks,
-                self.prefill.cache,
-                jnp.int32(slot.fill),
-                jnp.int32(i),
-                jnp.asarray(pslots.page_table[i : i + 1]),
-            )
-            slot.fill += bucket
-            self.stats["prefill_chunks"] += 1
-            self.stats["prefill_tokens_computed"] += bucket
-            if slot.prompt_remaining == 0:
-                self._handoff(pslots, i, self._sample_first(req, logits), completed)
+            logits = self.prefill.prefill_chunk(pslots, i)
+            if logits is not None and pslots.slots[i].prompt_remaining == 0:
+                first = self._sample_first(pslots.slots[i].request, logits)
+                self._handoff(pslots, i, first, completed)
 
     def _tail_tick(self, pslots: PagedSlotManager, completed) -> None:
         """One teacher-forced tick over the prefill ring for prompt tails
@@ -1203,46 +880,20 @@ class DisaggregatedEngine:
         prefill ring width. A lane consuming its LAST prompt token keeps the
         tick's sample as the request's first generated token and is handed
         off; every prefill-side sample before that is discarded."""
-        active = pslots.active_mask()
-        if not active.any():
+        if not pslots.active_mask().any():
             return
-        step = self.prefill.tick()
-        self._rng, sub = jax.random.split(self._rng)
-        n_forced = int(active.sum())
-        nxt, self.prefill.cache = step(
-            self.prefill.params,
-            jnp.asarray(pslots.feed_tokens()[:, None]),
-            self.prefill.cache,
-            jnp.asarray(pslots.positions()),
-            jnp.asarray(pslots.page_table),
-            jnp.asarray(active),
-            jnp.asarray(pslots.temperatures()),
-            jnp.asarray(pslots.top_ks()),
-            sub,
-        )
-        self.stats["prefill_tokens_computed"] += n_forced
+        nxt, _, _ = self.prefill.tick(pslots, self.prefill_slots, self._next_key())
         for i in pslots.advance(np.asarray(nxt)):
             # prompt done AND max_new_tokens == 1: finished without ever
             # touching the seam (advance appended the first token already)
-            slot = pslots.slots[i]
-            req = slot.request
-            if self.prefill.index is not None:
-                publish_prefix(self.prefill.index, req.prompt, slot.plan.pages)
-            release_pages(self.prefill.pool, slot.plan.pages)
-            self.scheduler.prefill_done(req)
-            self.scheduler.first_token(req)
-            self.scheduler.finish(req)
-            completed[req.id] = req.tokens()
-            pslots.release(i)
-            self.prefill.audit_pages(pslots, where=f"after prefill finish(slot {i})")
+            self.prefill.publish(pslots, i)
+            self._complete(self.prefill, pslots, i, completed)
         for i, slot in enumerate(pslots.slots):
-            if slot.free or not slot.decoding:
-                continue
-            # newly decoding = prompt completed this tick: reclaim the first
-            # token advance() appended (the decode worker re-appends it at
-            # adoption) and hand the slot off
-            first = slot.request.generated.pop()
-            self._handoff(pslots, i, first, completed)
+            if slot.decoding:
+                # prompt completed this tick: reclaim the first token
+                # advance() appended (the decode worker re-appends it at
+                # adoption) and hand the slot off
+                self._handoff(pslots, i, slot.request.generated.pop(), completed)
 
     def _handoff(self, pslots: PagedSlotManager, i: int, first: int, completed):
         """Prompt fully computed and ``first`` sampled (not yet appended):
@@ -1251,24 +902,17 @@ class DisaggregatedEngine:
         without touching the seam."""
         slot = pslots.slots[i]
         req = slot.request
-        if self.prefill.index is not None:
-            publish_prefix(self.prefill.index, req.prompt, slot.plan.pages)
+        self.prefill.publish(pslots, i)
         if req.max_new_tokens <= 1:
             req.generated.append(int(first))
-            release_pages(self.prefill.pool, slot.plan.pages)
-            self.scheduler.prefill_done(req)
-            self.scheduler.first_token(req)
-            self.scheduler.finish(req)
-            completed[req.id] = req.tokens()
-            pslots.release(i)
-            self.prefill.audit_pages(pslots, where=f"after prefill finish(slot {i})")
+            self._complete(self.prefill, pslots, i, completed)
             return
         export = export_pages(
             slot.plan, req.prompt, page_size=self.page_size, first_token=first
         )
         ids = np.zeros((self.max_pages,), np.int32)
         ids[: len(export.pages)] = export.pages
-        block = self.prefill._export(self.prefill.cache, jnp.asarray(ids), jnp.int32(i))
+        block = self.prefill.export_step(self.prefill.cache, jnp.asarray(ids), jnp.int32(i))
         self.transfers.push(Transfer(export=export, block=self._stream(block), request=req))
         self.scheduler.prefill_done(req)
         # the first token was sampled from the final chunk's logits just
@@ -1280,9 +924,7 @@ class DisaggregatedEngine:
         # functional cache *value*, so reallocating these physical pages to
         # the next admission cannot race the in-flight stream; published
         # pages live on under the prefill index for future prefix hits
-        release_pages(self.prefill.pool, slot.plan.pages)
-        pslots.release(i)
-        self.prefill.audit_pages(pslots, where=f"after export(slot {i})")
+        self.prefill.release(pslots, i)
 
     # -- decode side ---------------------------------------------------------
     def _adopt(self, dslots: PagedSlotManager, i: int, transfer, imp) -> None:
@@ -1298,27 +940,15 @@ class DisaggregatedEngine:
         for j, src in enumerate(export.pages):
             if src in imp.remap:
                 ids[j] = imp.remap[src]
-        self.decode.cache = self.decode._import(
+        self.decode.cache = self.decode.import_step(
             self.decode.cache, transfer.block, jnp.asarray(ids), jnp.int32(i)
         )
         dslots.admit(i, req, imp.plan)
-        slot = dslots.slots[i]
-        slot.fill = len(req.prompt)  # nothing left to prefill: KV arrived by stream
+        dslots.slots[i].fill = len(req.prompt)  # nothing left to prefill: KV arrived by stream
         dslots.start_decoding(i, export.first_token)
-        if self.decode.index is not None:
-            publish_prefix(self.decode.index, req.prompt, imp.plan.pages)
-            slot.published = True
+        self.decode.publish(dslots, i)
         self.stats["pages_adopted"] += imp.adopted
-        self.decode.audit_pages(dslots, where=f"after adopt(slot {i})")
-
-    def _finish_decode(self, dslots: PagedSlotManager, i: int, completed) -> None:
-        slot = dslots.slots[i]
-        req = slot.request
-        release_pages(self.decode.pool, slot.plan.pages)
-        self.scheduler.finish(req)
-        completed[req.id] = req.tokens()
-        dslots.release(i)
-        self.decode.audit_pages(dslots, where=f"after decode release(slot {i})")
+        self.decode.audit_pages(dslots, f"adopt(slot {i})")
 
     # -- the serve loop ------------------------------------------------------
     def run(self) -> Dict[int, np.ndarray]:
@@ -1354,17 +984,10 @@ class DisaggregatedEngine:
                 req = self.scheduler.pop_waiting()
                 if req is None:
                     break
-                plan = plan_admission(
-                    self.prefill.pool,
-                    self.prefill.index,
-                    req.prompt,
-                    len(req.prompt),  # prefill holds prompt pages only
-                    share=self.prefix_sharing,
-                )
-                if plan is None:
+                # prefill holds prompt pages only
+                if self.prefill.admit(pslots, i, req, len(req.prompt)) is None:
                     self.scheduler.requeue(req)
                     break
-                self._admit_prefill(pslots, i, req, plan)
                 prefill_admitted += 1
             # the queue head found no prefill pages with the ring empty:
             # no prefill-side release is pending and the unshared-replan
@@ -1426,63 +1049,16 @@ class DisaggregatedEngine:
             #    head-of-line block the single-mesh engine suffers (its tick
             #    runs chunk-then-decode on one device), measured by
             #    ``stats["decode_tick_s"]`` in both engines.
-            active = dslots.active_mask()
-            if active.any():
-                t_tick = self._clock()
-                step = self.decode.decode_for(width)
-                self._rng, sub = jax.random.split(self._rng)
-                nxt, self.decode.cache = step(
-                    self.decode.params,
-                    jnp.asarray(dslots.feed_tokens()[:, None]),
-                    self.decode.cache,
-                    jnp.asarray(dslots.positions()),
-                    jnp.asarray(dslots.page_table),
-                    jnp.asarray(active),
-                    jnp.asarray(dslots.temperatures()),
-                    jnp.asarray(dslots.top_ks()),
-                    sub,
-                )
-                n_decoded = int(active.sum())
-                self.stats["ticks"] += 1
-                self.stats["decoded_tokens"] += n_decoded
-                self.stats["stage_history"].append(self.admission.stage)
-                nxt = np.asarray(nxt)  # block: tokens on host, pre-prefill
-                # one clock read shared by the stat deque and the trace span
-                t_now = self._clock()
-                self.stats["decode_tick_s"].append(t_now - t_tick)
-                self.tracer.complete(
-                    "serve.decode_tick",
-                    t_tick,
-                    t_now,
-                    width=width,
-                    decoded=n_decoded,
-                )
-                self.metrics.histogram("serve.decode_tick_s").observe(t_now - t_tick)
-                self.metrics.counter("serve.decoded_tokens").inc(n_decoded)
-                self.metrics.counter("serve.ticks").inc()
+            if dslots.active_mask().any():
+                nxt = self._decode_tick(self.decode, dslots, width, self._clock())
                 # 5. finished requests release their decode-pool pages
                 for i in dslots.advance(nxt):
-                    self._finish_decode(dslots, i, completed)
-            if self.tracer.enabled:
-                self.tracer.counter(
-                    "serve.pool",
-                    decode_used=self.decode.pool.used,
-                    prefill_used=self.prefill.pool.used,
-                )
-                self.tracer.counter(
-                    "serve.queue",
-                    waiting=self.scheduler.num_waiting,
-                    running=self.scheduler.num_running,
-                    transfers=len(self.transfers),
-                )
-                self.tracer.counter(
-                    "serve.admission", stage=self.admission.stage, budget=width
-                )
-                self.tracer.counter(
-                    "serve.prefix",
-                    reused=self.stats["prefix_tokens_reused"],
-                    total=self.stats["prompt_tokens_total"],
-                )
+                    self._complete(self.decode, dslots, i, completed)
+            self._trace_counters(
+                width,
+                {"decode_used": self.decode.pool.used, "prefill_used": self.prefill.pool.used},
+                transfers=len(self.transfers),
+            )
 
             # 6. chunk steps, then one teacher-forced tick for sub-chunk
             #    prompt tails; completions export + stream (adopted at the
@@ -1490,39 +1066,18 @@ class DisaggregatedEngine:
             self._chunk_tick(pslots, completed)
             self._tail_tick(pslots, completed)
 
-        if sanitize.enabled():
-            sanitize.audit_engine_compiles(self.prefill, where="(run end, prefill)")
-            sanitize.audit_engine_compiles(self.decode, where="(run end, decode)")
-            sanitize.audit_tracer(self.tracer, where="(run end)")
+        self._audit_run_end()
         return completed
 
-    # -- reporting -----------------------------------------------------------
-    def latencies(self) -> Dict[int, float]:
+    def _pool_stats(self, per_page: int) -> Dict[str, Any]:
+        # peaks are per worker: they live on different submeshes, and
+        # summing them would compare apples to a dense single-device slab
         return {
-            rid: req.latency
-            for rid, req in self.scheduler.requests.items()
-            if req.state == DONE
-        }
-
-    def memory_stats(self) -> Dict[str, Any]:
-        """Two-pool KV accounting: peaks are per worker (they live on
-        different submeshes — summing them would compare apples to a dense
-        single-device slab), dense-equivalent and hit rate follow the
-        single-mesh definitions."""
-        per_page = self.model.paged_kv_bytes_per_page(self.page_size)
-        dense_rows = max(self.stats["peak_width"], 1)
-        return {
-            "page_size": self.page_size,
             "pages_capacity": self.decode.pool.capacity,
             "pages_peak": self.decode.pool.peak_used,
             "prefill_pages_capacity": self.prefill.pool.capacity,
             "prefill_pages_peak": self.prefill.pool.peak_used,
             "kv_bytes_peak": (
                 max(self.prefill.pool.peak_used, self.decode.pool.peak_used) * per_page
-            ),
-            "kv_bytes_dense_equiv": dense_rows * self.max_pages * per_page,
-            "prefix_hit_rate": (
-                self.stats["prefix_tokens_reused"]
-                / max(self.stats["prompt_tokens_total"], 1)
             ),
         }

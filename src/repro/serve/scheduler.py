@@ -331,7 +331,7 @@ class AdmissionController:
 
     def reset(self) -> None:
         """Return the ramp to stage 0 with no accumulated pressure — the
-        public warm-run seam: benchmarks re-time an engine whose compiled
+        public warm-run seam: a caller re-times an engine whose compiled
         decode variants are warm but whose admission history must not leak
         into the measured run."""
         self.stage = 0

@@ -1,114 +1,25 @@
-"""Slot-ring state for continuous batching.
+"""Slot-ring state for continuous batching over the paged KV cache.
 
-A *slot* is one row of the fixed-shape decode batch and its row of the
-KV/state cache. :class:`SlotManager` tracks which request occupies each
-slot, its decode depth (the cache position the next token will be written
-to), and its sampling parameters, and materializes the per-step device
-inputs (token / position / active-mask / temperature / top-k arrays) for
-``build_slot_decode_step``.
+A *slot* is one row of the fixed-shape decode batch. :class:`PagedSlotManager`
+tracks which request occupies each slot, its page table, its prefill
+progress and decode depth (the cache position the next token will be
+written to), and its sampling parameters, and materializes the per-tick
+device inputs (token / position / page-table / active-mask / temperature /
+top-k arrays) for ``build_paged_decode_step``.
 
-All bookkeeping is host-side numpy; the arrays are tiny (one scalar per
-slot) and re-uploaded each tick. The heavy state — the KV cache — lives on
-device and is only touched through the model's ``cache_insert`` helper at
-admission and the jitted decode step in between.
+All bookkeeping is host-side numpy; the arrays are tiny (one scalar or one
+page-table row per slot) and re-uploaded each tick. The heavy state — the
+paged cache — lives on device and is only touched through the jitted steps.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import List, Optional
 
 import numpy as np
 
 from repro.serve.pages import AdmissionPlan
 from repro.serve.scheduler import Request
-
-
-@dataclass
-class Slot:
-    request: Optional[Request] = None
-    pos: int = 0  # cache position of the token currently being fed
-
-    @property
-    def free(self) -> bool:
-        return self.request is None
-
-
-class SlotManager:
-    def __init__(self, num_slots: int):
-        self.slots: List[Slot] = [Slot() for _ in range(num_slots)]
-        self.tokens = np.zeros((num_slots,), np.int32)  # current input token
-
-    @property
-    def width(self) -> int:
-        return len(self.slots)
-
-    def grow(self, num_slots: int) -> None:
-        """Stage ramp: widen the ring (existing occupancy is preserved)."""
-        assert num_slots >= self.width
-        extra = num_slots - self.width
-        self.slots.extend(Slot() for _ in range(extra))
-        self.tokens = np.concatenate([self.tokens, np.zeros((extra,), np.int32)])
-
-    def free_indices(self) -> List[int]:
-        return [i for i, s in enumerate(self.slots) if s.free]
-
-    def active_indices(self) -> List[int]:
-        return [i for i, s in enumerate(self.slots) if not s.free]
-
-    def num_active(self) -> int:
-        return sum(not s.free for s in self.slots)
-
-    def admit(self, i: int, req: Request, first_token: int) -> None:
-        """Occupy slot ``i``: the request's prompt cache has been inserted
-        and ``first_token`` (sampled from the prefill logits) is the next
-        decode input at depth ``len(prompt)``."""
-        assert self.slots[i].free
-        self.slots[i] = Slot(request=req, pos=len(req.prompt))
-        self.tokens[i] = first_token
-        req.generated.append(int(first_token))
-
-    def release(self, i: int) -> None:
-        self.slots[i] = Slot()
-        self.tokens[i] = 0
-
-    # -- per-step device inputs ---------------------------------------------
-    def positions(self) -> np.ndarray:
-        return np.asarray([s.pos for s in self.slots], np.int32)
-
-    def active_mask(self) -> np.ndarray:
-        return np.asarray([not s.free for s in self.slots], bool)
-
-    def temperatures(self) -> np.ndarray:
-        return np.asarray(
-            [0.0 if s.free else s.request.temperature for s in self.slots], np.float32
-        )
-
-    def top_ks(self) -> np.ndarray:
-        return np.asarray(
-            [0 if s.free else s.request.top_k for s in self.slots], np.int32
-        )
-
-    def advance(self, next_tokens: np.ndarray) -> List[int]:
-        """Apply one decode tick's sampled tokens. Returns the slot indices
-        whose requests just finished (caller releases them after collecting
-        results)."""
-        finished = []
-        for i, slot in enumerate(self.slots):
-            if slot.free:
-                continue
-            req = slot.request
-            tok = int(next_tokens[i])
-            req.generated.append(tok)
-            slot.pos += 1
-            self.tokens[i] = tok
-            if len(req.generated) >= req.max_new_tokens:
-                finished.append(i)
-        return finished
-
-
-# ---------------------------------------------------------------------------
-# paged slot ring
-# ---------------------------------------------------------------------------
 
 
 @dataclass
@@ -142,8 +53,7 @@ class PagedSlotManager:
     ``(num_slots, max_pages)`` int32 array re-uploaded each tick; freed rows
     are zeroed so inactive lanes read/write only the scratch page 0.
 
-    Unlike :class:`SlotManager`, admission does not carry a prefilled cache:
-    a slot is admitted with an :class:`~repro.serve.pages.AdmissionPlan`
+    A slot is admitted with an :class:`~repro.serve.pages.AdmissionPlan`
     (pages + reused-prefix length) and filled in place — chunk steps for the
     bulk, teacher-forced decode ticks for the tail.
     """

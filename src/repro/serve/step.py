@@ -57,12 +57,15 @@ def sample_tokens(logits, key, temperature, top_k):
 def build_paged_decode_step(model: LanguageModel, width: int, *, donate: bool = True):
     """Fixed-shape decode tick over a paged slot ring.
 
-    Like :func:`build_slot_decode_step` but KV reads/writes go through a
-    per-slot ``page_table`` (B, max_pages) into the shared page pool, and the
-    cache's recurrent-state leaves stay at the full ``max_slots`` width: the
-    step slices the first ``width`` rows (static per compile), advances them,
-    and writes them back — so stage ramps never reshape device state and the
-    chunk-prefill executable (which sees the full-width tree) never recompiles.
+    Every slot advances one token at its own cache depth; freed slots ride
+    along masked out (their sampled token is discarded), so the compiled
+    shape depends only on the ring width — one compile per admission stage.
+    KV reads/writes go through a per-slot ``page_table`` (B, max_pages) into
+    the shared page pool, and the cache's recurrent-state leaves stay at the
+    full ``max_slots`` width: the step slices the first ``width`` rows
+    (static per compile), advances them, and writes them back — so stage
+    ramps never reshape device state and the chunk-prefill executable (which
+    sees the full-width tree) never recompiles.
 
     The tick doubles as the tail of a chunked prefill: a slot still being
     prefilled rides along *teacher-forced* — the host feeds the next prompt
@@ -138,31 +141,4 @@ def build_page_import_step(model: LanguageModel, *, donate: bool = False):
         return model.paged_import_slot(cache, block, page_ids, slot)
 
     kwargs = {"donate_argnums": (0,)} if donate else {}
-    return jax.jit(step, **kwargs)
-
-
-def build_slot_decode_step(model: LanguageModel, *, donate: bool = True):
-    """Fixed-shape decode tick over the slot ring (continuous batching).
-
-    Every slot advances one token at its own cache depth; freed slots ride
-    along masked out (their sampled token is discarded and their depth does
-    not advance), so the compiled shape depends only on the ring width — one
-    compile per admission stage.
-
-    Inputs per call: tokens (B, 1) int32, cache, cache_pos (B,) int32,
-    active (B,) bool, temperature (B,) f32, top_k (B,) int32, key (PRNG),
-    memory (optional encoder output (B, T, d)).
-    Returns (next_token (B,) int32, new_cache, new_pos (B,) int32).
-    """
-    vocab = model.cfg.vocab_size
-
-    def step(params, tokens, cache, cache_pos, active, temperature, top_k, key, memory=None):
-        logits, new_cache = model.decode_step(params, tokens, cache, cache_pos, memory=memory)
-        logits = logits[:, -1, :vocab].astype(jnp.float32)
-        nxt = sample_tokens(logits, key, temperature, top_k)
-        nxt = jnp.where(active, nxt, tokens[:, 0])
-        new_pos = jnp.where(active, cache_pos + 1, cache_pos)
-        return nxt, new_cache, new_pos
-
-    kwargs = {"donate_argnums": (2,)} if donate else {}
     return jax.jit(step, **kwargs)

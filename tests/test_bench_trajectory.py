@@ -272,20 +272,16 @@ def _exercise(engine, cfg, n=3):
     return engine.run()
 
 
-@pytest.mark.parametrize("kind", ["dense", "paged"])
+@pytest.mark.parametrize("kind", ["disagg", "paged"])
 def test_reset_restores_every_stats_key(smoke_model, kind):
-    from repro.serve import ContinuousBatchingEngine, PagedContinuousBatchingEngine
+    from repro.serve import DisaggregatedEngine, PagedContinuousBatchingEngine
 
     cfg, model, params = smoke_model
-    if kind == "dense":
-        make = lambda: ContinuousBatchingEngine(  # noqa: E731
-            model, params, cache_len=32, max_slots=2, b1=1, rho=2.0, patience=1
-        )
-    else:
-        make = lambda: PagedContinuousBatchingEngine(  # noqa: E731
-            model, params, cache_len=32, max_slots=2, b1=1, rho=2.0, patience=1,
-            page_size=4, prefill_chunks=(4,),
-        )
+    cls = DisaggregatedEngine if kind == "disagg" else PagedContinuousBatchingEngine
+    make = lambda: cls(  # noqa: E731
+        model, params, cache_len=32, max_slots=2, b1=1, rho=2.0, patience=1,
+        page_size=4, prefill_chunks=(4,),
+    )
     engine = make()
     _exercise(engine, cfg)
     assert engine.stats["ticks"] > 0 and engine.stats["decoded_tokens"] > 0
@@ -301,19 +297,20 @@ def test_reset_restores_every_stats_key(smoke_model, kind):
         assert list(engine.stats[key]) == list(val) if key == "stage_history" \
             else engine.stats[key] == val, key
     assert engine.admission.stage == 0 and engine.admission._pressure == 0
-    if kind == "paged":
-        # monotonic pool peak rebased to live usage for the next window
-        assert engine.pool.peak_used == engine.pool.used
+    # every pool's monotonic peak rebased to live usage for the next window
+    for worker in engine.workers:
+        assert worker.pool.peak_used == worker.pool.used
 
 
 def test_reset_engine_still_serves_identically(smoke_model):
     """After reset the engine must produce the same tokens as a fresh one
     (reset touches bookkeeping only, never device state semantics)."""
-    from repro.serve import ContinuousBatchingEngine
+    from repro.serve import PagedContinuousBatchingEngine
 
     cfg, model, params = smoke_model
-    make = lambda: ContinuousBatchingEngine(  # noqa: E731
-        model, params, cache_len=32, max_slots=2, b1=1, rho=2.0, patience=1, seed=7
+    make = lambda: PagedContinuousBatchingEngine(  # noqa: E731
+        model, params, cache_len=32, max_slots=2, b1=1, rho=2.0, patience=1, seed=7,
+        page_size=4, prefill_chunks=(4,),
     )
     warm = make()
     _exercise(warm, cfg)

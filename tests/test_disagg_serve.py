@@ -131,7 +131,7 @@ def test_disagg_identity_under_pressure_random_workloads(seed):
             err_msg=f"seed {seed} request {i} (len {len(prompts[i])}, "
                     f"budget {budgets[i]})",
         )
-    ref.pool.check()
+    ref.worker.pool.check()
     eng.prefill.pool.check()
     eng.decode.pool.check()
     assert len(eng.transfers) == 0

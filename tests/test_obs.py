@@ -1,5 +1,5 @@
 """Observability subsystem: tracer/metrics unit behaviour, percentile
-consistency with the serve benchmark's nearest-rank method, scheduler
+consistency with the nearest-rank formula, scheduler
 lifecycle timestamps, and the determinism guarantees — tracing must not
 change a single token, loss, or compiled executable."""
 import json
@@ -192,9 +192,9 @@ def test_fake_clock_makes_traces_bit_reproducible():
 
 
 def test_nearest_rank_matches_benchmark_formula():
-    """nearest_rank is a bit-identical port of the serve benchmark's _pct
-    (sorted(x)[ceil(q/100 * n) - 1]); the consistency contract that lets
-    tracer-derived percentiles replace the hand-rolled math."""
+    """nearest_rank is bit-identical to the nearest-rank formula
+    sorted(x)[ceil(q/100 * n) - 1], so tracer-derived percentiles need no
+    hand-rolled math."""
     rng = np.random.default_rng(0)
     for n in (1, 2, 7, 100):
         xs = rng.uniform(1e-4, 2.0, n).tolist()

@@ -12,9 +12,10 @@ this container, so correctness is proven, not eyeballed:
 - the fused sampler is bit-identical to serve/step.py's sample_tokens
   (greedy == argmax including ties; temperature/top-k streams match
   token-for-token from the same key);
-- the full PagedContinuousBatchingEngine produces token-identical output
-  with kernel="pallas" vs kernel="xla" on qwen (GQA) and gemma (sliding
-  window + logit softcap) configs.
+- the full PagedContinuousBatchingEngine produces token-identical greedy
+  output with kernel="pallas" vs kernel="xla" on qwen (GQA) and gemma
+  (sliding window + logit softcap) configs, and the paged decode step's
+  logits agree between the kernels within bf16 rounding.
 """
 import jax
 import jax.numpy as jnp
@@ -29,7 +30,7 @@ from repro.models import build_model
 from repro.models.layers.attention import _paged_write
 from repro.serve import PagedContinuousBatchingEngine
 from repro.serve.pages import PagePool
-from repro.serve.step import sample_tokens
+from repro.serve.step import build_chunk_prefill_step, sample_tokens
 
 
 def _assert_close(out, expect, dtype):
@@ -314,7 +315,7 @@ def _engine_tokens(arch, kernel, *, temperature=0.0, top_k=0):
         for p in prompts
     ]
     results = engine.run()
-    engine.pool.check()
+    engine.worker.pool.check()
     return [results[r] for r in ids]
 
 
@@ -330,12 +331,49 @@ def test_engine_greedy_token_identical(arch):
 
 
 def test_engine_sampled_token_identical():
-    """Fixed engine seed, temperature + top-k: the fused sampler consumes
-    the identical gumbel stream, so the sampled tokens match exactly."""
-    xla = _engine_tokens("qwen2.5-3b", "xla", temperature=0.8, top_k=5)
-    pallas = _engine_tokens("qwen2.5-3b", "pallas", temperature=0.8, top_k=5)
-    for i, (a, b) in enumerate(zip(xla, pallas)):
-        np.testing.assert_array_equal(a, b, err_msg=f"request {i}")
+    """Fixed engine seed, temperature + top-k: both kernels draw the same key
+    at every tick and the fused sampler maps identical logits to identical
+    tokens, so the sampled streams can part only where the kernels' logits
+    differ. Those differ by bf16 rounding (the smoke config computes in
+    bf16): engine runs on this config saw at most 3.5e-2 per logit, enough
+    to flip a near-tie between two Gumbel-perturbed scores or at the top-k
+    boundary, so sampled engine tokens are not compared. Held here: the
+    paged decode step's logits agree between the kernels within 6.25e-2
+    (two bf16 ulps at logit magnitude 4-8) on a cache filled by chunked
+    prefill, and both samplers draw the same tokens from them."""
+    cfg = get_config("qwen2.5-3b", "smoke")
+    models = {k: build_model(cfg.replace(decode_kernel=k)) for k in ("xla", "pallas")}
+    params, _ = models["xla"].init(jax.random.key(0))
+    ps, mp, lengths = 4, 6, [13, 6, 9]
+    slots = len(lengths)
+    cache = models["xla"].init_paged_cache(1 + slots * mp, ps, slots)
+    table = np.arange(1, 1 + slots * mp, dtype=np.int32).reshape(slots, mp)
+    rng = np.random.default_rng(0)
+    prefill = build_chunk_prefill_step(models["xla"], donate=False)
+    for s, n in enumerate(lengths):
+        prompt = jnp.asarray(rng.integers(0, cfg.vocab_size, (1, n)), jnp.int32)
+        _, cache = prefill(
+            params, prompt, cache, jnp.int32(0), jnp.int32(s), jnp.asarray(table[s : s + 1])
+        )
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (slots, 1)), jnp.int32)
+    logits = {}
+    for kernel, model in models.items():
+        out, _ = jax.jit(model.decode_step)(
+            params, tokens, cache, jnp.asarray(lengths, jnp.int32),
+            page_table=jnp.asarray(table),
+        )
+        logits[kernel] = out[:, -1, : cfg.vocab_size].astype(jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(logits["pallas"]), np.asarray(logits["xla"]), atol=6.25e-2, rtol=0
+    )
+    temp = jnp.full((slots,), 0.8, jnp.float32)
+    top_k = jnp.full((slots,), 5, jnp.int32)
+    for seed in range(4):
+        key = jax.random.key(seed)
+        np.testing.assert_array_equal(
+            np.asarray(pops.fused_sample(logits["xla"], key, temp, top_k)),
+            np.asarray(sample_tokens(logits["xla"], key, temp, top_k)),
+        )
 
 
 def test_engine_kernel_arg_validated():

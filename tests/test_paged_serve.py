@@ -1,7 +1,8 @@
 """Paged continuous-batching engine: token-identity vs the static
 ``ServeEngine`` across architecture families (with and without prefix
 sharing), compile-count bounds under randomized prompt lengths, page-pool
-pressure behaviour, and memory accounting."""
+pressure behaviour, and memory accounting. Tests parametrized over
+``engine_cls`` also run the disaggregated layout of the same engine."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,7 +10,13 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import build_model
-from repro.serve import PagedContinuousBatchingEngine, ServeEngine
+from repro.serve import DisaggregatedEngine, PagedContinuousBatchingEngine, ServeEngine
+
+# the single-mesh engine and its disaggregated layout (one device here)
+ENGINES = pytest.mark.parametrize(
+    "engine_cls", [PagedContinuousBatchingEngine, DisaggregatedEngine],
+    ids=["paged", "disagg"],
+)
 
 # fast subset runs two families (dense attn + rwkv); the rest ride -m slow
 ARCHS = [
@@ -102,7 +109,7 @@ def test_paged_whisper_enc_dec():
 
 
 def test_compile_counts_bounded_under_random_prompt_lengths():
-    """Regression: the dense engine compiles one prefill executable per
+    """Regression: full-prompt prefill compiles one prefill executable per
     distinct prompt length; the paged engine must stay bounded by the
     chunk-size bucket count (sub-chunk tails ride already-compiled decode
     ticks), and decode compiles stay one per admission stage."""
@@ -114,7 +121,7 @@ def test_compile_counts_bounded_under_random_prompt_lengths():
     assert engine.admission.ladder == [1, 2, 4]
     rng = np.random.default_rng(3)
     lengths = rng.integers(1, 24, size=10)  # many distinct prompt lengths
-    assert len(set(lengths)) > len(engine.prefill_chunks)
+    assert len(set(lengths)) > len(engine.worker.prefill_chunks)
     ids = [
         engine.submit(rng.integers(0, cfg.vocab_size, n), max_new_tokens=4)
         for n in lengths
@@ -122,28 +129,29 @@ def test_compile_counts_bounded_under_random_prompt_lengths():
     out = engine.run()
     assert set(ids) == set(out)
     # chunk-prefill executables: one per bucket, NOT one per prompt length
-    assert engine.prefill_compiles <= len(engine.prefill_chunks)
+    worker = engine.worker
+    assert engine.prefill_compiles <= len(worker.prefill_chunks)
     assert (
-        sum(step._cache_size() for step in engine._chunk_steps.values())
-        <= len(engine.prefill_chunks)
+        sum(step._cache_size() for step in worker._chunk_steps.values())
+        <= len(worker.prefill_chunks)
     )
     # decode: at most one executable per admission stage (a stage whose only
     # work was chunk prefill never ticks), each compiled exactly once
     assert engine.admission.stage == engine.admission.num_stages - 1
-    assert set(engine._decodes) <= {1, 2, 4} and 4 in engine._decodes
-    assert engine.decode_compiles == len(engine._decodes) <= engine.admission.num_stages
-    assert all(step._cache_size() == 1 for step in engine._decodes.values())
+    assert set(worker._decodes) <= {1, 2, 4} and 4 in worker._decodes
+    assert engine.decode_compiles == len(worker._decodes) <= engine.admission.num_stages
+    assert all(step._cache_size() == 1 for step in worker._decodes.values())
     # re-serving at known widths/buckets adds no executables
     ids2 = [engine.submit(rng.integers(0, cfg.vocab_size, 13), max_new_tokens=3)]
     engine.run()
-    assert engine.prefill_compiles <= len(engine.prefill_chunks)
-    assert all(step._cache_size() == 1 for step in engine._decodes.values())
+    assert engine.prefill_compiles <= len(worker.prefill_chunks)
+    assert all(step._cache_size() == 1 for step in worker._decodes.values())
 
 
 def test_paged_slot_recycling_and_memory_high_water():
     """More requests than slots complete through recycled pages, and the
-    pool's high-water mark stays below the dense engine's resident KV for
-    the same ring."""
+    pool's high-water mark stays below a dense per-slot cache's resident
+    KV for the same ring."""
     cfg, model, params = _setup("qwen2.5-3b")
     n_requests, n_slots = 6, 2
     prompts = np.asarray(
@@ -161,8 +169,8 @@ def test_paged_slot_recycling_and_memory_high_water():
         np.testing.assert_array_equal(out[rid], ref[i], err_msg=f"request {i}")
     mem = engine.memory_stats()
     assert mem["kv_bytes_peak"] < mem["kv_bytes_dense_equiv"]
-    engine.pool.check()
-    assert engine.pool.used == 0  # every page returned after the drain
+    engine.worker.pool.check()
+    assert engine.worker.pool.used == 0  # every page returned after the drain
 
 
 def test_paged_pool_pressure_defers_admission():
@@ -182,16 +190,17 @@ def test_paged_pool_pressure_defers_admission():
     out = engine.run()
     for i, rid in enumerate(ids):
         np.testing.assert_array_equal(out[rid], ref[i], err_msg=f"request {i}")
-    engine.pool.check()
+    engine.worker.pool.check()
 
 
-def test_paged_mixed_lengths_and_budgets():
+@ENGINES
+def test_paged_mixed_lengths_and_budgets(engine_cls):
     """Mixed prompt lengths and per-request max_new_tokens share one ring:
     a request finishing right at prefill completion (max_new_tokens=1), a
     1-token prompt (pure teacher-forced prefill, no chunk fits), and chunked
     prompts all match the static engine."""
     cfg, model, params = _setup("qwen2.5-3b")
-    engine = PagedContinuousBatchingEngine(
+    engine = engine_cls(
         model, params, cache_len=64, max_slots=2, page_size=4, prefill_chunks=(4,)
     )
     p = np.asarray(jax.random.randint(jax.random.key(1), (8,), 0, cfg.vocab_size))
@@ -205,8 +214,9 @@ def test_paged_mixed_lengths_and_budgets():
         np.testing.assert_array_equal(
             out[rid], se.generate(prompt[None, :], max_new_tokens=n)[0]
         )
-    engine.pool.check()
-    assert engine.pool.used == engine.index.num_pages  # only published pages live
+    for worker in engine.workers:
+        worker.pool.check()
+        assert worker.pool.used == worker.index.num_pages  # only published pages live
 
 
 def test_requeued_request_survives_one_page_pool():
@@ -233,43 +243,46 @@ def test_requeued_request_survives_one_page_pool():
         np.testing.assert_array_equal(
             out[rid], static.generate(prompts[i][None, :], max_new_tokens=3)[0]
         )
-    engine.pool.check()
-    assert engine.pool.used == 0
+    engine.worker.pool.check()
+    assert engine.worker.pool.used == 0
 
 
-def test_request_larger_than_pool_raises_not_hangs():
+@ENGINES
+def test_request_larger_than_pool_raises_not_hangs(engine_cls):
     """A request whose footprint exceeds the whole pool (even after full
     index eviction) must fail loudly at admission — the complement of the
-    requeue-drain guarantee above."""
+    requeue-drain guarantee above. The disaggregated engine admits prompts
+    into its prefill pool, so that is the pool made too small."""
     cfg, model, params = _setup("qwen2.5-3b")
-    engine = PagedContinuousBatchingEngine(
-        model, params, cache_len=16, max_slots=2, page_size=4, num_pages=2,
-        prefill_chunks=(4,),
+    disagg = engine_cls is DisaggregatedEngine
+    engine = engine_cls(
+        model, params, cache_len=16, max_slots=2, page_size=4, prefill_chunks=(4,),
+        **{"prefill_pages" if disagg else "num_pages": 2},
     )
     engine.submit(np.arange(6, dtype=np.int32) % cfg.vocab_size, max_new_tokens=2)
-    with pytest.raises(RuntimeError, match="cannot fit"):
+    pool = "prefill page pool" if disagg else "page pool"
+    with pytest.raises(RuntimeError, match=f"^{pool} .* cannot fit"):
         engine.run()
 
 
-def test_paged_sampling_params_per_slot():
+@ENGINES
+def test_paged_sampling_params_per_slot(engine_cls):
     """top_k=1 reduces to greedy (identical to static); temperature sampling
-    is reproducible per engine seed and stays in-vocab."""
+    is reproducible per engine seed and stays in-vocab. The two engines draw
+    from their RNG stream in different orders, so their sampled tokens are
+    not compared with each other."""
     cfg, model, params = _setup("qwen2.5-3b")
     prompts = np.asarray(jax.random.randint(jax.random.key(1), (2, 6), 0, cfg.vocab_size))
     ref = ServeEngine(model, params, cache_len=64).generate(prompts, max_new_tokens=6)
 
-    eng = PagedContinuousBatchingEngine(
-        model, params, cache_len=64, max_slots=2, page_size=4, seed=7
-    )
+    eng = engine_cls(model, params, cache_len=64, max_slots=2, page_size=4, seed=7)
     ids = [eng.submit(p, max_new_tokens=6, temperature=1.0, top_k=1) for p in prompts]
     out = eng.run()
     for i, rid in enumerate(ids):
         np.testing.assert_array_equal(out[rid], ref[i])
 
     def sample_run():
-        e = PagedContinuousBatchingEngine(
-            model, params, cache_len=64, max_slots=2, page_size=4, seed=7
-        )
+        e = engine_cls(model, params, cache_len=64, max_slots=2, page_size=4, seed=7)
         rids = [e.submit(p, max_new_tokens=6, temperature=0.8, top_k=16) for p in prompts]
         res = e.run()
         return [res[r] for r in rids]

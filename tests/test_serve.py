@@ -1,6 +1,5 @@
-"""Serving engines: static-batch greedy consistency, and the continuous-
-batching engine — token-identity vs the static path, slot recycling, and
-the stagewise admission ramp's compile accounting."""
+"""Serving engines: static-batch greedy consistency, the stagewise admission
+ramp's gating and compile accounting, and request lifecycle stamps."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,7 +7,12 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import build_model
-from repro.serve import AdmissionController, ContinuousBatchingEngine, ServeEngine
+from repro.serve import (
+    AdmissionController,
+    DisaggregatedEngine,
+    PagedContinuousBatchingEngine,
+    ServeEngine,
+)
 
 # fast subset runs two families (dense attn + rwkv); the rest ride -m slow
 ARCHS = [
@@ -57,44 +61,8 @@ def test_whisper_generation_with_audio_memory():
 
 
 # ---------------------------------------------------------------------------
-# continuous batching
+# scheduled engines
 # ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("arch", ARCHS)
-def test_continuous_batching_matches_static_greedy(arch):
-    """Continuous-batching greedy output is token-identical to the static
-    ServeEngine on every architecture family: per-slot decode depths,
-    one-hot cache writes and batch-1 prefill must not perturb a single
-    logit argmax."""
-    cfg, model, params = _setup(arch)
-    prompts = np.asarray(jax.random.randint(jax.random.key(1), (4, 6), 0, cfg.vocab_size))
-    ref = ServeEngine(model, params, cache_len=64).generate(prompts, max_new_tokens=6)
-    engine = ContinuousBatchingEngine(model, params, cache_len=64, max_slots=4)
-    ids = [engine.submit(p, max_new_tokens=6) for p in prompts]
-    out = engine.run()
-    for i, rid in enumerate(ids):
-        np.testing.assert_array_equal(out[rid], ref[i], err_msg=f"request {i}")
-
-
-def test_slot_recycling_serves_more_requests_than_slots():
-    """N requests complete correctly through fewer than N slots in ONE
-    decode loop: freed slots are re-admitted mid-loop via in-place cache
-    insertion, and recycled slots produce the same tokens as a fresh
-    static batch."""
-    cfg, model, params = _setup("qwen2.5-3b")
-    n_requests, n_slots = 6, 2
-    prompts = np.asarray(
-        jax.random.randint(jax.random.key(1), (n_requests, 6), 0, cfg.vocab_size)
-    )
-    ref = ServeEngine(model, params, cache_len=64).generate(prompts, max_new_tokens=5)
-    engine = ContinuousBatchingEngine(model, params, cache_len=64, max_slots=n_slots)
-    ids = [engine.submit(p, max_new_tokens=5) for p in prompts]
-    out = engine.run()
-    assert len(out) == n_requests
-    assert engine.stats["peak_width"] == n_slots  # never widened past 2 slots
-    for i, rid in enumerate(ids):
-        np.testing.assert_array_equal(out[rid], ref[i], err_msg=f"request {i}")
 
 
 def test_admission_ramp_compiles_one_decode_variant_per_stage():
@@ -103,8 +71,9 @@ def test_admission_ramp_compiles_one_decode_variant_per_stage():
     engine's compile-count hook, as test_trainer_modes does for train
     steps), and re-serving at known widths adds none."""
     cfg, model, params = _setup("qwen2.5-3b")
-    engine = ContinuousBatchingEngine(
-        model, params, cache_len=64, max_slots=4, b1=1, rho=2.0, patience=2
+    engine = PagedContinuousBatchingEngine(
+        model, params, cache_len=64, max_slots=4, b1=1, rho=2.0, patience=2,
+        page_size=4, prefill_chunks=(4,),
     )
     assert engine.admission.ladder == [1, 2, 4]
     prompts = np.asarray(jax.random.randint(jax.random.key(1), (8, 4), 0, cfg.vocab_size))
@@ -113,29 +82,13 @@ def test_admission_ramp_compiles_one_decode_variant_per_stage():
     assert set(out) == set(ids)
     # sustained 8-deep queue must ramp through every stage
     assert engine.admission.stage == engine.admission.num_stages - 1
-    assert sorted(engine._decodes) == [1, 2, 4]
+    assert sorted(engine.worker._decodes) == [1, 2, 4]
     assert engine.decode_compiles == engine.admission.num_stages
     # serving more traffic at the same widths reuses the compiled variants
     ids2 = [engine.submit(p, max_new_tokens=4) for p in prompts[:3]]
     out2 = engine.run()
     assert set(ids2) <= set(out2)
     assert engine.decode_compiles == engine.admission.num_stages
-
-
-def test_cache_insert_extract_roundtrip():
-    """cache_extract is cache_insert's inverse on the (layers, batch, ...)
-    slot layout — the contract the admission path's insertion relies on."""
-    cfg, model, params = _setup("qwen2.5-3b")
-    wide = model.init_cache(3, 32)
-    batch = {"tokens": jnp.asarray(np.arange(4, dtype=np.int32)[None, :])}
-    _, one = model.prefill(params, batch, model.init_cache(1, 32))
-    wide = model.cache_insert(wide, one, 2)
-    back = model.cache_extract(wide, 2)
-    for a, b in zip(jax.tree.leaves(one), jax.tree.leaves(back)):
-        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
-    # untouched slots stay zero-initialized
-    other = model.cache_extract(wide, 0)
-    assert all(not np.asarray(leaf).any() for leaf in jax.tree.leaves(other))
 
 
 def test_admission_controller_sustained_load_gating():
@@ -150,68 +103,6 @@ def test_admission_controller_sustained_load_gating():
     assert ctl.observe(10) == 4
     assert ctl.observe(10) == 8  # stage 2 (cap)
     assert ctl.observe(100) == 8  # saturated: never exceeds max_slots
-
-
-def test_continuous_sampling_params_per_slot():
-    """temperature=0 and top_k=1 must both reduce to greedy; temperature
-    sampling is reproducible per engine seed and stays in-vocab."""
-    cfg, model, params = _setup("qwen2.5-3b")
-    prompts = np.asarray(jax.random.randint(jax.random.key(1), (2, 6), 0, cfg.vocab_size))
-    ref = ServeEngine(model, params, cache_len=64).generate(prompts, max_new_tokens=6)
-
-    eng = ContinuousBatchingEngine(model, params, cache_len=64, max_slots=2, seed=7)
-    ids = [eng.submit(p, max_new_tokens=6, temperature=1.0, top_k=1) for p in prompts]
-    out = eng.run()
-    for i, rid in enumerate(ids):  # top-1 truncation == greedy
-        np.testing.assert_array_equal(out[rid], ref[i])
-
-    def sample_run():
-        e = ContinuousBatchingEngine(model, params, cache_len=64, max_slots=2, seed=7)
-        rids = [e.submit(p, max_new_tokens=6, temperature=0.8, top_k=16) for p in prompts]
-        out = e.run()
-        return [out[r] for r in rids]
-
-    a, b = sample_run(), sample_run()
-    for ra, rb in zip(a, b):
-        np.testing.assert_array_equal(ra, rb)
-        assert (ra < cfg.vocab_size).all()
-
-
-def test_continuous_mixed_lengths_and_budgets():
-    """Mixed prompt lengths and per-request max_new_tokens share one ring;
-    max_new_tokens=1 completes at admission without a decode tick."""
-    cfg, model, params = _setup("qwen2.5-3b")
-    engine = ContinuousBatchingEngine(model, params, cache_len=64, max_slots=2)
-    p = np.asarray(jax.random.randint(jax.random.key(1), (8,), 0, cfg.vocab_size))
-    a = engine.submit(p[:4], max_new_tokens=1)
-    b = engine.submit(p, max_new_tokens=8)
-    c = engine.submit(p[:6], max_new_tokens=3)
-    out = engine.run()
-    assert out[a].shape == (5,) and out[b].shape == (16,) and out[c].shape == (9,)
-    # the 1-token request's output equals its greedy prefill continuation
-    ref = ServeEngine(model, params, cache_len=64).generate(p[None, :4], max_new_tokens=1)
-    np.testing.assert_array_equal(out[a], ref[0])
-
-
-@pytest.mark.slow
-def test_continuous_whisper_with_per_request_memory():
-    cfg, model, params = _setup("whisper-tiny")
-    prompts = np.zeros((2, 4), np.int32)
-    audio = 0.1 * np.asarray(
-        jax.random.normal(jax.random.key(2), (2, cfg.encoder_seq, cfg.d_model))
-    )
-    mem = jnp.asarray(audio, jnp.bfloat16)
-    ref = ServeEngine(model, params, cache_len=32).generate(
-        prompts, max_new_tokens=4, memory=mem
-    )
-    engine = ContinuousBatchingEngine(model, params, cache_len=32, max_slots=2)
-    ids = [
-        engine.submit(prompts[i], max_new_tokens=4, memory=mem[i : i + 1])
-        for i in range(2)
-    ]
-    out = engine.run()
-    for i, rid in enumerate(ids):
-        np.testing.assert_array_equal(out[rid], ref[i])
 
 
 def test_request_latency_guarded_until_done():
@@ -231,13 +122,19 @@ def test_request_latency_guarded_until_done():
     assert req.latency == pytest.approx(0.125)
 
 
-def test_engine_stamps_full_request_lifecycle():
-    """Every request served by the continuous engine carries real monotonic
+@pytest.mark.parametrize(
+    "engine_cls", [PagedContinuousBatchingEngine, DisaggregatedEngine],
+    ids=["paged", "disagg"],
+)
+def test_engine_stamps_full_request_lifecycle(engine_cls):
+    """Every request served by a scheduled engine carries real monotonic
     lifecycle stamps (submit < admit ≤ prefill_done ≤ first_token < finish)
     and the derived phase durations are finite and add up — the contract
     the obs tracer and the SLO percentile reports are built on."""
     cfg, model, params = _setup("qwen2.5-3b")
-    engine = ContinuousBatchingEngine(model, params, cache_len=64, max_slots=2)
+    engine = engine_cls(
+        model, params, cache_len=64, max_slots=2, page_size=4, prefill_chunks=(4,)
+    )
     prompts = np.asarray(jax.random.randint(jax.random.key(1), (4, 6), 0, cfg.vocab_size))
     ids = [engine.submit(p, max_new_tokens=4) for p in prompts]
     engine.run()
